@@ -1,7 +1,8 @@
 """Framework-side benchmark: rotor-collective wire bytes vs theory.
 
-Runs a subprocess with 8 fake XLA devices, compiles the rotor/XLA
-collective variants, and compares measured per-device wire bytes
+Runs a subprocess with 8 fake CPU devices (``JAX_PLATFORMS=cpu``, so it
+never reaches for an accelerator), compiles the rotor/XLA collective
+variants, and compares the CPU HLO's per-device wire bytes
 (loop-aware HLO accounting) against the closed-form schedule_stats —
 the bandwidth-tax ledger of the TPU adaptation.
 """
@@ -20,20 +21,21 @@ ROOT = Path(__file__).resolve().parents[1]
 
 _SCRIPT = r"""
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json, jax, jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from repro import compat
+from repro.launch.mesh import auto_mesh
 from repro.core import collectives as C
 from repro.analysis.hlo_cost import analyze
 
-mesh = compat.make_mesh((8,), ("d",))
+mesh = auto_mesh((8,), ("d",))
 N = 8
 SZ = 1 << 14  # floats per shard
 
 def wire(fn, shape):
-    f = compat.shard_map(fn, mesh=mesh, in_specs=P("d"), out_specs=P("d"),
+    f = jax.shard_map(fn, mesh=mesh, in_specs=P("d"), out_specs=P("d"),
                       check_vma=False)
     spec = jax.ShapeDtypeStruct(shape, jnp.float32)
     hlo = jax.jit(f).lower(spec).compile().as_text()

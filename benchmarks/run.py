@@ -1,6 +1,9 @@
 """Benchmark harness: one module per paper table/figure + framework benches.
 
     PYTHONPATH=src python -m benchmarks.run [--only fig08,fig12] [--skip ...]
+
+Exits 1 when any module raises or any of its checks fails, and 2 on an
+unknown module name.
 """
 from __future__ import annotations
 
@@ -35,6 +38,10 @@ def main(argv=None):
     args = ap.parse_args(argv)
     only = set(filter(None, args.only.split(",")))
     skip = set(filter(None, args.skip.split(",")))
+    unknown = (only | skip) - {name for name, _ in MODULES}
+    if unknown:
+        print(f"unknown benchmark module(s): {sorted(unknown)}")
+        sys.exit(2)
 
     results, failed = {}, []
     t0 = time.time()
@@ -60,7 +67,7 @@ def main(argv=None):
     print("== BENCHMARK SUMMARY")
     print("=" * 78)
     for name, r in results.items():
-        status = "OK  " if r.get("ok") else "WARN"
+        status = "OK  " if r.get("ok") else "FAIL"
         nchk = len(r.get("checks", {}))
         npass = sum(bool(v) for v in r.get("checks", {}).values())
         print(f"  [{status}] {name:26s} {npass}/{nchk} checks")
@@ -68,6 +75,11 @@ def main(argv=None):
     save("summary", results)
     if failed:
         print(f"\n{len(failed)} benchmark(s) errored: {failed}")
+    bad_checks = [name for name, r in results.items()
+                  if "checks" in r and not r["ok"]]
+    if bad_checks:
+        print(f"\n{len(bad_checks)} benchmark(s) failed checks: {bad_checks}")
+    if failed or bad_checks:
         sys.exit(1)
 
 

@@ -9,13 +9,13 @@
 import numpy as np
 
 import jax
-from repro import compat
 import jax.numpy as jnp
 
 from repro.configs.opera_paper import OPERA_648
 from repro.core.expander import mean_max_path, spectral_gap
 from repro.core.schedule import cycle_timing
 from repro.core.topology import build_opera_topology
+from repro.launch.mesh import auto_mesh
 from repro.netsim.fluid import simulate_rotor_bulk
 from repro.netsim.workloads import demand_all_to_all
 
@@ -43,13 +43,13 @@ from repro.core import collectives as C  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 n = len(jax.devices())
-mesh = compat.make_mesh((n, 1), ("data", "model"))
+mesh = auto_mesh((n, 1), ("data", "model"))
 grads = jnp.arange(8.0 * n).reshape(n, 8)
-rotor = jax.jit(compat.shard_map(
+rotor = jax.jit(jax.shard_map(
     lambda g: C.rotor_all_reduce(g, "data"),
     mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False,
 ))(grads)
-want = jax.jit(compat.shard_map(
+want = jax.jit(jax.shard_map(
     lambda g: jax.lax.psum(g, "data"),
     mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False,
 ))(grads)

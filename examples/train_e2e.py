@@ -11,7 +11,6 @@ import argparse
 import time
 
 import jax
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 
@@ -49,7 +48,7 @@ def main():
     print(f"model: {n/1e6:.1f}M params | floor {src.conditional_entropy():.3f}"
           f" nats | uniform {np.log(cfg.vocab_size):.3f} nats")
     t0, losses = time.time(), []
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for i in range(args.steps):
             state, m = step_fn(
                 state, jax.tree.map(jnp.asarray, src.batch_at(i))

@@ -54,12 +54,6 @@ def _perm_pairs(p: np.ndarray) -> list[tuple[int, int]]:
     return [(int(i), int(p[i])) for i in range(len(p)) if int(p[i]) != i]
 
 
-def _axis_size(axis_name) -> int:
-    from repro.compat import axis_size
-
-    return axis_size(axis_name)
-
-
 def _split_leading(x: jnp.ndarray, n: int) -> jnp.ndarray:
     """Reshape to (n, chunk) over a flattened view; requires divisibility."""
     flat = x.reshape(-1)
@@ -81,7 +75,7 @@ def rotor_reduce_scatter(x: jnp.ndarray, axis_name) -> jnp.ndarray:
     bulk class.  Input may be any shape; it is flattened to (N, chunk) and
     the local reduced chunk (chunk,) is returned.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     i = lax.axis_index(axis_name)
     xs = _split_leading(x, n)
     acc = jnp.take(xs, i, axis=0)
@@ -100,7 +94,7 @@ def rotor_reduce_scatter(x: jnp.ndarray, axis_name) -> jnp.ndarray:
 
 def rotor_all_gather(x: jnp.ndarray, axis_name) -> jnp.ndarray:
     """All-gather of per-shard chunks, one direct hop per chunk."""
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     i = lax.axis_index(axis_name)
     out = jnp.zeros((n,) + x.shape, x.dtype)
     out = out.at[i].set(x)
@@ -129,7 +123,7 @@ def rotor_all_reduce(
     """
     if mode == "direct":
         acc = x
-        n = _axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         i = lax.axis_index(axis_name)
         for p in _matchings(n):
             pairs = _perm_pairs(p)
@@ -158,7 +152,7 @@ def rotor_all_to_all(
     destinations) direct scheduling idles most slices while VLB keeps
     every slice busy.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if x.shape[0] != n:
         raise ValueError(f"leading dim {x.shape[0]} != axis size {n}")
     i = lax.axis_index(axis_name)
@@ -242,7 +236,7 @@ def expander_all_gather(
     paper accepts for the (tiny) latency-sensitive fraction, in exchange
     for not waiting on the rotor cycle.  Use for control-plane tensors.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     i = lax.axis_index(axis_name)
     if n == 1:
         return x[None]

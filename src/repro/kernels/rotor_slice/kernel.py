@@ -1,20 +1,22 @@
 """Pallas permutation-sparse rotor slice step.
 
-Grid (B / block_b,): the vmapped scenario batch rides the Pallas grid,
-one (block_b, N, N) state tile per cell; the (N, u) destination-index
-tensor (`OperaTopology.matching_index_tensor()` slice, sentinel N for
-dark slots) is broadcast to every cell.  The body is the edge-layout
-math of `ref.rotor_slice_ref` — gathers into (block_b, N, u), compare-
-select chains instead of scatters (see ref.py for why both are exact) —
-so one cell does O(N·(N + u)) work where the dense engine's relay
-matmul does O(N²·u).
+Grid (B,): one scenario per grid cell, its (N, N) `own` / `relay` tiles
+in VMEM; the destination-index tensor (`OperaTopology.
+matching_index_tensor()` slice, sentinel N for dark slots) enters
+transposed as (u, N) and is broadcast to every cell.  The body is the
+math of `ref.rotor_slice_ref`, in the forms Mosaic lowers:
 
-`ops.py` picks block_b per backend: one scenario per cell on TPU (each
-tile fits VMEM up to N ≈ 1k f32), the whole batch in a single cell
-under interpretation — XLA CPU executes consecutive grid steps of one
-program several-fold slower than the same body as one fused block (the
-measured multi-step pathology that also rules out `lax.scan` driving;
-see fluid_jax._run_batch_sparse).
+* no gathers: slot s's live edges are the (N, N) one-hot mask
+  ``hit_s[i, j] = (dst[i, s] == j)``, symmetric because every matching
+  is an involution.  The edge value ``own[i, dst[i, s]]`` is a masked
+  row sum, and the relay row gather ``take[dst[j, s], :]`` is the
+  product ``hit_s @ take`` at HIGHEST precision.  Both sum one nonzero
+  term, so both are exact.
+* slots are a `fori_loop`, and per-edge quantities are (N, 1) columns
+  rebuilt per slot, so VMEM holds a fixed number of (N, N) tiles
+  whatever u is.
+* per-rack totals leave through a (B, N, 3) block (full trailing dims,
+  a legal Mosaic block); `ref.rack_totals` sums them to (B,).
 """
 from __future__ import annotations
 
@@ -24,95 +26,122 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.rotor_slice.ref import rack_totals
+
+# The body keeps about two dozen live (N, N) f32 temporaries beside the
+# double-buffered state blocks (18.5 MB of VMEM at N = 432 for v5e);
+# Mosaic's default 16 MiB scoped limit is raised to that, within the
+# 128 MiB a v5e core has.
+_VMEM_LIVE_TILES = 32
+_VMEM_CAP_BYTES = 100 * 2**20
 
 
-def _apply_edges(dense, dst, vals, iota):
-    """dense[b, i, dst[i, s]] += vals[b, i, s] as a nested select tree
-    (slots are disjoint, see ref.apply_edges); the sentinel never
-    matches the iota so dark slots add exactly 0."""
-    acc = None
-    for s in range(dst.shape[1]):
-        hit = (dst[:, s:s + 1] == iota[None, :])[None]
-        v = vals[:, :, s:s + 1]
-        acc = jnp.where(hit, v, 0.0) if acc is None else jnp.where(hit, v, acc)
-    return dense + acc
+def _vmem_limit_bytes(n: int) -> int:
+    return min(max(16 * 2**20, _VMEM_LIVE_TILES * n * n * 4), _VMEM_CAP_BYTES)
 
 
-def _kernel(dst_ref, own_ref, relay_ref,
-            own_o, relay_o, deliv_o, moved_o, *, vlb: bool):
-    own = own_ref[...]          # (block_b, N, N)
-    relay = relay_ref[...]
-    dst = dst_ref[...]          # (N, u)
-    bsz, n = own.shape[0], own.shape[1]
-    u = dst.shape[1]
-    iota = jnp.arange(n, dtype=dst.dtype)
-    valid = dst < n
-    dstc = jnp.where(valid, dst, 0)
-    vf = valid.astype(own.dtype)[None]
-    idx = jnp.broadcast_to(dstc[None], (bsz, n, u))
+def _gather_rows(onehot, x):
+    """``x[dst[j]]`` for each row j of a one-hot matrix: exact, since
+    every output sums one product with 1.0 and zeros."""
+    return jax.lax.dot_general(
+        onehot, x, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
-    own_e = jnp.take_along_axis(own, idx, axis=2) * vf
-    send_own_e = jnp.minimum(own_e, vf)
-    room_e = vf - send_own_e
-    relay_e = jnp.take_along_axis(relay, idx, axis=2) * vf
-    send_relay_e = jnp.minimum(relay_e, room_e)
-    room_e = room_e - send_relay_e
-    delivered = send_own_e.sum((1, 2)) + send_relay_e.sum((1, 2))
 
-    own = _apply_edges(own, dst, -send_own_e, iota)
-    relay = _apply_edges(relay, dst, -send_relay_e, iota)
+def _kernel(dst_t_ref, own_ref, relay_ref, own_o, relay_o, stats_o, *,
+            vlb: bool):
+    own0 = own_ref[0]           # (N, N)
+    relay0 = relay_ref[0]
+    u, n = dst_t_ref.shape
+    rows = jax.lax.broadcasted_iota(dst_t_ref.dtype, (n, n), 0)
+
+    def slot(s):
+        """Slot s's edge mask and (N, 1) edge columns of the direct phase.
+        ``rows == dst[:, s]`` as a row is hit_s transposed, which is
+        hit_s itself: the slot's matching is an involution."""
+        hit = rows == dst_t_ref[pl.ds(s, 1), :]
+        vf = hit.astype(own0.dtype).sum(1, keepdims=True)
+        own_e = jnp.where(hit, own0, 0.0).sum(1, keepdims=True)
+        send_own = jnp.minimum(own_e, vf)
+        relay_e = jnp.where(hit, relay0, 0.0).sum(1, keepdims=True)
+        send_relay = jnp.minimum(relay_e, vf - send_own)
+        room = vf - send_own - send_relay
+        return hit, own_e, send_own, send_relay, room
+
+    def direct(s, carry):
+        # nested selects: slots are disjoint, at most one fires per element
+        d_own, d_relay, d_elig, r, so, sr = carry
+        hit, own_e, send_own, send_relay, room = slot(s)
+        return (jnp.where(hit, -send_own, d_own),
+                jnp.where(hit, -send_relay, d_relay),
+                jnp.where(hit, -(own_e - send_own), d_elig),
+                r + room, so + send_own, sr + send_relay)
+
+    zn = jnp.zeros_like(own0)
+    z1 = jnp.zeros((n, 1), own0.dtype)
+    d_own, d_relay, d_elig, r, so, sr = jax.lax.fori_loop(
+        0, u, direct, (zn, zn, zn, z1, z1, z1))
+    own = own0 + d_own
+    relay = relay0 + d_relay
+    moved = z1
     if vlb:
-        elig = _apply_edges(own, dst, -(own_e - send_own_e), iota)
-        q = elig.sum(2)
-        r = room_e.sum(2)
+        elig = own + d_elig
+        q = elig.sum(1, keepdims=True)
         t = jnp.minimum(q, r)
-        frac = jnp.where(q > 0, t / jnp.maximum(q, 1e-30), 0.0)[:, :, None]
+        frac = jnp.where(q > 0, t / jnp.maximum(q, 1e-30), 0.0)
         take = elig * frac
-        share_e = room_e * jnp.where(
-            r > 0, 1.0 / jnp.maximum(r, 1e-30), 0.0)[:, :, None]
+        inv_r = jnp.where(r > 0, 1.0 / jnp.maximum(r, 1e-30), 0.0)
         own = own - take
-        g_share = jnp.take_along_axis(share_e, idx, axis=1)
-        w = vf * g_share
-        add = jnp.zeros_like(relay)
-        for s in range(u):
-            add = add + w[:, :, s:s + 1] * jnp.take(take, dstc[:, s], axis=1)
-        relay = relay + add
-        moved = t.sum(1)
-    else:
-        moved = jnp.zeros_like(delivered)
 
-    own_o[...] = own
-    relay_o[...] = relay
-    deliv_o[...] = delivered[:, None]
-    moved_o[...] = moved[:, None]
+        def spread(s, add):
+            # relay[j] += share_s[dst[j, s]] * take[dst[j, s]]: both row
+            # gathers are products with the one-hot (symmetric) hit_s
+            hit, _, _, _, room = slot(s)
+            onehot = hit.astype(take.dtype)
+            w = _gather_rows(onehot, room * inv_r)
+            return add + w * _gather_rows(onehot, take)
+
+        relay = relay + jax.lax.fori_loop(0, u, spread, zn)
+        moved = t
+
+    own_o[0] = own
+    relay_o[0] = relay
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, 3), 1)
+    stats_o[0] = jnp.where(col == 0, so, jnp.where(col == 1, sr, moved))
 
 
 def rotor_slice_fwd(
     own: jnp.ndarray,     # (B, N, N)
     relay: jnp.ndarray,   # (B, N, N)
     dst: jnp.ndarray,     # (N, u) int32
-    vlb: bool, block_b: int, interpret: bool,
+    vlb: bool, interpret: bool,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     bsz, n = own.shape[0], own.shape[1]
     u = dst.shape[1]
-    grid = (bsz // block_b,)
-    state_spec = pl.BlockSpec((block_b, n, n), lambda b: (b, 0, 0))
-    scalar_spec = pl.BlockSpec((block_b, 1), lambda b: (b, 0))
-    own2, relay2, deliv, moved = pl.pallas_call(
+    state_spec = pl.BlockSpec((1, n, n), lambda b: (b, 0, 0))
+    own2, relay2, stats = pl.pallas_call(
         functools.partial(_kernel, vlb=vlb),
-        grid=grid,
+        grid=(bsz,),
         in_specs=[
-            pl.BlockSpec((n, u), lambda b: (0, 0)),
+            pl.BlockSpec((u, n), lambda b: (0, 0)),
             state_spec,
             state_spec,
         ],
-        out_specs=[state_spec, state_spec, scalar_spec, scalar_spec],
+        out_specs=[
+            state_spec,
+            state_spec,
+            pl.BlockSpec((1, n, 3), lambda b: (b, 0, 0)),
+        ],
         out_shape=[
             jax.ShapeDtypeStruct((bsz, n, n), own.dtype),
             jax.ShapeDtypeStruct((bsz, n, n), own.dtype),
-            jax.ShapeDtypeStruct((bsz, 1), own.dtype),
-            jax.ShapeDtypeStruct((bsz, 1), own.dtype),
+            jax.ShapeDtypeStruct((bsz, n, 3), own.dtype),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit_bytes(n)),
         interpret=interpret,
-    )(dst, own, relay)
-    return own2, relay2, deliv[:, 0], moved[:, 0]
+    )(dst.T, own, relay)
+    return (own2, relay2) + rack_totals(stats)

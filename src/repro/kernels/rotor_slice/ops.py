@@ -13,36 +13,27 @@ from repro.kernels.rotor_slice.ref import rotor_slice_ref
 
 
 @functools.partial(
-    jax.jit, static_argnames=("vlb", "block_b", "interpret", "force_pallas"))
+    jax.jit, static_argnames=("vlb", "interpret", "force_pallas"))
 def rotor_slice_step(
     own: jnp.ndarray,     # (B, N, N) undelivered bytes, normalized units
     relay: jnp.ndarray,   # (B, N, N) in-flight relayed bytes
     dst: jnp.ndarray,     # (N, u) int32 destination indices, sentinel N
     vlb: bool = True,
-    block_b: Optional[int] = None,
     interpret: Optional[bool] = None,
     force_pallas: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One Opera slice over a scenario batch; returns (own, relay,
     delivered, moved) with (B,) delivered / VLB-spread totals.
 
-    Off TPU (``interpret`` resolves True) the oracle math of
-    `ref.rotor_slice_ref` is dispatched directly: the Pallas interpreter
-    adds a fixed per-call cost that is material against the sub-ms step
-    this op targets (~15% at N = 432 on one CPU core), and the kernel
-    body is the same jnp expression graph either way.  Pass
-    ``force_pallas=True`` to route through ``pl.pallas_call(
-    interpret=True)`` anyway — the kernel-exercise mode the parity tests
-    use.  On TPU the Pallas kernel runs with one scenario per grid cell
-    (``block_b=1``); each (block_b, N, N) tile fits VMEM up to N ~ 1k.
+    On TPU the Pallas kernel always runs, one scenario per grid cell.
+    Elsewhere (``interpret`` resolves True) the jnp math of
+    `ref.rotor_slice_ref` runs instead: the Pallas interpreter walks the
+    grid one scenario at a time, far slower than the batched jnp graph
+    on XLA CPU.  ``force_pallas=True`` routes through
+    ``pl.pallas_call(interpret=True)`` anyway — the kernel-exercise mode
+    the parity tests use.
     """
     interpret = resolve_interpret(interpret)
     if interpret and not force_pallas:
         return rotor_slice_ref(own, relay, dst, vlb=vlb)
-    if block_b is None:
-        block_b = own.shape[0] if interpret else 1
-    if own.shape[0] % block_b:
-        raise ValueError(
-            f"batch {own.shape[0]} not divisible by block_b {block_b}")
-    return rotor_slice_fwd(own, relay, dst, vlb=vlb, block_b=block_b,
-                           interpret=interpret)
+    return rotor_slice_fwd(own, relay, dst, vlb=vlb, interpret=interpret)

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 
 
@@ -60,6 +61,25 @@ def apply_edges(dense: jnp.ndarray, dst: jnp.ndarray,
     return dense + acc
 
 
+def _slot_sum(e: jnp.ndarray) -> jnp.ndarray:
+    """Sum over the slot axis, slot by slot in order — the accumulation
+    order of the Pallas kernel's slot loop, so the two agree bitwise."""
+    acc = e[..., 0]
+    for s in range(1, e.shape[-1]):
+        acc = acc + e[..., s]
+    return acc
+
+
+def rack_totals(stats: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(B,) delivered and VLB-moved totals from (B, N, 3) per-rack
+    columns (sent own, sent relay, moved).  The barrier makes XLA reduce
+    the same materialized array whichever path produced it, so the ref
+    path and the Pallas kernel agree bitwise."""
+    stats = jax.lax.optimization_barrier(stats)
+    return (stats[:, :, 0].sum(1) + stats[:, :, 1].sum(1),
+            stats[:, :, 2].sum(1))
+
+
 def rotor_slice_ref(
     own: jnp.ndarray,     # (B, N, N) undelivered source->dst bytes
     relay: jnp.ndarray,   # (B, N, N) relayed bytes awaiting 2nd hop
@@ -83,19 +103,20 @@ def rotor_slice_ref(
     relay_e = jnp.take_along_axis(relay, idx, axis=2) * vf
     send_relay_e = jnp.minimum(relay_e, room_e)
     room_e = room_e - send_relay_e
-    delivered = send_own_e.sum((1, 2)) + send_relay_e.sum((1, 2))
+    so, sr = _slot_sum(send_own_e), _slot_sum(send_relay_e)
 
     own = apply_edges(own, dst, -send_own_e)
     relay = apply_edges(relay, dst, -send_relay_e)
     if not vlb:
-        return own, relay, delivered, jnp.zeros_like(delivered)
+        return (own, relay) + rack_totals(
+            jnp.stack([so, sr, jnp.zeros_like(so)], -1))
 
     # VLB spread.  Eligible bytes are those with no live circuit this
     # slice; subtracting the *pre-send* edge value own_e realises the
     # dense `where(adj > 0, 0, own)` with exact zeros at live edges.
     elig = apply_edges(own, dst, -(own_e - send_own_e))
     q = elig.sum(2)
-    r = room_e.sum(2)
+    r = _slot_sum(room_e)
     t = jnp.minimum(q, r)
     frac = jnp.where(q > 0, t / jnp.maximum(q, 1e-30), 0.0)[:, :, None]
     take = elig * frac
@@ -110,7 +131,7 @@ def rotor_slice_ref(
     for s in range(u):
         add = add + w[:, :, s:s + 1] * jnp.take(take, dstc[:, s], axis=1)
     relay = relay + add
-    return own, relay, delivered, t.sum(1)
+    return (own, relay) + rack_totals(jnp.stack([so, sr, t], -1))
 
 
 def rotor_slice_faulted_ref(
@@ -169,8 +190,8 @@ def rotor_slice_faulted_ref(
     relay = apply_edges(relay, dst, -send_relay_e * arrive_e)
     delivered = ((send_own_e * arrive_e).sum((1, 2))
                  + (send_relay_e * arrive_e).sum((1, 2)))
-    attempted = send_own_e.sum((1, 2)) + send_relay_e.sum((1, 2))
-    blackholed = attempted - delivered
+    blackholed = ((send_own_e * e_real_e).sum((1, 2))
+                  + (send_relay_e * e_real_e).sum((1, 2)))
     if not vlb:
         return own, relay, delivered, jnp.zeros_like(delivered), blackholed
 

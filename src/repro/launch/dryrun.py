@@ -1,5 +1,12 @@
+"""Compile-only dry run of the production meshes on 512 fake CPU devices.
+
+Counts HLO collective bytes and roofline terms from CPU compilations; it
+measures nothing on an accelerator and never touches one (it pins
+``JAX_PLATFORMS=cpu`` before jax is imported).
+"""
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 # --- everything below this line may import jax ------------------------------
@@ -14,7 +21,6 @@ import numpy as np  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.analysis.hlo import collective_breakdown_table, collective_bytes  # noqa: E402
-from repro.compat import set_mesh  # noqa: E402
 from repro.analysis.hlo_cost import analyze as hlo_analyze  # noqa: E402
 from repro.analysis.roofline import RooflineTerms, model_flops  # noqa: E402
 from repro.configs import SHAPES, get_config, input_specs, list_archs  # noqa: E402
@@ -124,7 +130,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: Path,
     t0 = time.time()
     cfg, spec, jfn, args = build_cell(arch, shape_name, mesh, pctx,
                                       cfg_overrides=cfg_over)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jfn.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

@@ -6,22 +6,25 @@ axes (data, model).  Multi-pod: 2 pods x 256 = 512 chips, axes
 (pod, data, model); the `pod` axis is the rotor-scheduled inter-pod
 dimension (DESIGN.md §3.1).
 
-Generic mesh construction lives in ``repro.compat.make_mesh`` — import
-it from there (the SC-AST-SHADOW staticcheck rule rejects re-exports of
-the compat surface; this module used to carry a trivial `make_mesh`
-alias that shadowed it).
+`auto_mesh` is the repo's one mesh constructor: every axis is Auto, so
+GSPMD propagates shardings and `shard_map` regions bind axes explicitly
+(``jax.make_mesh`` defaults to Explicit axes).
 """
 from __future__ import annotations
 
 import jax
 
-from repro.compat import make_mesh as _compat_make_mesh
+
+def auto_mesh(shape, axes, devices=None):
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _compat_make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
@@ -29,7 +32,7 @@ def make_host_mesh(model: int = 1):
     used by tests and the CPU examples, never by the dry-run."""
     n = len(jax.devices())
     data = n // model
-    return _compat_make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 def pctx_for_mesh(mesh, **kw):

@@ -1,7 +1,12 @@
-"""Serving launcher: continuous-batching engine over a (reduced) arch.
+"""Serving launcher: continuous-batching engine over a registered arch.
+
+Full width on one chip (smollm-360m, 2048-token slot caches):
 
     PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m \
-        --requests 8 --slots 4 --max-new 8
+        --requests 4 --slots 4 --max-new 16 --max-seq 2048
+
+CPU-scale smoke: add --reduced (d_model 64).  `main` returns the
+finished requests.
 """
 from __future__ import annotations
 
@@ -13,6 +18,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.configs.base import reduced_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.models.parallel import single_device_ctx
 from repro.serve.engine import Request, ServeEngine
@@ -21,13 +27,14 @@ from repro.serve.engine import Request, ServeEngine
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--max-seq", type=int, default=64)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -51,7 +58,8 @@ def main(argv=None):
     print(f"[serve] {cfg.name}: {len(done)} requests, {toks} tokens, "
           f"{dt:.1f}s ({toks/dt:.1f} tok/s)")
     for r in done[:4]:
-        print(f"  req {r.rid}: {list(r.prompt[:6])}... -> {r.out_tokens}")
+        print(f"  req {r.rid}: {r.prompt[:6].tolist()}... -> {r.out_tokens}")
+    return done
 
 
 if __name__ == "__main__":

@@ -1,10 +1,15 @@
 """Training launcher.
 
-CPU-scale smoke:  PYTHONPATH=src python -m repro.launch.train \
-    --arch smollm-360m --reduced --steps 50 --batch 8 --seq 64
+Full width on the local chips (one TPU v5e: smollm-360m, 32 layers,
+d_model 960; the host mesh spans every local device):
 
-On hardware the same entry point takes --mesh pod/multipod and the full
-configs; here the examples use --reduced with a host mesh.
+    PYTHONPATH=src python -m repro.launch.train --arch smollm-360m \
+        --steps 3 --batch 8 --seq 1024 --trainer opera-dp
+
+CPU-scale smoke: add --reduced (d_model 64) with e.g. --batch 8 --seq 64.
+--mesh pod/multipod builds the production meshes of launch/mesh.py.
+`main` returns the per-step losses and how many devices hold the
+trained parameters.
 """
 from __future__ import annotations
 
@@ -15,10 +20,10 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import set_mesh
 from repro.configs import get_config
 from repro.configs.base import reduced_config
 from repro.data.pipeline import SyntheticLM, device_batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh, pctx_for_mesh
 from repro.models import init_params
 from repro.models.sharding import batch_spec, param_shardings
@@ -48,6 +53,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -62,8 +68,13 @@ def main(argv=None):
 
     key = jax.random.key(args.seed)
     params = init_params(cfg, key)
+    # the initial state is placed as the step returns it, so the step
+    # compiles once, not again at step 1 for a new input sharding
+    replicated = NamedSharding(mesh, P())
     if args.trainer == "opera-dp":
-        state = init_opera_dp_state(params, compress=args.compress_grads)
+        state = jax.device_put(
+            init_opera_dp_state(params, compress=args.compress_grads),
+            replicated)
         step_fn = make_opera_dp_train_step(
             cfg, pctx, opt, compress=args.compress_grads
         )
@@ -76,10 +87,14 @@ def main(argv=None):
             "opt": {
                 "m": jax.device_put(state["opt"]["m"], shardings),
                 "v": jax.device_put(state["opt"]["v"], shardings),
-                "step": state["opt"]["step"],
+                "step": jax.device_put(state["opt"]["step"], replicated),
             },
         }
-    jitted = jax.jit(step_fn)
+    # outputs keep the inputs' exact shardings: GSPMD may spell the same
+    # layout differently (P(None, 'data') for P(None, 'data', None)),
+    # which is a cache miss, so an unpinned step compiles a second time
+    jitted = jax.jit(step_fn, out_shardings=(
+        jax.tree.map(lambda x: x.sharding, state), None))
 
     ckpt = Checkpointer(args.ckpt_dir) if args.ckpt_dir else None
     start_step = 0
@@ -99,7 +114,7 @@ def main(argv=None):
           f"floor={src.conditional_entropy():.3f} nats")
     t0 = time.time()
     losses = []
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for step in range(start_step, args.steps):
             batch = next(batches)
             state, metrics = jitted(state, batch)
@@ -118,7 +133,8 @@ def main(argv=None):
         ckpt.save(args.steps, state, blocking=True)
     print(f"[train] done: loss {losses[0]:.3f} -> {losses[-1]:.3f} "
           f"(floor {src.conditional_entropy():.3f})")
-    return losses
+    leaf = jax.tree.leaves(state["params"])[0]
+    return {"losses": losses, "param_devices": len(leaf.sharding.device_set)}
 
 
 if __name__ == "__main__":

@@ -196,10 +196,22 @@ def forward_decode(
 # --------------------------------------------------------------------------
 
 
+def _pick_gold(logits: jnp.ndarray, targets: jnp.ndarray) -> jnp.ndarray:
+    """``logits[..., targets]`` as a one-hot select-sum, not a gather.
+
+    Exact (one nonzero term per row), and elementwise over the vocab dim,
+    so a vocab-sharded logits tensor reduces with one all-reduce.  XLA's
+    gather partitioner aborts on that layout inside a partial-manual
+    `shard_map` region (the rotor pod trainer, train/trainer.py).
+    """
+    vocab = jnp.arange(logits.shape[-1], dtype=targets.dtype)
+    return jnp.where(vocab == targets[..., None], logits, 0.0).sum(-1)
+
+
 def softmax_xent(logits: jnp.ndarray, targets: jnp.ndarray, z_weight=1e-4):
     """Mean token cross-entropy (+ z-loss) in fp32."""
     lse = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    gold = _pick_gold(logits, targets)
     ce = (lse - gold).mean()
     z = (lse**2).mean() * z_weight
     return ce + z, ce
@@ -242,10 +254,7 @@ def softmax_xent_chunked(
         ).sum(-1)
         t_loc = targets - lo
         in_chunk = (t_loc >= 0) & (t_loc < c)
-        g = jnp.take_along_axis(
-            logits, jnp.clip(t_loc, 0, c - 1)[..., None], axis=-1
-        )[..., 0]
-        gold = gold + jnp.where(in_chunk, g, 0.0)
+        gold = gold + jnp.where(in_chunk, _pick_gold(logits, t_loc), 0.0)
         return (m_new, s, gold), None
 
     B, S = targets.shape

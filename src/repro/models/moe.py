@@ -25,7 +25,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.core import collectives as C
 from repro.models.layers import act_fn, dense_init
@@ -235,7 +234,7 @@ def apply_moe(
             aux = aux / (tp * pctx.dp_size)
             return y, aux
 
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             shard_fn,
             in_specs=(in_spec, P(), P(tp_axis, None, None),
                       P(tp_axis, None, None), P(tp_axis, None, None)),
@@ -262,7 +261,7 @@ def apply_moe(
             aux = _aux_loss(probs, idx, E)
             return y, aux
 
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             shard_fn,
             in_specs=(in_spec, P(), P(tp_axis, None, None),
                       P(tp_axis, None, None), P(tp_axis, None, None)),

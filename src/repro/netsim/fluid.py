@@ -152,8 +152,9 @@ def rotor_slice_step_faulted(
     relay = relay - send_relay * arrive
     room = room - send_relay
     delivered = float((send_own * arrive).sum() + (send_relay * arrive).sum())
-    attempted = float(send_own.sum() + send_relay.sum())
-    blackholed = attempted - delivered
+    # summed directly, not as attempted - delivered: a difference of two
+    # large sums keeps their rounding error and can go negative
+    blackholed = float((send_own * e_real).sum() + (send_relay * e_real).sum())
 
     moved = 0.0
     if vlb:

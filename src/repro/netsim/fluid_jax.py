@@ -78,7 +78,9 @@ def _slice_step(state, adj, vlb: bool):
         take = elig * jnp.where(q > 0, t / jnp.maximum(q, 1e-30), 0.0)[:, None]
         share = room * jnp.where(r > 0, 1.0 / jnp.maximum(r, 1e-30), 0.0)[:, None]
         own = own - take
-        relay = relay + share.T @ take
+        # HIGHEST: the TPU's default f32 matmul rounds through bf16
+        relay = relay + jnp.matmul(share.T, take,
+                                   precision=jax.lax.Precision.HIGHEST)
         wire = wire + t.sum()
     return (own, relay, done, wire), (done, wire)
 
@@ -276,10 +278,10 @@ def _slice_step_faulted(state, xs, ops, vlb: bool):
     relay = relay - send_relay * arrive
     room = room - send_relay
     delivered = (send_own * arrive).sum() + (send_relay * arrive).sum()
-    attempted = send_own.sum() + send_relay.sum()
     done = done + delivered
     wire = wire + delivered
-    blk = blk + (attempted - delivered)
+    # lost sends summed directly (see fluid.rotor_slice_step_faulted)
+    blk = blk + (send_own * e_real).sum() + (send_relay * e_real).sum()
     if vlb:
         dst_ok = 1.0 - tor_known
         elig = jnp.where(cap > 0, 0.0, own * dst_ok[None, :])
@@ -294,7 +296,8 @@ def _slice_step_faulted(state, xs, ops, vlb: bool):
         lost = (share * e_real).sum(1)
         own = own - take + take * lost[:, None]
         relay = relay - rtake + rtake * lost[:, None]
-        relay = relay + (share * arrive).T @ (take + rtake)
+        relay = relay + jnp.matmul((share * arrive).T, take + rtake,
+                                   precision=jax.lax.Precision.HIGHEST)
         lost_sum = ((take + rtake).sum(1) * lost).sum()
         wire = wire + (t.sum() - lost_sum)
         blk = blk + lost_sum

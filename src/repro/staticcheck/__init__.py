@@ -14,8 +14,8 @@ Two layers, one finding vocabulary (`Finding`, rule IDs `SC-*`):
   — traces the jitted engine entry points to closed jaxprs and flags
   float64 leaks / host callbacks / sweep-grid recompilation, and walks
   the tree's ASTs to enforce the repo policies from ROADMAP Architecture
-  notes (the `repro.compat` import rule, oracle<->JAX lockstep pairs,
-  kernel trio completeness, annotated host-side float64 staging).
+  notes (oracle<->JAX lockstep pairs, kernel trio completeness,
+  annotated host-side float64 staging).
 
 Run it: ``python -m repro.staticcheck`` (CLI, exits non-zero on
 violations, writes ``results/staticcheck.json``) or via

@@ -1,13 +1,5 @@
 """Layer 2b — AST rules enforcing repo code policies (ROADMAP notes).
 
-SC-AST-COMPAT    all code must import shard_map/set_mesh/make_mesh from
-                 ``repro.compat`` — direct ``jax.shard_map`` /
-                 ``jax.set_mesh`` / ``jax.make_mesh`` attribute access or
-                 ``jax.experimental.shard_map`` imports are banned
-                 outside ``repro/compat.py``.
-SC-AST-SHADOW    no module other than ``repro/compat.py`` may (re)define
-                 a top-level ``shard_map``/``set_mesh``/``make_mesh`` —
-                 a shadowing re-export splits the canonical surface.
 SC-AST-F64       float32 device-engine modules (``netsim/*_jax.py``) may
                  touch float64 only on explicitly annotated host-side
                  staging lines (``# staticcheck: ok SC-AST-F64 (...)``).
@@ -32,8 +24,6 @@ from typing import Iterable, List, Optional, Sequence, Tuple
 
 from repro.staticcheck.findings import Finding, WARNING, allowed_lines
 
-COMPAT_SURFACE = ("shard_map", "set_mesh", "make_mesh")
-COMPAT_MODULE = os.path.join("repro", "compat.py")
 SCAN_DIRS = ("src", "tests", "benchmarks", "examples", "scripts")
 ENGINE_F64_GLOBS = ("*/netsim/*_jax.py",)
 LOCKSTEP_PAIRS: Tuple[Tuple[str, str], ...] = (
@@ -61,77 +51,6 @@ def iter_py_files(root: str, dirs: Sequence[str] = SCAN_DIRS) -> Iterable[str]:
 
 def _rel(root: str, path: str) -> str:
     return os.path.relpath(path, root)
-
-
-def _is_compat(rel: str) -> bool:
-    return rel.replace(os.sep, "/").endswith("repro/compat.py")
-
-
-def check_compat_policy(root: str, path: str, tree: ast.AST,
-                        source: str) -> List[Finding]:
-    """SC-AST-COMPAT + SC-AST-SHADOW on one parsed module."""
-    rel = _rel(root, path)
-    if _is_compat(rel):
-        return []
-    out: List[Finding] = []
-
-    def flag(rule: str, node: ast.AST, msg: str) -> None:
-        out.append(Finding(rule, msg, path=rel, line=node.lineno))
-
-    for node in ast.walk(tree):
-        if isinstance(node, ast.ImportFrom) and node.module:
-            mod = node.module
-            if mod.startswith("jax.experimental.shard_map"):
-                flag("SC-AST-COMPAT", node,
-                     "import jax.experimental.shard_map directly — use "
-                     "repro.compat.shard_map")
-            elif mod == "jax.experimental" and any(
-                a.name == "shard_map" for a in node.names
-            ):
-                flag("SC-AST-COMPAT", node,
-                     "from jax.experimental import shard_map — use "
-                     "repro.compat.shard_map")
-            elif mod == "jax" and any(
-                a.name in COMPAT_SURFACE for a in node.names
-            ):
-                flag("SC-AST-COMPAT", node,
-                     "import the mesh surface from repro.compat, not jax")
-        elif isinstance(node, ast.Import):
-            for a in node.names:
-                if a.name.startswith("jax.experimental.shard_map"):
-                    flag("SC-AST-COMPAT", node,
-                         "import jax.experimental.shard_map directly — use "
-                         "repro.compat.shard_map")
-        elif isinstance(node, ast.Attribute):
-            if (isinstance(node.value, ast.Name) and node.value.id == "jax"
-                    and node.attr in COMPAT_SURFACE):
-                flag("SC-AST-COMPAT", node,
-                     f"jax.{node.attr} used directly — use "
-                     f"repro.compat.{node.attr}")
-            elif (isinstance(node.value, ast.Attribute)
-                  and node.value.attr == "experimental"
-                  and isinstance(node.value.value, ast.Name)
-                  and node.value.value.id == "jax"
-                  and node.attr == "shard_map"):
-                flag("SC-AST-COMPAT", node,
-                     "jax.experimental.shard_map used directly — use "
-                     "repro.compat.shard_map")
-
-    body = getattr(tree, "body", [])
-    for node in body:
-        names: List[str] = []
-        if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
-            names = [node.name]
-        elif isinstance(node, ast.Assign):
-            names = [t.id for t in node.targets if isinstance(t, ast.Name)]
-        for name in names:
-            if name in COMPAT_SURFACE:
-                out.append(Finding(
-                    "SC-AST-SHADOW",
-                    f"top-level `{name}` shadows the canonical "
-                    f"repro.compat.{name} surface",
-                    path=rel, line=node.lineno))
-    return out
 
 
 def check_engine_f64(root: str, path: str, tree: ast.AST,
@@ -225,7 +144,6 @@ def scan_tree(root: str, diff_base: Optional[str] = None,
             out.append(Finding("SC-AST-PARSE", f"syntax error: {e}",
                                path=_rel(root, path), line=e.lineno))
             continue
-        out += check_compat_policy(root, path, tree, source)
         out += check_engine_f64(root, path, tree, source)
     out += check_kernel_trios(root)
     if lockstep:

@@ -7,7 +7,7 @@ Stages (select with ``--layers``):
   fault-mask artifact (SC-INV-FAULT, incl. each design's declared
   switch-fault budget).
 * ``ast``        — walk every .py under src/tests/benchmarks/examples/
-  scripts for the compat/lockstep/trio/f64 policies.
+  scripts for the lockstep/trio/f64 policies.
 * ``jaxpr``      — trace the thirteen engine entry points (dense +
   sparse + tiled-flow netsim engines plus their faulted lowerings, five
   Pallas kernels) and run the f64/callback/recompile rules.

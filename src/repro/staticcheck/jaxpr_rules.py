@@ -57,7 +57,7 @@ class TracedEntry:
     name: str
     path: str          # repo-relative module path
     line: int
-    jaxpr: object      # jax.core.ClosedJaxpr
+    jaxpr: object      # jax.extend.core.ClosedJaxpr
 
 
 def _src_location(fn) -> Tuple[str, int]:
@@ -219,11 +219,10 @@ def trace_entrypoints(
 ) -> Tuple[List[TracedEntry], List[Finding]]:
     """Abstractly trace every engine entry point under enable_x64."""
     import jax
-    from jax.experimental import enable_x64
 
     entries: List[TracedEntry] = []
     findings: List[Finding] = []
-    with enable_x64():
+    with jax.enable_x64(True):
         for name, fn, build_args in _entry_specs():
             if only and not any(o in name for o in only):
                 continue
@@ -241,12 +240,12 @@ def trace_entrypoints(
 
 def _walk_jaxpr(jaxpr, visit) -> None:
     """Depth-first over eqns, recursing into any sub-jaxpr params."""
-    import jax
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def maybe_recurse(v):
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, ClosedJaxpr):
             _walk_jaxpr(v.jaxpr, visit)
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, Jaxpr):
             _walk_jaxpr(v, visit)
         elif isinstance(v, (tuple, list)):
             for x in v:

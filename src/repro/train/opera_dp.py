@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.core import collectives as C
 from repro.models.model import loss_fn
@@ -84,7 +83,7 @@ def make_opera_dp_train_step(
 
     batch_spec = P(tuple(pctx.dp_axes))
     rep = P()
-    mapped = shard_map(
+    mapped = jax.shard_map(
         per_shard,
         mesh=mesh,
         in_specs=(rep, rep, rep, batch_spec),

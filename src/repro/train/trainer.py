@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import HAS_PARTIAL_MANUAL, shard_map
 from repro.configs.base import ModelConfig
 from repro.core import collectives as C
 from repro.models.model import loss_fn
@@ -35,16 +34,10 @@ from repro.optim.adamw import AdamWConfig, adamw_update, init_opt_state
 
 
 def make_train_step(cfg: ModelConfig, pctx: ParallelContext, opt: AdamWConfig):
-    # The explicit rotor pod region is a *partial* shard_map (only `pod`
-    # manual; data/model stay GSPMD-auto inside).  On jax 0.4.x that
-    # binding aborts in XLA (compat.HAS_PARTIAL_MANUAL), so rotor grad
-    # sync degrades to GSPMD-inserted inter-pod collectives there — the
-    # update math is identical, only the collective schedule differs.
     use_rotor_pod = (
         cfg.grad_sync == "rotor"
         and pctx.pod_axis is not None
         and pctx.mesh is not None
-        and HAS_PARTIAL_MANUAL
     )
 
     def grads_and_metrics(params, batch, inner_pctx):
@@ -98,7 +91,7 @@ def make_train_step(cfg: ModelConfig, pctx: ParallelContext, opt: AdamWConfig):
 
         # bind ONLY the pod axis; data/model stay GSPMD-auto inside
         rep = P()  # params replicated across pods (sharded inside by auto axes)
-        fn = shard_map(
+        fn = jax.shard_map(
             per_pod,
             mesh=pctx.mesh,
             in_specs=(rep, rep, P(pod)),

@@ -14,15 +14,15 @@ import numpy as np  # noqa: E402
 from jax import lax  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
+from repro.launch.mesh import auto_mesh  # noqa: E402
 from repro.core import collectives as C  # noqa: E402
 
-mesh = compat.make_mesh((2, 4), ("pod", "data"))
+mesh = auto_mesh((2, 4), ("pod", "data"))
 rng = np.random.default_rng(0)
 
 
 def run(fn, x, in_spec, out_spec):
-    f = compat.shard_map(fn, mesh=mesh, in_specs=(in_spec,), out_specs=out_spec,
+    f = jax.shard_map(fn, mesh=mesh, in_specs=(in_spec,), out_specs=out_spec,
                          check_vma=False)
     return jax.jit(f)(x)
 

@@ -9,7 +9,6 @@ import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax
-from repro import compat  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
@@ -17,8 +16,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 from repro.configs import get_config  # noqa: E402
 from repro.configs.base import reduced_config  # noqa: E402
 from repro.data.pipeline import SyntheticLM  # noqa: E402
-from repro.compat import make_mesh  # noqa: E402
-from repro.launch.mesh import pctx_for_mesh  # noqa: E402
+from repro.launch.mesh import auto_mesh, pctx_for_mesh  # noqa: E402
 from repro.models import init_params  # noqa: E402
 from repro.models.model import loss_fn, param_shapes  # noqa: E402
 from repro.models.parallel import single_device_ctx  # noqa: E402
@@ -30,7 +28,7 @@ from repro.train.opera_dp import (  # noqa: E402
 )
 from repro.train.trainer import init_train_state, make_train_step  # noqa: E402
 
-mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = auto_mesh((2, 2, 2), ("pod", "data", "model"))
 opt = AdamWConfig(lr=1e-3, warmup_steps=2, total_steps=10)
 
 # ---------------- dense arch: gspmd + opera-dp vs single device ------------
@@ -51,7 +49,7 @@ ref_loss = float(m_ref["loss"])
 # gspmd multi-device (params sharded by rules; batch sharded over dp)
 pctx = pctx_for_mesh(mesh, grad_sync="xla")
 shardings = param_shardings(param_shapes(cfg), cfg, pctx)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     sh_params = jax.device_put(params, shardings)
     state = init_train_state(cfg, sh_params)
     bsh = jax.tree.map(
@@ -66,7 +64,7 @@ print("ok: gspmd multi-device trainer matches single-device loss")
 
 # rotor pod-sync trainer
 pctx_r = pctx_for_mesh(mesh, grad_sync="rotor")
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     state_r = init_train_state(cfg, jax.device_put(params, shardings))
     state_r, m_r = jax.jit(make_train_step(cfg, pctx_r, opt))(state_r, bsh)
 assert abs(float(m_r["loss"]) - ref_loss) < 1e-3
@@ -78,7 +76,7 @@ for x, y in zip(pa, pb):
 print("ok: rotor pod-sync trainer matches gspmd updates")
 
 # opera-dp explicit trainer
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     s_dp = init_opera_dp_state(params)
     s_dp, m_dp = jax.jit(make_opera_dp_train_step(cfg, pctx_r, opt))(s_dp, batch)
 assert abs(float(m_dp["loss"]) - ref_loss) < 1e-3
@@ -95,7 +93,7 @@ losses = {}
 for dispatch in ("rotor", "rotor_vlb", "xla"):
     pctx_m = pctx_for_mesh(mesh, moe_dispatch=dispatch)
     mshard = param_shardings(param_shapes(mcfg), mcfg, pctx_m)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         shp = jax.device_put(mparams, mshard)
         bsh = jax.tree.map(
             lambda x: jax.device_put(

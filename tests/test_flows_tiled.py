@@ -111,8 +111,15 @@ class TestTiledParity:
         assert small.peak_window_tiles == ample.peak_window_tiles
         for a, b in zip(small.hists, ample.hists):
             assert np.array_equal(a, b)
+        # backlog_frac is (rem_end - rem_mid) / offered over two float32
+        # device sums of the (W, T) window.  XLA picks a reduction's
+        # association by the operand's shape, so a grown window (another
+        # W) re-associates the sum: allow 32 float32 ulps of it.  Every
+        # other field is computed from bitwise-equal state and must match.
         for a, b in zip(small.results, ample.results):
-            assert a == b
+            assert a.backlog_frac == pytest.approx(b.backlog_frac,
+                                                   rel=32 * 2.0**-24)
+            assert dataclasses.replace(a, backlog_frac=b.backlog_frac) == b
 
 
 def _pad(scn, npad=37):

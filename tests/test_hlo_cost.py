@@ -3,13 +3,13 @@ import jax
 
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 import jax.numpy as jnp
 import pytest
 from jax import lax
 
 from repro.analysis.hlo import collective_bytes
 from repro.analysis.hlo_cost import analyze
+from repro.launch.mesh import auto_mesh
 
 
 def _compile(f, *specs):
@@ -81,7 +81,7 @@ class TestCollectiveAccounting:
     def test_psum_inside_scan_multiplied(self):
         """Naive text grep counts loop collectives once; analyze() must
         multiply by trip count."""
-        mesh = compat.make_mesh((1,), ("d",))
+        mesh = auto_mesh((1,), ("d",))
 
         def f(x):
             def per(a):
@@ -89,7 +89,7 @@ class TestCollectiveAccounting:
                     return lax.psum(c, "d") * 0.5, None
                 y, _ = lax.scan(body, a, None, length=7)
                 return y
-            return compat.shard_map(
+            return jax.shard_map(
                 per, mesh=mesh, in_specs=P("d"), out_specs=P("d"),
                 check_vma=False,
             )(x)

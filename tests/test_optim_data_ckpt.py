@@ -2,13 +2,12 @@
 import os
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.data.pipeline import SyntheticLM
+from repro.launch.mesh import auto_mesh
 from repro.optim.adamw import (
     AdamWConfig,
     adamw_update,
@@ -118,7 +117,7 @@ class TestCheckpoint:
         ck = Checkpointer(str(tmp_path))
         state = self._state()
         ck.save(7, state, blocking=True)
-        mesh = compat.make_mesh((1, 1), ("data", "model"))
+        mesh = auto_mesh((1, 1), ("data", "model"))
         shardings = jax.tree.map(
             lambda _: NamedSharding(mesh, P()), state
         )

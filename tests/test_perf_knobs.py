@@ -1,14 +1,13 @@
 """The §Perf levers must preserve semantics: chunked CE == standard CE,
 bf16 normalize ~= fp32 normalize, layouts don't change the math."""
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_config
 from repro.configs.base import reduced_config
+from repro.launch.mesh import auto_mesh
 from repro.models import init_params, loss_fn
 from repro.models.layers import apply_norm, init_norm
 from repro.models.model import softmax_xent, softmax_xent_chunked
@@ -86,7 +85,6 @@ class TestNormDowncast:
 class TestLayouts:
     def test_dp_only_pctx_math_unchanged(self):
         """dp_only must be a layout change only: same loss on 1 device."""
-        from repro.compat import make_mesh
         from repro.launch.mesh import pctx_for_mesh
 
         cfg = reduced_config(get_config("smollm-360m")).replace(num_layers=2)
@@ -97,8 +95,8 @@ class TestLayouts:
             "targets": jnp.asarray(RNG.integers(0, cfg.vocab_size, (2, 8)),
                                    jnp.int32),
         }
-        mesh = make_mesh((1, 1), ("data", "model"))
-        with compat.set_mesh(mesh):
+        mesh = auto_mesh((1, 1), ("data", "model"))
+        with jax.set_mesh(mesh):
             t1, _ = loss_fn(params, batch, cfg, pctx_for_mesh(mesh))
             t2, _ = loss_fn(params, batch, cfg,
                             pctx_for_mesh(mesh, layout="dp_only"))

@@ -9,8 +9,9 @@ Three contracts under test:
      grouped reconfiguration darkens (at least) `groups` whole columns
      per slice.
   2. The `kernels/rotor_slice` trio agrees with itself (Pallas
-     interpret path vs jnp ref path, bitwise — same jitted expression
-     graph) and with the numpy oracle `fluid.rotor_slice_step`.
+     interpret path vs jnp ref path, bitwise — the kernel's one-hot
+     gathers are exact and it sums in the ref's order) and with the
+     numpy oracle `fluid.rotor_slice_step`.
   3. The sparse batch drivers (`_run_batch_sparse`, and the faulted
      engine behind ``engine="sparse"``) match the dense scan engine on
      full trajectories, unfaulted and under a nonempty

@@ -203,52 +203,17 @@ def _scan_src(tmp_path, rel, source):
     """Write `source` at tmp_path/rel and run the per-file AST rules."""
     import ast as ast_mod
 
-    from repro.staticcheck.ast_rules import check_compat_policy, check_engine_f64
+    from repro.staticcheck.ast_rules import check_engine_f64
 
     path = tmp_path / rel
     path.parent.mkdir(parents=True, exist_ok=True)
     path.write_text(textwrap.dedent(source))
     tree = ast_mod.parse(path.read_text())
     root = str(tmp_path)
-    return (check_compat_policy(root, str(path), tree, path.read_text())
-            + check_engine_f64(root, str(path), tree, path.read_text()))
+    return check_engine_f64(root, str(path), tree, path.read_text())
 
 
 class TestAstRules:
-    def test_direct_experimental_shard_map_flagged(self, tmp_path):
-        found = _scan_src(tmp_path, "src/repro/x.py",
-                          "from jax.experimental.shard_map import shard_map\n")
-        assert rules(found) == {"SC-AST-COMPAT"}
-
-    def test_jax_attribute_surface_flagged(self, tmp_path):
-        found = _scan_src(tmp_path, "src/repro/y.py", """\
-            import jax
-            mesh = jax.make_mesh((1,), ("d",))
-            jax.set_mesh(mesh)
-            f = jax.shard_map(lambda x: x, mesh=mesh, in_specs=None,
-                              out_specs=None)
-            g = jax.experimental.shard_map.shard_map
-            """)
-        found_rules = [f.rule for f in found]
-        assert found_rules.count("SC-AST-COMPAT") == 4
-
-    def test_compat_module_exempt(self, tmp_path):
-        found = _scan_src(tmp_path, "src/repro/compat.py", """\
-            import jax
-            def shard_map(f, **kw):
-                return jax.shard_map(f, **kw)
-            """)
-        assert found == []
-
-    def test_shadowing_compat_surface_flagged(self, tmp_path):
-        found = _scan_src(tmp_path, "src/repro/launch/m.py", """\
-            from repro.compat import make_mesh as _mm
-            def make_mesh(shape, axes):
-                return _mm(shape, axes)
-            set_mesh = None
-            """)
-        assert [f.rule for f in found] == ["SC-AST-SHADOW", "SC-AST-SHADOW"]
-
     def test_engine_f64_requires_directive(self, tmp_path):
         src = """\
             import numpy as np
@@ -290,7 +255,7 @@ class TestAstRules:
                                "src/repro/netsim/flows.py",
                                "src/repro/netsim/flows_jax.py"])
         assert both == []
-        unrelated = check_lockstep(["ROADMAP.md", "src/repro/compat.py"])
+        unrelated = check_lockstep(["ROADMAP.md", "src/repro/launch/mesh.py"])
         assert unrelated == []
 
     def test_lockstep_faults_coupling(self):
@@ -358,14 +323,13 @@ class TestJaxprRules:
         import jax
         import jax.numpy as jnp
         import numpy as np
-        from jax.experimental import enable_x64
 
         from repro.staticcheck.jaxpr_rules import TracedEntry, check_float64
 
         def leaky(x):
             return x * jnp.asarray(np.float64(2.0))  # f64 constant promotes
 
-        with enable_x64():
+        with jax.enable_x64(True):
             closed = jax.make_jaxpr(leaky)(
                 jax.ShapeDtypeStruct((4,), jnp.float32))
         found = check_float64([TracedEntry("leaky", "x.py", 1, closed)])

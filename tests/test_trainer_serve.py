@@ -1,7 +1,5 @@
 """Integration: training learns, checkpoint-restart is exact, serving runs."""
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -9,6 +7,7 @@ import pytest
 from repro.configs import get_config
 from repro.configs.base import reduced_config
 from repro.data.pipeline import SyntheticLM, device_batches
+from repro.launch.mesh import auto_mesh
 from repro.models import init_params
 from repro.models.parallel import single_device_ctx
 from repro.optim.adamw import AdamWConfig
@@ -19,7 +18,7 @@ from repro.train.trainer import init_train_state, make_train_step
 
 
 def _mesh11():
-    return compat.make_mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))
 
 
 def _tiny():
@@ -59,7 +58,7 @@ class TestTrainerLearns:
         from repro.launch.mesh import pctx_for_mesh
 
         pctx = pctx_for_mesh(mesh)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             s1 = init_train_state(cfg, params)
             s1, m1 = jax.jit(make_train_step(cfg, pctx, opt))(s1, batch)
             s2 = init_opera_dp_state(params)
@@ -81,7 +80,7 @@ class TestTrainerLearns:
 
         pctx = pctx_for_mesh(mesh)
         src = SyntheticLM(cfg.vocab_size, 32, 8, seed=2)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             step = jax.jit(
                 make_opera_dp_train_step(cfg, pctx, opt, compress=True)
             )
