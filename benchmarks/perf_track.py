@@ -1,172 +1,22 @@
-"""PR-over-PR step-time tracking for both perf-tracked hot paths:
-the rotor engines (dense vs permutation-sparse) and the flow engines
-(dense vs tiled-streaming).
+"""Engine parity gates: the sparse-vs-dense rotor gate and the
+tiled-vs-dense flow gate, full engine runs at small points, faulted and
+unfaulted.  `scripts/ci_tier1.sh` runs them; the process exits nonzero
+on drift.
 
-Rotor section: measures the median per-step, per-scenario wall time of
-both fluid engines at representative Appendix-B design points — the two
-paper-table fabrics (k8-n16, k12-n108 at both group counts) and one
-k >= 32 point the dense path never covered — and records them into the
-root-level ``BENCH_netsim.json`` with an append-only history keyed by
-commit, so regressions in either engine show up as a diff in review.
+    PYTHONPATH=src:. python -m benchmarks.perf_track --fast
 
-Both rotor engines run *truncated* slice sets (``SLICES_MEASURED``
-steps) on identical demand batches: step time is shape-stationary
-across a run, so a short prefix measures the same thing as a full sweep
-while keeping the dense (S, N, N) adjacency tractable at N = 432 (the
-full 432-slice tensor is ~320 MB; 16 slices are ~12).  The truncated
-dense adjacency is rebuilt from the index tensor rather than
-`matching_tensor()` for the same reason.
-
-Flow section: measures dense-vs-tiled per-step wall time and peak
-device flow state on synthetic short-flow streams (``FLOW_SIZES``
-flows over ``FLOW_STEPS`` fixed-dt steps) and records them into
-``BENCH_flows.json`` with the same commit-keyed history.  Dense
-per-step time comes from differencing two truncated-horizon runs (the
-same shape-stationarity argument; differencing cancels host staging),
-tiled from a full end-to-end run including its host chunk loop.
-
-``--fast`` skips timing entirely and runs both parity gates — the
-sparse-vs-dense rotor gate and the tiled-vs-dense flow gate (full
-engine runs at small points, faulted and unfaulted) — the mode
-`scripts/ci_tier1.sh` wires in; exits nonzero on drift.
+They time nothing: speed is measured on the chip by `bench/run.py`
+(see PERF.md).
 """
 from __future__ import annotations
 
 import argparse
-import json
-import subprocess
 import sys
-import time
-from pathlib import Path
 
 import numpy as np
 
-from benchmarks.common import banner, check, save
+from benchmarks.common import banner, check
 from repro.netsim.sweep import DesignPoint
-
-REPO_ROOT = Path(__file__).resolve().parent.parent
-BENCH_PATH = REPO_ROOT / "BENCH_netsim.json"
-BENCH_FLOWS_PATH = REPO_ROOT / "BENCH_flows.json"
-
-POINTS = (
-    DesignPoint(k=8, num_racks=16, groups=1),
-    DesignPoint(k=12, num_racks=108, groups=1),
-    DesignPoint(k=12, num_racks=108, groups=2),
-    DesignPoint(k=32, num_racks=432, groups=1),
-    DesignPoint(k=32, num_racks=512, groups=2),
-)
-BATCH = 4
-SLICES_MEASURED = 16
-REPEATS = 7
-# acceptance bar: at N >= this, sparse must beat dense by SPEEDUP_MIN
-SPEEDUP_AT_RACKS = 432
-SPEEDUP_MIN = 2.0
-
-# flow-engine section: synthetic short-flow streams of this many flows
-# over FLOW_STEPS steps; dense per-step time is differenced between
-# runs truncated to FLOW_DENSE_STEPS
-FLOW_SIZES = (32768, 131072, 393216)
-FLOW_STEPS = 1500
-FLOW_DENSE_STEPS = (150, 450)
-FLOW_REPEATS = 5
-# acceptance bar: at the largest size, tiled must beat dense 2x in
-# step time OR peak device flow state
-FLOW_WIN_MIN = 2.0
-
-
-def _build_point(dp: DesignPoint):
-    """Topology + truncated index/dense slice tensors + a demand batch."""
-    from repro.core.topology import (
-        build_lifted_opera_topology,
-        build_opera_topology,
-    )
-    from repro.netsim.sweep import LIFTED_TOPO_RACKS, scenario_demand
-
-    cfg = dp.to_config()
-    if cfg.num_racks > LIFTED_TOPO_RACKS:
-        topo = build_lifted_opera_topology(
-            cfg.num_racks, cfg.u, seed=dp.topo_seed, groups=cfg.groups)
-    else:
-        topo = build_opera_topology(
-            cfg.num_racks, cfg.u, seed=dp.topo_seed, groups=cfg.groups)
-    s = min(SLICES_MEASURED, topo.num_slices)
-    dst = topo.matching_index_tensor()[:s]            # (s, N, u)
-    n = cfg.num_racks
-    adj = np.zeros((s, n, n), np.float32)
-    t_idx, i_idx, s_idx = np.nonzero(dst < n)
-    adj[t_idx, i_idx, dst[t_idx, i_idx, s_idx]] = 1.0
-    demands = np.stack([
-        scenario_demand("permutation", cfg, 0.3, seed) for seed in range(BATCH)
-    ])
-    return cfg, dst, adj, demands
-
-
-def measure_point(dp: DesignPoint) -> dict:
-    import jax.numpy as jnp
-
-    from repro.core.schedule import cycle_timing, slice_capacity_bytes
-    from repro.netsim.fluid_jax import _run_batch, _run_batch_sparse
-
-    cfg, dst, adj, demands = _build_point(dp)
-    cap = slice_capacity_bytes(cfg, cycle_timing(cfg))
-    own0 = jnp.asarray(demands / cap, jnp.float32)
-    adj_j = jnp.asarray(adj)
-    dst_j = jnp.asarray(dst)
-    s = dst.shape[0]
-
-    def run_dense():
-        _run_batch(adj_j, own0, True, 1)[2].block_until_ready()
-
-    def run_sparse():
-        _run_batch_sparse(dst_j, own0, True, 1)[2].block_until_ready()
-
-    # Interleave the two engines within each round so clock drift and
-    # cache/allocator state hit both equally; the speedup is the median
-    # of per-round ratios, not a ratio of medians.
-    run_dense(), run_sparse()              # warmup / compile
-    dense_t, sparse_t = [], []
-    for _ in range(REPEATS):
-        t0 = time.perf_counter()
-        run_dense()
-        dense_t.append(time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        run_sparse()
-        sparse_t.append(time.perf_counter() - t0)
-    scale = 1e6 / s / BATCH
-    ratios = [d / sp for d, sp in zip(dense_t, sparse_t)]
-    return dict(
-        num_racks=dp.num_racks, k=dp.k, groups=dp.groups,
-        slices_measured=s, batch=BATCH,
-        dense_us=round(float(np.median(dense_t)) * scale, 1),
-        sparse_us=round(float(np.median(sparse_t)) * scale, 1),
-        speedup=round(float(np.median(ratios)), 2),
-    )
-
-
-def _git_head() -> str:
-    try:
-        return subprocess.run(
-            ["git", "rev-parse", "--short", "HEAD"], cwd=REPO_ROOT,
-            capture_output=True, text=True, check=True,
-        ).stdout.strip()
-    except Exception:  # noqa: BLE001
-        return "unknown"
-
-
-def _record(points: dict, path: Path = BENCH_PATH) -> dict:
-    doc = dict(updated="", points={}, history=[])
-    if path.exists():
-        try:
-            doc = json.loads(path.read_text())
-        except json.JSONDecodeError:
-            pass
-    stamp = time.strftime("%Y-%m-%d")
-    doc["updated"] = stamp
-    doc["points"] = points
-    doc.setdefault("history", []).append(
-        dict(commit=_git_head(), date=stamp, points=points))
-    path.write_text(json.dumps(doc, indent=1) + "\n")
-    return doc
 
 
 def parity_gate(tol: float = 1e-5) -> bool:
@@ -208,97 +58,6 @@ def parity_gate(tol: float = 1e-5) -> bool:
                     f"{dp.name} {'faulted' if fs else 'clean'} {field} "
                     f"drift < {tol}", drift < tol, f"{drift:.2e}")
     return ok
-
-
-def _stream_scenario(num_flows: int, num_steps: int = FLOW_STEPS,
-                     seed: int = 0):
-    """Synthetic mostly-short-flow stream: `num_flows` Poisson-ish
-    arrivals over 80% of the horizon, lognormal sizes with a clipped
-    heavy tail (all three FCT classes populated), single latency pool
-    provisioned at 1.5x the offered rate — the admitted regime the
-    tiled engine targets, where the concurrently-active population is a
-    sliver of the lifetime flow count."""
-    from repro.netsim.flows import FlowScenario
-
-    dt_s = 1e-3
-    horizon_s = 0.8 * num_steps * dt_s
-    tail_s = 0.2 * num_steps * dt_s
-    link_gbps = 10.0
-    unit = link_gbps * 1e9 / 8.0 * dt_s          # bytes per NIC-step
-    rng = np.random.default_rng(seed)
-    arr = np.sort(rng.uniform(0.0, horizon_s, num_flows))
-    sizes = np.clip(
-        rng.lognormal(mean=np.log(0.3 * unit), sigma=1.5, size=num_flows),
-        1e3, 30.0 * unit)
-    offered_Bps = sizes.sum() / horizon_s
-    return FlowScenario(
-        network="synthetic", workload="stream", load=0.0, seed=seed,
-        horizon_s=horizon_s, dt_s=dt_s, tail_s=tail_s,
-        num_hosts=1, link_gbps=link_gbps,
-        arr=arr, sizes=sizes,
-        start_step=np.ceil(arr / dt_s).astype(np.int32),
-        is_bulk=np.zeros(num_flows, bool),
-        lat_pool_Bps=float(1.5 * offered_Bps), bulk_pool_Bps=0.0,
-    )
-
-
-def measure_flow_point(num_flows: int) -> dict:
-    import dataclasses
-
-    from repro.netsim.flows_jax import (
-        DEFAULT_TILE,
-        dense_state_bytes,
-        simulate_flows_batch,
-        tiled_state_bytes,
-    )
-
-    scn = _stream_scenario(num_flows)
-
-    # dense per-step time by differencing two truncated horizons: the
-    # per-step cost is shape-stationary, and the difference cancels the
-    # O(n) host staging both runs pay.
-    def dense_run(steps):
-        trunc = dataclasses.replace(
-            scn, horizon_s=steps * scn.dt_s, tail_s=0.0)
-        simulate_flows_batch([trunc], engine="dense")
-
-    s_lo, s_hi = FLOW_DENSE_STEPS
-    dense_run(s_lo), dense_run(s_hi)           # warmup / compile
-    dense_t = []
-    for _ in range(FLOW_REPEATS):
-        t0 = time.perf_counter()
-        dense_run(s_lo)
-        t_lo = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        dense_run(s_hi)
-        t_hi = time.perf_counter() - t0
-        dense_t.append((t_hi - t_lo) / (s_hi - s_lo))
-
-    # tiled end-to-end over the full horizon, host chunk loop included
-    def tiled_run():
-        return simulate_flows_batch([scn], engine="tiled")
-
-    res = tiled_run()                          # warmup / compile
-    tiled_t = []
-    for _ in range(FLOW_REPEATS):
-        t0 = time.perf_counter()
-        tiled_run()
-        tiled_t.append((time.perf_counter() - t0) / scn.steps)
-
-    dense_us = float(np.median(dense_t)) * 1e6
-    tiled_us = float(np.median(tiled_t)) * 1e6
-    dense_b = dense_state_bytes(num_flows)
-    tiled_b = tiled_state_bytes(res.peak_window_tiles, DEFAULT_TILE)
-    return dict(
-        num_flows=num_flows, steps=scn.steps,
-        tile=DEFAULT_TILE, peak_window_tiles=res.peak_window_tiles,
-        dense_us_step=round(dense_us, 1),
-        tiled_us_step=round(tiled_us, 1),
-        speedup=round(dense_us / tiled_us, 2),
-        dense_state_mb=round(dense_b / 1e6, 2),
-        tiled_state_mb=round(tiled_b / 1e6, 2),
-        state_ratio=round(dense_b / tiled_b, 2),
-    )
 
 
 def flow_parity_gate() -> bool:
@@ -365,68 +124,19 @@ def flow_parity_gate() -> bool:
     return ok
 
 
-def run(fast: bool = False) -> dict:
-    banner("Engine perf tracking — dense vs permutation-sparse step time")
-    if fast:
-        ok = parity_gate()
-        ok_flow = flow_parity_gate()
-        return dict(mode="fast", checks=dict(parity=ok, flow_parity=ok_flow))
-
-    points = {}
-    for dp in POINTS:
-        r = measure_point(dp)
-        points[dp.name] = r
-        print(f"  {dp.name:14s} dense={r['dense_us']:8.1f} us/step/scn  "
-              f"sparse={r['sparse_us']:8.1f}  speedup={r['speedup']:.2f}x")
-    doc = _record(points)
-    print(f"  recorded -> {BENCH_PATH.relative_to(REPO_ROOT)} "
-          f"(history: {len(doc['history'])} entries)")
-
-    big = [r for r in points.values() if r["num_racks"] >= SPEEDUP_AT_RACKS]
-    ok_speed = check(
-        f"sparse >= {SPEEDUP_MIN}x dense at N >= {SPEEDUP_AT_RACKS}",
-        bool(big) and all(r["speedup"] >= SPEEDUP_MIN for r in big),
-        ", ".join(f"N={r['num_racks']}: {r['speedup']:.2f}x" for r in big))
-    ok_parity = parity_gate()
-
-    banner("Flow engine perf tracking — dense vs tiled streaming")
-    fpoints = {}
-    for n in FLOW_SIZES:
-        r = measure_flow_point(n)
-        fpoints[f"n{n}"] = r
-        print(f"  n={n:<8d} dense={r['dense_us_step']:8.1f} us/step  "
-              f"tiled={r['tiled_us_step']:8.1f}  "
-              f"speedup={r['speedup']:.2f}x  "
-              f"state {r['dense_state_mb']:.1f} -> {r['tiled_state_mb']:.1f} "
-              f"MB ({r['state_ratio']:.1f}x)")
-    fdoc = _record(fpoints, BENCH_FLOWS_PATH)
-    print(f"  recorded -> {BENCH_FLOWS_PATH.relative_to(REPO_ROOT)} "
-          f"(history: {len(fdoc['history'])} entries)")
-
-    largest = fpoints[f"n{max(FLOW_SIZES)}"]
-    ok_flow_win = check(
-        f"tiled >= {FLOW_WIN_MIN}x dense (step time or state) at "
-        f"n={max(FLOW_SIZES)}",
-        largest["speedup"] >= FLOW_WIN_MIN
-        or largest["state_ratio"] >= FLOW_WIN_MIN,
-        f"speedup={largest['speedup']:.2f}x, "
-        f"state={largest['state_ratio']:.2f}x")
-    ok_flow_parity = flow_parity_gate()
-    return dict(points=points, flow_points=fpoints,
-                checks=dict(speedup=ok_speed, parity=ok_parity,
-                            flow_win=ok_flow_win,
-                            flow_parity=ok_flow_parity))
+def run() -> dict:
+    banner("Engine parity gates — sparse vs dense rotor, tiled vs dense flow")
+    return dict(checks=dict(parity=parity_gate(),
+                            flow_parity=flow_parity_gate()))
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--fast", action="store_true",
-                    help="parity gate only, no timing (CI mode)")
-    args = ap.parse_args(argv)
-    out = run(fast=args.fast)
-    if not args.fast:
-        save("perf_track", out)
-    if not all(out["checks"].values()):
+                    help="accepted for existing callers: the gates are the "
+                         "only mode")
+    ap.parse_args(argv)
+    if not all(run()["checks"].values()):
         sys.exit(1)
 
 

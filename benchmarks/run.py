@@ -27,7 +27,6 @@ MODULES = [
     ("perf_track", "benchmarks.perf_track"),
     ("table1_appD", "benchmarks.table1_appD"),
     ("bench_rotor_collectives", "benchmarks.bench_rotor_collectives"),
-    ("bench_roofline", "benchmarks.bench_roofline"),
 ]
 
 

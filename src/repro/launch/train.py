@@ -113,15 +113,18 @@ def main(argv=None):
           f" params), mesh {dict(mesh.shape)}, trainer={args.trainer}, "
           f"floor={src.conditional_entropy():.3f} nats")
     t0 = time.time()
-    losses = []
+    # the device's loss scalars, read only at log steps and at the end:
+    # a read every step would wait for the device every step
+    step_losses = []
     with jax.set_mesh(mesh):
         for step in range(start_step, args.steps):
-            batch = next(batches)
-            state, metrics = jitted(state, batch)
-            losses.append(float(metrics["loss"]))
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                batch = next(batches)
+                state, metrics = jitted(state, batch)
+            step_losses.append(metrics["loss"])
             if step % args.log_every == 0 or step == args.steps - 1:
                 print(
-                    f"[train] step {step:5d} loss {losses[-1]:.4f} "
+                    f"[train] step {step:5d} loss {float(metrics['loss']):.4f} "
                     f"gnorm {float(metrics['grad_norm']):.3f} "
                     f"lr {float(metrics['lr']):.2e} "
                     f"({(time.time() - t0):.1f}s)",
@@ -131,6 +134,7 @@ def main(argv=None):
                 ckpt.save(step + 1, state)
     if ckpt:
         ckpt.save(args.steps, state, blocking=True)
+    losses = [float(x) for x in jax.device_get(step_losses)]
     print(f"[train] done: loss {losses[0]:.3f} -> {losses[-1]:.3f} "
           f"(floor {src.conditional_entropy():.3f})")
     leaf = jax.tree.leaves(state["params"])[0]

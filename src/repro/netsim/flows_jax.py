@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.netsim.flows import (
     FCT_BIN_LOG2_WIDTH,
     FCT_HIST_BINS,
@@ -532,17 +533,19 @@ def _simulate_flows_tiled(
     num_steps = scenarios[0].steps
     B, T, C = len(scenarios), int(tile_size), int(chunk_steps)
     faulted = any(s.has_faults for s in scenarios)
-    states = [_TiledState(s, T, num_steps, faulted) for s in scenarios]
-
-    lat_u = jnp.asarray([s.lat_pool_Bps / s.nic_Bps for s in scenarios], dtype)
-    bulk_u = jnp.asarray([s.bulk_pool_Bps / s.nic_Bps for s in scenarios], dtype)
-    dt_ms = jnp.asarray([s.dt_s * 1e3 for s in scenarios], dtype)
-    mid_step = jnp.asarray([s.mid_step for s in scenarios], jnp.int32)
-    end_step = jnp.asarray([s.end_step for s in scenarios], jnp.int32)
-    hist = jnp.zeros((B, NUM_FCT_CLASSES * FCT_HIST_BINS), jnp.int32)
-    fct_sum = jnp.zeros((B,), dtype)
-    rem_mid = jnp.zeros((B,), dtype)
-    rem_end = jnp.zeros((B,), dtype)
+    with obs.span("flows.prepare"):
+        states = [_TiledState(s, T, num_steps, faulted) for s in scenarios]
+        lat_u = jnp.asarray([s.lat_pool_Bps / s.nic_Bps for s in scenarios], dtype)
+        bulk_u = jnp.asarray([s.bulk_pool_Bps / s.nic_Bps for s in scenarios], dtype)
+        dt_ms = jnp.asarray([s.dt_s * 1e3 for s in scenarios], dtype)
+        mid_step = jnp.asarray([s.mid_step for s in scenarios], jnp.int32)
+        end_step = jnp.asarray([s.end_step for s in scenarios], jnp.int32)
+        obs.count("flows.h2d_bytes", sum(
+            x.nbytes for x in (lat_u, bulk_u, dt_ms, mid_step, end_step)))
+        hist = jnp.zeros((B, NUM_FCT_CLASSES * FCT_HIST_BINS), jnp.int32)
+        fct_sum = jnp.zeros((B,), dtype)
+        rem_mid = jnp.zeros((B,), dtype)
+        rem_end = jnp.zeros((B,), dtype)
 
     window_names = ("rem", "rem0", "start", "is_bulk", "class_id", "arr_ms")
     if faulted:
@@ -552,70 +555,80 @@ def _simulate_flows_tiled(
     W = int(window_tiles)
     peak_w = 0
     c0 = 0
-    while c0 < num_steps:
-        chunk_end = min(c0 + C, num_steps)
-        ws = [st.window(chunk_end) for st in states]
-        peak_w = max(peak_w, max(ws))
-        if max(ws) == 0:
-            if all(st.done for st in states):
-                break
+    with obs.span("flows.run"):
+        while c0 < num_steps:
+            chunk_end = min(c0 + C, num_steps)
+            ws = [st.window(chunk_end) for st in states]
+            peak_w = max(peak_w, max(ws))
+            if max(ws) == 0:
+                if all(st.done for st in states):
+                    break
+                c0 += C
+                continue
+            while max(ws) > W:
+                W *= 2
+            with obs.span("flows.tiled.fill"):
+                row = dict(
+                    rem=np.zeros((B, W, T), np.float32),
+                    rem0=np.zeros((B, W, T), np.float32),
+                    start=np.full((B, W, T), num_steps + 1, np.int32),
+                    is_bulk=np.zeros((B, W, T), bool),
+                    class_id=np.zeros((B, W, T), np.int32),
+                    arr_ms=np.zeros((B, W, T), np.float32),
+                )
+                if faulted:
+                    for name in ("blk_start", "blk_end", "frz_start", "frz_end"):
+                        row[name] = np.full((B, W, T), NEVER, np.int32)
+                    lsc = np.ones((B, C), np.float32)
+                    bsc = np.ones((B, C), np.float32)
+                    for b, st in enumerate(states):
+                        lsc[b, :chunk_end - c0] = st.lat_scale[c0:chunk_end]
+                        bsc[b, :chunk_end - c0] = st.bulk_scale[c0:chunk_end]
+                for b, (st, w) in enumerate(zip(states, ws)):
+                    st.fill(row, b, w)
+            with obs.span("flows.tiled.upload"):
+                operands = [jnp.asarray(row[name], dtype) if name in
+                            ("rem", "rem0", "arr_ms") else jnp.asarray(row[name])
+                            for name in window_names]
+                if faulted:
+                    operands += [jnp.asarray(lsc, dtype), jnp.asarray(bsc, dtype)]
+                obs.count("flows.h2d_bytes", sum(x.nbytes for x in operands))
+            with obs.span("flows.tiled.chunk"):
+                if faulted:
+                    rem_out, hist, fct_sum, rem_mid, rem_end = _run_tiled_chunk_faulted(
+                        *operands[:6], lat_u, bulk_u, dt_ms, mid_step, end_step,
+                        *operands[6:], hist, fct_sum, rem_mid, rem_end, c0,
+                        num_steps=num_steps, chunk_steps=C,
+                    )
+                else:
+                    rem_out, hist, fct_sum, rem_mid, rem_end = _run_tiled_chunk(
+                        *operands, lat_u, bulk_u, dt_ms, mid_step, end_step,
+                        hist, fct_sum, rem_mid, rem_end, c0,
+                        num_steps=num_steps, chunk_steps=C,
+                    )
+            with obs.span("flows.tiled.readback"):
+                rem_np = np.asarray(rem_out)
+            with obs.span("flows.tiled.retire"):
+                for b, (st, w) in enumerate(zip(states, ws)):
+                    st.writeback(rem_np[b], w)
+                    st.advance()
             c0 += C
-            continue
-        while max(ws) > W:
-            W *= 2
-        row = dict(
-            rem=np.zeros((B, W, T), np.float32),
-            rem0=np.zeros((B, W, T), np.float32),
-            start=np.full((B, W, T), num_steps + 1, np.int32),
-            is_bulk=np.zeros((B, W, T), bool),
-            class_id=np.zeros((B, W, T), np.int32),
-            arr_ms=np.zeros((B, W, T), np.float32),
-        )
-        if faulted:
-            for name in ("blk_start", "blk_end", "frz_start", "frz_end"):
-                row[name] = np.full((B, W, T), NEVER, np.int32)
-        for b, (st, w) in enumerate(zip(states, ws)):
-            st.fill(row, b, w)
-        operands = [jnp.asarray(row[name], dtype) if name in
-                    ("rem", "rem0", "arr_ms") else jnp.asarray(row[name])
-                    for name in window_names]
-        if faulted:
-            lsc = np.ones((B, C), np.float32)
-            bsc = np.ones((B, C), np.float32)
-            for b, st in enumerate(states):
-                lsc[b, :chunk_end - c0] = st.lat_scale[c0:chunk_end]
-                bsc[b, :chunk_end - c0] = st.bulk_scale[c0:chunk_end]
-            rem_out, hist, fct_sum, rem_mid, rem_end = _run_tiled_chunk_faulted(
-                *operands[:6], lat_u, bulk_u, dt_ms, mid_step, end_step,
-                *operands[6:], jnp.asarray(lsc, dtype), jnp.asarray(bsc, dtype),
-                hist, fct_sum, rem_mid, rem_end, c0,
-                num_steps=num_steps, chunk_steps=C,
-            )
-        else:
-            rem_out, hist, fct_sum, rem_mid, rem_end = _run_tiled_chunk(
-                *operands, lat_u, bulk_u, dt_ms, mid_step, end_step,
-                hist, fct_sum, rem_mid, rem_end, c0,
-                num_steps=num_steps, chunk_steps=C,
-            )
-        rem_np = np.asarray(rem_out)
-        for b, (st, w) in enumerate(zip(states, ws)):
-            st.writeback(rem_np[b], w)
-            st.advance()
-        c0 += C
 
-    units = np.asarray([st.unit for st in states])
-    hists = np.asarray(hist, np.int64).reshape(
-        B, NUM_FCT_CLASSES, FCT_HIST_BINS
-    )
-    fct_sums = np.asarray(fct_sum, np.float64)   # staticcheck: ok SC-AST-F64 (host staging)
-    rem_mid_B = np.asarray(rem_mid, np.float64) * units  # staticcheck: ok SC-AST-F64 (host staging)
-    rem_end_B = np.asarray(rem_end, np.float64) * units  # staticcheck: ok SC-AST-F64 (host staging)
-    results = [
-        finalize_streamed(s, hists[b], float(fct_sums[b]),
-                          rem_mid_B[b], rem_end_B[b])
-        for b, s in enumerate(scenarios)
-    ]
-    remaining_bytes = [st.remaining_bytes() for st in states]
+    with obs.span("flows.readback"):
+        hists = np.asarray(hist, np.int64).reshape(
+            B, NUM_FCT_CLASSES, FCT_HIST_BINS
+        )
+        fct_sums = np.asarray(fct_sum, np.float64)   # staticcheck: ok SC-AST-F64 (host staging)
+        units = np.asarray([st.unit for st in states])
+        rem_mid_B = np.asarray(rem_mid, np.float64) * units  # staticcheck: ok SC-AST-F64 (host staging)
+        rem_end_B = np.asarray(rem_end, np.float64) * units  # staticcheck: ok SC-AST-F64 (host staging)
+    with obs.span("flows.finalize"):
+        results = [
+            finalize_streamed(s, hists[b], float(fct_sums[b]),
+                              rem_mid_B[b], rem_end_B[b])
+            for b, s in enumerate(scenarios)
+        ]
+        remaining_bytes = [st.remaining_bytes() for st in states]
     return FlowBatchResult(
         results, remaining_bytes, traces=None,
         hists=[hists[b] for b in range(B)],
@@ -681,125 +694,136 @@ def simulate_flows_batch(
                 f"TRACE_MAX_ELEMS={TRACE_MAX_ELEMS:,}); trace mode is for "
                 "test-sized grids — drop trace or shrink the scenario"
             )
+    obs.count("flows.scenario_steps", B * num_steps)
     if resolved == "tiled":
         return _simulate_flows_tiled(
             scenarios, dtype, tile_size, window_tiles, chunk_steps
         )
 
-    # Host-side staging is float64 on purpose: oracle-shared quantities are
-    # normalized at full precision, then cast once at the device boundary.
-    remaining0 = np.zeros((B, n_max), np.float64)  # staticcheck: ok SC-AST-F64 (host staging)
-    start_step = np.full((B, n_max), num_steps + 1, np.int32)
-    is_bulk = np.zeros((B, n_max), bool)
-    allow_mid = np.zeros((B, n_max), np.float64)   # staticcheck: ok SC-AST-F64 (host staging)
-    allow_end = np.zeros((B, n_max), np.float64)   # staticcheck: ok SC-AST-F64 (host staging)
-    class_id = np.zeros((B, n_max), np.int32)
-    arr_ms = np.zeros((B, n_max), np.float64)      # staticcheck: ok SC-AST-F64 (host staging)
-    lat_u = np.zeros(B)
-    bulk_u = np.zeros(B)
-    dt_ms = np.zeros(B)
-    mid_step = np.zeros(B, np.int32)
-    end_step = np.zeros(B, np.int32)
-    units = np.zeros(B)
-    faulted = any(s.has_faults for s in scenarios)
-    if faulted:
-        # NEVER-filled windows for fault-free rows and pad flows; unit
-        # scales for fault-free rows — the faulted step then reduces to
-        # the plain recurrence for them (to f32 fusion tolerance).
-        from repro.netsim.faults import NEVER
+    with obs.span("flows.prepare"):
+        # Host-side staging is float64 on purpose: oracle-shared quantities
+        # are normalized at full precision, then cast once at the device
+        # boundary.
+        remaining0 = np.zeros((B, n_max), np.float64)  # staticcheck: ok SC-AST-F64 (host staging)
+        start_step = np.full((B, n_max), num_steps + 1, np.int32)
+        is_bulk = np.zeros((B, n_max), bool)
+        allow_mid = np.zeros((B, n_max), np.float64)   # staticcheck: ok SC-AST-F64 (host staging)
+        allow_end = np.zeros((B, n_max), np.float64)   # staticcheck: ok SC-AST-F64 (host staging)
+        class_id = np.zeros((B, n_max), np.int32)
+        arr_ms = np.zeros((B, n_max), np.float64)      # staticcheck: ok SC-AST-F64 (host staging)
+        lat_u = np.zeros(B)
+        bulk_u = np.zeros(B)
+        dt_ms = np.zeros(B)
+        mid_step = np.zeros(B, np.int32)
+        end_step = np.zeros(B, np.int32)
+        units = np.zeros(B)
+        faulted = any(s.has_faults for s in scenarios)
+        if faulted:
+            # NEVER-filled windows for fault-free rows and pad flows; unit
+            # scales for fault-free rows — the faulted step then reduces to
+            # the plain recurrence for them (to f32 fusion tolerance).
+            from repro.netsim.faults import NEVER
 
-        blk_start = np.full((B, n_max), NEVER, np.int32)
-        blk_end = np.full((B, n_max), NEVER, np.int32)
-        frz_start = np.full((B, n_max), NEVER, np.int32)
-        frz_end = np.full((B, n_max), NEVER, np.int32)
-        lat_scale = np.ones((B, num_steps), np.float64)   # staticcheck: ok SC-AST-F64 (host staging)
-        bulk_scale = np.ones((B, num_steps), np.float64)  # staticcheck: ok SC-AST-F64 (host staging)
-    for b, s in enumerate(scenarios):
-        n = s.num_flows
-        unit = s.nic_Bps * s.dt_s          # bytes one NIC serves per step
-        units[b] = unit
-        remaining0[b, :n] = s.sizes / unit
-        start_step[b, :n] = s.start_step
-        is_bulk[b, :n] = s.is_bulk
-        allow_mid[b, :n] = s.deficit_allowance(s.mid_step) / unit
-        allow_end[b, :n] = s.deficit_allowance(s.end_step) / unit
-        class_id[b, :n] = fct_class_id(s.sizes)
-        arr_ms[b, :n] = s.arr * 1e3
-        lat_u[b] = s.lat_pool_Bps / s.nic_Bps
-        bulk_u[b] = s.bulk_pool_Bps / s.nic_Bps
-        dt_ms[b] = s.dt_s * 1e3
-        mid_step[b] = s.mid_step
-        end_step[b] = s.end_step
-        if faulted and s.has_faults:
-            blk_start[b, :n] = s.blk_start
-            blk_end[b, :n] = s.blk_end
-            frz_start[b, :n] = s.frz_start
-            frz_end[b, :n] = s.frz_end
-            lat_scale[b] = s.lat_scale[:num_steps]
-            bulk_scale[b] = s.bulk_scale[:num_steps]
+            blk_start = np.full((B, n_max), NEVER, np.int32)
+            blk_end = np.full((B, n_max), NEVER, np.int32)
+            frz_start = np.full((B, n_max), NEVER, np.int32)
+            frz_end = np.full((B, n_max), NEVER, np.int32)
+            lat_scale = np.ones((B, num_steps), np.float64)   # staticcheck: ok SC-AST-F64 (host staging)
+            bulk_scale = np.ones((B, num_steps), np.float64)  # staticcheck: ok SC-AST-F64 (host staging)
+        for b, s in enumerate(scenarios):
+            n = s.num_flows
+            unit = s.nic_Bps * s.dt_s          # bytes one NIC serves per step
+            units[b] = unit
+            remaining0[b, :n] = s.sizes / unit
+            start_step[b, :n] = s.start_step
+            is_bulk[b, :n] = s.is_bulk
+            allow_mid[b, :n] = s.deficit_allowance(s.mid_step) / unit
+            allow_end[b, :n] = s.deficit_allowance(s.end_step) / unit
+            class_id[b, :n] = fct_class_id(s.sizes)
+            arr_ms[b, :n] = s.arr * 1e3
+            lat_u[b] = s.lat_pool_Bps / s.nic_Bps
+            bulk_u[b] = s.bulk_pool_Bps / s.nic_Bps
+            dt_ms[b] = s.dt_s * 1e3
+            mid_step[b] = s.mid_step
+            end_step[b] = s.end_step
+            if faulted and s.has_faults:
+                blk_start[b, :n] = s.blk_start
+                blk_end[b, :n] = s.blk_end
+                frz_start[b, :n] = s.frz_start
+                frz_end[b, :n] = s.frz_end
+                lat_scale[b] = s.lat_scale[:num_steps]
+                bulk_scale[b] = s.bulk_scale[:num_steps]
 
-    common = (
-        jnp.asarray(remaining0, dtype),
-        jnp.asarray(start_step),
-        jnp.asarray(is_bulk),
-        jnp.asarray(lat_u, dtype),
-        jnp.asarray(bulk_u, dtype),
-        jnp.asarray(allow_mid, dtype),
-        jnp.asarray(allow_end, dtype),
-        jnp.asarray(mid_step),
-        jnp.asarray(end_step),
-        jnp.asarray(class_id),
-        jnp.asarray(arr_ms, dtype),
-        jnp.asarray(dt_ms, dtype),
-    )
-    if faulted:
-        remaining, done_step, rem_mid, rem_end, hist, _, ys = (
-            _run_batch_faulted(
-                *common,
+        common = (
+            jnp.asarray(remaining0, dtype),
+            jnp.asarray(start_step),
+            jnp.asarray(is_bulk),
+            jnp.asarray(lat_u, dtype),
+            jnp.asarray(bulk_u, dtype),
+            jnp.asarray(allow_mid, dtype),
+            jnp.asarray(allow_end, dtype),
+            jnp.asarray(mid_step),
+            jnp.asarray(end_step),
+            jnp.asarray(class_id),
+            jnp.asarray(arr_ms, dtype),
+            jnp.asarray(dt_ms, dtype),
+        )
+        fault_ops = ()
+        if faulted:
+            fault_ops = (
                 jnp.asarray(blk_start), jnp.asarray(blk_end),
                 jnp.asarray(frz_start), jnp.asarray(frz_end),
                 jnp.asarray(lat_scale, dtype), jnp.asarray(bulk_scale, dtype),
-                num_steps, bool(trace),
             )
-        )
-    else:
-        remaining, done_step, rem_mid, rem_end, hist, _, ys = _run_batch(
-            *common, num_steps, bool(trace),
-        )
-    done_step = np.asarray(done_step)
-    # Device f32 results are de-normalized on the host at float64, matching
-    # the float64 oracle's finalize() inputs.  The deficit snapshots come
-    # back as per-flow vectors and are summed here over *real* flows only:
-    # the summed arrays are then identical whether or not never-active pad
-    # flows were appended, so padding is bitwise invisible.
-    remaining = np.asarray(remaining, np.float64)  # staticcheck: ok SC-AST-F64 (host staging)
-    rem_mid = np.asarray(rem_mid, np.float64)  # staticcheck: ok SC-AST-F64 (host staging)
-    rem_end = np.asarray(rem_end, np.float64)  # staticcheck: ok SC-AST-F64 (host staging)
-    hist = np.asarray(hist, np.int64).reshape(B, NUM_FCT_CLASSES, FCT_HIST_BINS)
+        obs.count("flows.h2d_bytes", sum(x.nbytes for x in common + fault_ops))
 
-    def _deficit(vec, b, s):
-        real = s.sizes > 0
-        return float(vec[b, : s.num_flows][real].sum()) * units[b]
+    with obs.span("flows.run"):
+        if faulted:
+            remaining, done_step, rem_mid, rem_end, hist, _, ys = (
+                _run_batch_faulted(*common, *fault_ops, num_steps, bool(trace))
+            )
+        else:
+            remaining, done_step, rem_mid, rem_end, hist, _, ys = _run_batch(
+                *common, num_steps, bool(trace),
+            )
+    with obs.span("flows.readback"):
+        done_step = np.asarray(done_step)
+        # Device f32 results are de-normalized on the host at float64,
+        # matching the float64 oracle's finalize() inputs.  The deficit
+        # snapshots come back as per-flow vectors and are summed here over
+        # *real* flows only: the summed arrays are then identical whether or
+        # not never-active pad flows were appended, so padding is bitwise
+        # invisible.
+        remaining = np.asarray(remaining, np.float64)  # staticcheck: ok SC-AST-F64 (host staging)
+        rem_mid = np.asarray(rem_mid, np.float64)  # staticcheck: ok SC-AST-F64 (host staging)
+        rem_end = np.asarray(rem_end, np.float64)  # staticcheck: ok SC-AST-F64 (host staging)
+        hist = np.asarray(hist, np.int64).reshape(B, NUM_FCT_CLASSES, FCT_HIST_BINS)
+        if trace:
+            # staticcheck: ok SC-AST-F64 (host staging)
+            ys = np.asarray(ys, np.float64)    # (B, steps, n_max)
 
-    results = [
-        finalize(s, done_step[b, : s.num_flows],
-                 _deficit(rem_mid, b, s), _deficit(rem_end, b, s))
-        for b, s in enumerate(scenarios)
-    ]
-    remaining_bytes = [
-        remaining[b, : s.num_flows] * units[b]
-        for b, s in enumerate(scenarios)
-    ]
-    traces = None
-    if trace:
-        # staticcheck: ok SC-AST-F64 (host staging)
-        ys = np.asarray(ys, np.float64)    # (B, steps, n_max)
-        traces = [
-            ys[b, :, : s.num_flows] * units[b]
+    with obs.span("flows.finalize"):
+        def _deficit(vec, b, s):
+            real = s.sizes > 0
+            return float(vec[b, : s.num_flows][real].sum()) * units[b]
+
+        results = [
+            finalize(s, done_step[b, : s.num_flows],
+                     _deficit(rem_mid, b, s), _deficit(rem_end, b, s))
             for b, s in enumerate(scenarios)
         ]
-    return FlowBatchResult(results, remaining_bytes, traces,
-                           hists=[hist[b] for b in range(B)])
+        remaining_bytes = [
+            remaining[b, : s.num_flows] * units[b]
+            for b, s in enumerate(scenarios)
+        ]
+        traces = None
+        if trace:
+            traces = [
+                ys[b, :, : s.num_flows] * units[b]
+                for b, s in enumerate(scenarios)
+            ]
+        return FlowBatchResult(results, remaining_bytes, traces,
+                               hists=[hist[b] for b in range(B)])
 
 
 def simulate_grid(

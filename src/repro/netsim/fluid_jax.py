@@ -31,8 +31,8 @@ Two engines share the public API (`engine=` on
   sparse engine is a *host-side* per-step driver: one jitted call per
   slice, because XLA CPU executes a multi-step program (scan or
   unrolled) several-fold slower per step than the identical step
-  compiled alone — measured on the benchmark backend, see
-  benchmarks/perf_track.py for the tracked numbers.  ``engine="auto"``
+  compiled alone.  On the chip the host loop sets the pace: see PERF.md
+  for the measured numbers and the spans that time it.  ``engine="auto"``
   picks sparse at N >= `SPARSE_AUTO_RACKS`, dense below.  Both engines
   agree with the oracle at f32 ulp tolerance (tests/test_rotor_slice.py
   pins sparse-vs-dense on every default Appendix-B point, faulted and
@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.opera_paper import OperaNetConfig
 from repro.core.schedule import cycle_timing, slice_capacity_bytes
 from repro.core.topology import OperaTopology, build_opera_topology
@@ -119,8 +120,8 @@ def _run_batch(adj, own0, vlb: bool, num_cycles: int):
 # engine="auto" switches to the sparse gather engine at this rack count:
 # the dense relay matmul's O(N^2 u) overtakes the sparse step's
 # O(N (N + u)) well below this on paper radixes, but per-step dispatch
-# overhead eats the win for small fabrics (benchmarks/perf_track.py
-# records the measured crossover PR-over-PR).
+# overhead eats the win for small fabrics (PERF.md records what the
+# chip measures on each side of this threshold).
 SPARSE_AUTO_RACKS = 192
 
 
@@ -148,21 +149,25 @@ def _run_batch_sparse(dst, own0, vlb: bool, num_cycles: int):
     single-step jit call leaves the compare-select chains fused and
     fast.  Per-step dispatch costs microseconds against a
     millisecond-scale step at the rack counts that route here."""
-    bsz = own0.shape[0]
-    own = own0
-    relay = jnp.zeros_like(own0)
-    done = jnp.zeros((bsz,), own0.dtype)
-    wire = jnp.zeros((bsz,), own0.dtype)
-    dst_slices = [dst[t] for t in range(dst.shape[0])]
-    done_t, wire_t = [], []
-    for _ in range(num_cycles):
-        for d in dst_slices:
-            own, relay, done, wire = _sparse_slice_step(
-                own, relay, done, wire, d, vlb)
-            done_t.append(done)
-            wire_t.append(wire)
-    residual = own.sum((1, 2)) + relay.sum((1, 2))
-    return jnp.stack(done_t, 1), jnp.stack(wire_t, 1), residual
+    with obs.span("fluid.sparse.loop"):
+        bsz = own0.shape[0]
+        own = own0
+        relay = jnp.zeros_like(own0)
+        done = jnp.zeros((bsz,), own0.dtype)
+        wire = jnp.zeros((bsz,), own0.dtype)
+        with obs.span("fluid.sparse.split"):
+            dst_slices = [dst[t] for t in range(dst.shape[0])]
+        done_t, wire_t = [], []
+        with obs.span("fluid.sparse.dispatch"):
+            for _ in range(num_cycles):
+                for d in dst_slices:
+                    own, relay, done, wire = _sparse_slice_step(
+                        own, relay, done, wire, d, vlb)
+                    done_t.append(done)
+                    wire_t.append(wire)
+        with obs.span("fluid.sparse.stack"):
+            residual = own.sum((1, 2)) + relay.sum((1, 2))
+            return jnp.stack(done_t, 1), jnp.stack(wire_t, 1), residual
 
 
 @functools.partial(jax.jit, static_argnames=("vlb",))
@@ -209,31 +214,35 @@ def _run_batch_sparse_faulted(
     """Sparse analogue of `_run_batch_faulted` (same host-side per-step
     driving as `_run_batch_sparse`); returns (done_t, wire_t, residual,
     blackholed)."""
-    bsz = own0.shape[0]
-    if paced_cycles:
-        inject = own0 * (1.0 / paced_cycles)
-        own = jnp.zeros_like(own0)
-    else:
-        own = own0
-    relay = jnp.zeros_like(own0)
-    done = jnp.zeros((bsz,), own0.dtype)
-    wire = jnp.zeros((bsz,), own0.dtype)
-    blk = jnp.zeros((bsz,), own0.dtype)
-    g = jnp.zeros((), jnp.int32)
-    dst_slices = [dst[t] for t in range(dst.shape[0])]
-    done_t, wire_t = [], []
-    for c in range(num_cycles):
-        if paced_cycles and c < paced_cycles:
-            own = own + inject
-        for d in dst_slices:
-            own, relay, done, wire, blk, g = _sparse_slice_step_faulted(
-                own, relay, done, wire, blk, g, d, pair_sw,
-                up_onset, up_detect, up_recover,
-                tor_onset, tor_detect, tor_recover, vlb)
-            done_t.append(done)
-            wire_t.append(wire)
-    residual = own.sum((1, 2)) + relay.sum((1, 2))
-    return jnp.stack(done_t, 1), jnp.stack(wire_t, 1), residual, blk
+    with obs.span("fluid.sparse.loop"):
+        bsz = own0.shape[0]
+        if paced_cycles:
+            inject = own0 * (1.0 / paced_cycles)
+            own = jnp.zeros_like(own0)
+        else:
+            own = own0
+        relay = jnp.zeros_like(own0)
+        done = jnp.zeros((bsz,), own0.dtype)
+        wire = jnp.zeros((bsz,), own0.dtype)
+        blk = jnp.zeros((bsz,), own0.dtype)
+        g = jnp.zeros((), jnp.int32)
+        with obs.span("fluid.sparse.split"):
+            dst_slices = [dst[t] for t in range(dst.shape[0])]
+        done_t, wire_t = [], []
+        with obs.span("fluid.sparse.dispatch"):
+            for c in range(num_cycles):
+                if paced_cycles and c < paced_cycles:
+                    own = own + inject
+                for d in dst_slices:
+                    own, relay, done, wire, blk, g = _sparse_slice_step_faulted(
+                        own, relay, done, wire, blk, g, d, pair_sw,
+                        up_onset, up_detect, up_recover,
+                        tor_onset, tor_detect, tor_recover, vlb)
+                    done_t.append(done)
+                    wire_t.append(wire)
+        with obs.span("fluid.sparse.stack"):
+            residual = own.sum((1, 2)) + relay.sum((1, 2))
+            return jnp.stack(done_t, 1), jnp.stack(wire_t, 1), residual, blk
 
 
 def _slice_step_faulted(state, xs, ops, vlb: bool):
@@ -464,107 +473,114 @@ def simulate_rotor_bulk_batch(
     that engine's unfaulted program, so `FailureSchedule.empty()` stays
     bit-identical to the failure-free run.
     """
-    demands = np.asarray(demands, np.float64)  # staticcheck: ok SC-AST-F64 (host staging)
-    if demands.ndim == 2:
-        demands = demands[None]
-    n = cfg.num_racks
-    if demands.shape[1:] != (n, n):
-        raise ValueError(f"demand shape {demands.shape[1:]} != ({n}, {n})")
-    topo = topo or build_opera_topology(n, cfg.u, seed=seed, groups=cfg.groups)
-    t = cycle_timing(cfg)
-    cap = slice_capacity_bytes(cfg, t)
-    engine = resolve_engine(engine, n)
+    with obs.span("fluid.prepare"):
+        demands = np.asarray(demands, np.float64)  # staticcheck: ok SC-AST-F64 (host staging)
+        if demands.ndim == 2:
+            demands = demands[None]
+        n = cfg.num_racks
+        if demands.shape[1:] != (n, n):
+            raise ValueError(f"demand shape {demands.shape[1:]} != ({n}, {n})")
+        topo = topo or build_opera_topology(n, cfg.u, seed=seed, groups=cfg.groups)
+        t = cycle_timing(cfg)
+        cap = slice_capacity_bytes(cfg, t)
+        engine = resolve_engine(engine, n)
 
-    own0 = jnp.asarray(demands / cap, dtype)
-    blackholed = None
-    if _faults_all_empty(faults) and not paced_cycles:
+        own0 = jnp.asarray(demands / cap, dtype)
         if engine == "sparse":
-            dst = jnp.asarray(topo.matching_index_tensor())
-            done_t, wire_t, residual = _run_batch_sparse(
-                dst, own0, bool(vlb), int(max_cycles))
+            sched = jnp.asarray(topo.matching_index_tensor())
         else:
-            adj = jnp.asarray(topo.matching_tensor(), dtype)
-            done_t, wire_t, residual = _run_batch(
-                adj, own0, bool(vlb), int(max_cycles))
-    else:
-        from repro.netsim.faults import (
-            FailureSchedule,
-            FaultMasks,
-            compile_fault_masks,
-        )
+            sched = jnp.asarray(topo.matching_tensor(), dtype)
+        clean = _faults_all_empty(faults) and not paced_cycles
+        uploads = [own0, sched]
+        blackholed = None
+        if not clean:
+            from repro.netsim.faults import (
+                FailureSchedule,
+                FaultMasks,
+                compile_fault_masks,
+            )
 
-        if faults is None:
-            faults = FailureSchedule.empty(topo)
-        masks = (faults if isinstance(faults, FaultMasks)
-                 else compile_fault_masks(topo, faults))
-        masks = masks.broadcast_to(demands.shape[0])
-        if engine == "sparse":
-            dst = jnp.asarray(topo.matching_index_tensor())
+            if faults is None:
+                faults = FailureSchedule.empty(topo)
+            masks = (faults if isinstance(faults, FaultMasks)
+                     else compile_fault_masks(topo, faults))
+            masks = masks.broadcast_to(demands.shape[0])
+            pair_sw = jnp.asarray(masks.pair_switch)
+            timelines = [jnp.asarray(getattr(masks, f)) for f in (
+                "up_onset", "up_detect", "up_recover",
+                "tor_onset", "tor_detect", "tor_recover")]
+            uploads += [pair_sw, *timelines]
+            if engine == "dense":
+                sw = jnp.asarray(masks.switch_id)
+                uploads.append(sw)
+        obs.count("fluid.h2d_bytes", sum(x.nbytes for x in uploads))
+        obs.count("fluid.scenario_slices",
+                  demands.shape[0] * int(max_cycles) * sched.shape[0])
+
+    with obs.span("fluid.run"):
+        if clean:
+            run = _run_batch_sparse if engine == "sparse" else _run_batch
+            done_t, wire_t, residual = run(
+                sched, own0, bool(vlb), int(max_cycles))
+        elif engine == "sparse":
             done_t, wire_t, residual, blackholed = _run_batch_sparse_faulted(
-                dst, jnp.asarray(masks.pair_switch), own0,
-                jnp.asarray(masks.up_onset), jnp.asarray(masks.up_detect),
-                jnp.asarray(masks.up_recover),
-                jnp.asarray(masks.tor_onset), jnp.asarray(masks.tor_detect),
-                jnp.asarray(masks.tor_recover),
+                sched, pair_sw, own0, *timelines,
                 bool(vlb), int(max_cycles), int(paced_cycles),
             )
         else:
-            adj = jnp.asarray(topo.matching_tensor(), dtype)
-            sw = jnp.asarray(masks.switch_id)
             done_t, wire_t, residual, blackholed = _run_batch_faulted(
-                adj, sw, jnp.asarray(masks.pair_switch), own0,
-                jnp.asarray(masks.up_onset), jnp.asarray(masks.up_detect),
-                jnp.asarray(masks.up_recover),
-                jnp.asarray(masks.tor_onset), jnp.asarray(masks.tor_detect),
-                jnp.asarray(masks.tor_recover),
+                sched, sw, pair_sw, own0, *timelines,
                 bool(vlb), int(max_cycles), int(paced_cycles),
             )
-        blackholed = np.asarray(blackholed, np.float64) * cap  # staticcheck: ok SC-AST-F64 (host staging)
 
-    # Device f32 trajectories are de-normalized on the host at float64
-    # before stats, mirroring the numpy oracle's precision.
-    done = np.asarray(done_t, np.float64) * cap  # staticcheck: ok SC-AST-F64 (host staging)
-    wire = np.asarray(wire_t, np.float64) * cap  # staticcheck: ok SC-AST-F64 (host staging)
-    residual = np.asarray(residual, np.float64) * cap  # staticcheck: ok SC-AST-F64 (host staging)
-    totals = demands.sum((1, 2))
+    with obs.span("fluid.readback"):
+        # Device f32 trajectories are de-normalized on the host at float64
+        # before stats, mirroring the numpy oracle's precision.
+        done = np.asarray(done_t, np.float64) * cap  # staticcheck: ok SC-AST-F64 (host staging)
+        wire = np.asarray(wire_t, np.float64) * cap  # staticcheck: ok SC-AST-F64 (host staging)
+        residual = np.asarray(residual, np.float64) * cap  # staticcheck: ok SC-AST-F64 (host staging)
+        if not clean:
+            blackholed = np.asarray(blackholed, np.float64) * cap  # staticcheck: ok SC-AST-F64 (host staging)
 
-    B, T = done.shape
-    time_us = (np.arange(T) + 1) * t.slice_us
-    fct99 = np.empty(B)
-    fct_mean = np.empty(B)
-    tput = np.empty(B)
-    slices_run = np.empty(B, np.int64)
-    finished = done / np.maximum(totals, 1.0)[:, None]
-    for b in range(B):
-        hit = done[b] >= totals[b] * 0.99999
-        k = int(np.argmax(hit)) if hit.any() else T - 1
-        slices_run[b] = k + 1
-        fin = finished[b, : k + 1]
-        tms = time_us[: k + 1] / 1e3
-        fct99[b] = (
-            float(tms[np.searchsorted(fin, 0.99)])
-            if fin[-1] >= 0.99
-            else float("inf")
+    with obs.span("fluid.stats"):
+        totals = demands.sum((1, 2))
+        B, T = done.shape
+        time_us = (np.arange(T) + 1) * t.slice_us
+        fct99 = np.empty(B)
+        fct_mean = np.empty(B)
+        tput = np.empty(B)
+        slices_run = np.empty(B, np.int64)
+        finished = done / np.maximum(totals, 1.0)[:, None]
+        for b in range(B):
+            hit = done[b] >= totals[b] * 0.99999
+            k = int(np.argmax(hit)) if hit.any() else T - 1
+            slices_run[b] = k + 1
+            fin = finished[b, : k + 1]
+            tms = time_us[: k + 1] / 1e3
+            fct99[b] = (
+                float(tms[np.searchsorted(fin, 0.99)])
+                if fin[-1] >= 0.99
+                else float("inf")
+            )
+            fct_mean[b] = float(np.interp(0.5, fin, tms))
+            dur_s = time_us[k] * 1e-6
+            tput[b] = done[b, k] * 8 / dur_s / 1e9
+
+        rows = np.arange(B)
+        at_end = (slices_run - 1).clip(0, T - 1)
+        return RotorBatchResult(
+            finished_frac=finished,
+            time_us=time_us,
+            fct_99_ms=fct99,
+            fct_mean_ms=fct_mean,
+            throughput_gbps=tput,
+            wire_bytes=wire[rows, at_end],
+            goodput_bytes=done[rows, at_end],
+            residual_bytes=residual,
+            total_bytes=totals,
+            slices_run=slices_run,
+            blackholed_bytes=blackholed,
         )
-        fct_mean[b] = float(np.interp(0.5, fin, tms))
-        dur_s = time_us[k] * 1e-6
-        tput[b] = done[b, k] * 8 / dur_s / 1e9
-
-    rows = np.arange(B)
-    at_end = (slices_run - 1).clip(0, T - 1)
-    return RotorBatchResult(
-        finished_frac=finished,
-        time_us=time_us,
-        fct_99_ms=fct99,
-        fct_mean_ms=fct_mean,
-        throughput_gbps=tput,
-        wire_bytes=wire[rows, at_end],
-        goodput_bytes=done[rows, at_end],
-        residual_bytes=residual,
-        total_bytes=totals,
-        slices_run=slices_run,
-        blackholed_bytes=blackholed,
-    )
 
 
 def simulate_rotor_bulk_jax(

@@ -58,6 +58,33 @@ class TestEntryPoints:
         assert out["param_devices"] == len(jax.devices())
         assert jitted[-1]._cache_size() == 1
 
+    def test_train_main_losses_are_the_step_losses(self, cache_dir,
+                                                   monkeypatch):
+        """The losses come back as Python floats, one a step, equal to
+        the step's own `loss` output, with only the first and last steps
+        logged (so the rest are read after the loop)."""
+        from repro.launch import train
+
+        seen = []
+        real_jit = jax.jit
+
+        def spy_jit(*a, **kw):
+            step = real_jit(*a, **kw)
+
+            def call(*args):
+                state, metrics = step(*args)
+                seen.append(metrics["loss"])
+                return state, metrics
+            return call
+
+        monkeypatch.setattr(train.jax, "jit", spy_jit)
+        out = train.main(["--arch", "smollm-360m", "--reduced", "--steps",
+                          "4", "--batch", "4", "--seq", "16",
+                          "--log-every", "10"])
+        assert len(out["losses"]) == len(seen) == 4
+        assert all(type(x) is float for x in out["losses"])
+        assert out["losses"] == [float(x) for x in seen]
+
     def test_serve_main_returns_every_token(self, cache_dir):
         from repro.launch import serve
 
