@@ -16,7 +16,7 @@ One chip:
                 one scenario per workload against the numpy oracle
                 `fluid.simulate_rotor_bulk`.
 * fluid-sparse  k32-n432 for a few cycles on the sparse engine, whose
-                compiled step must hold the Pallas kernel
+                compiled slice loop must hold the Pallas kernel
                 (`tpu_custom_call`), against the dense engine.
 * flows         websearch + datamining at 648 hosts on the dense and the
                 tiled flow engines: histograms bitwise equal, and the
@@ -135,20 +135,19 @@ def fluid_dense(k=12, num_racks=108, seeds=8, loads=(0.1, 0.3),
             f"max_cycles={max_cycles}")
 
 
-def kernel_in_sparse_step(batch, num_racks, u):
-    """The sparse engine's compiled per-step program holds the Pallas
+def kernel_in_sparse_step(batch, num_slices, num_racks, u, max_cycles):
+    """The sparse engine's compiled slice-loop program holds the Pallas
     kernel: no substitute runs in its place on the chip."""
     import jax
     import jax.numpy as jnp
 
     from repro.netsim import fluid_jax
 
-    st = jax.ShapeDtypeStruct((batch, num_racks, num_racks), jnp.float32)
-    vec = jax.ShapeDtypeStruct((batch,), jnp.float32)
-    dst = jax.ShapeDtypeStruct((num_racks, u), jnp.int32)
-    text = fluid_jax._sparse_slice_step.lower(
-        st, st, vec, vec, dst, True).compile().as_text()
-    check("tpu_custom_call" in text, "tpu_custom_call in _sparse_slice_step")
+    dst = jax.ShapeDtypeStruct((num_slices, num_racks, u), jnp.int32)
+    own = jax.ShapeDtypeStruct((batch, num_racks, num_racks), jnp.float32)
+    text = fluid_jax._run_batch_sparse.lower(
+        dst, own, True, max_cycles).compile().as_text()
+    check("tpu_custom_call" in text, "tpu_custom_call in _run_batch_sparse")
 
 
 def fluid_sparse(k=32, num_racks=432, seeds=2, max_cycles=2):
@@ -159,7 +158,9 @@ def fluid_sparse(k=32, num_racks=432, seeds=2, max_cycles=2):
     spec = dict(designs=(dp,), workloads=("shuffle", "skew"), loads=(0.3,),
                 seeds=tuple(range(seeds)), max_cycles=max_cycles)
     _, sparse = run_design(SweepSpec(**spec, engine="sparse"), dp)
-    kernel_in_sparse_step(sparse.batch_size, num_racks, k // 2)
+    kernel_in_sparse_step(
+        sparse.batch_size, sparse.finished_frac.shape[1] // max_cycles,
+        num_racks, k // 2, max_cycles)
     _, dense = run_design(SweepSpec(**spec, engine="dense"), dp)
     worst = 0.0
     for f in ("finished_frac", "goodput_bytes", "wire_bytes",

@@ -20,8 +20,8 @@ Two structural tricks keep it scatter-free (XLA CPU scatters serialize):
   because matchings are involutions: ``dst[dst[j, s], s] == j``.
 
 `kernels/rotor_slice/kernel.py` is the Pallas form of this exact math
-and `ops.py` parity-gates the two; `fluid_jax._sparse_slice_step`
-drives it and `fluid.rotor_slice_step` (numpy, f64) stays the
+and `ops.py` parity-gates the two; `fluid_jax._sparse_slice_step`,
+the scan body of the sparse engine's slice loop, drives it and `fluid.rotor_slice_step` (numpy, f64) stays the
 engine-level oracle.
 """
 from __future__ import annotations

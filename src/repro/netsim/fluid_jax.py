@@ -27,16 +27,17 @@ Two engines share the public API (`engine=` on
   sentinel N = dark slot) via the `kernels/rotor_slice` Pallas op,
   cutting the per-slice work from O(N²·u) (the VLB relay matmul) to
   O(N·(N + u)) and the topology artifact from O(S·N²) to O(S·N·u) —
-  what makes the k >= 32 Appendix-B points fit on one host.  The
-  sparse engine is a *host-side* per-step driver: one jitted call per
-  slice, because XLA CPU executes a multi-step program (scan or
-  unrolled) several-fold slower per step than the identical step
-  compiled alone.  On the chip the host loop sets the pace: see PERF.md
-  for the measured numbers and the spans that time it.  ``engine="auto"``
-  picks sparse at N >= `SPARSE_AUTO_RACKS`, dense below.  Both engines
-  agree with the oracle at f32 ulp tolerance (tests/test_rotor_slice.py
-  pins sparse-vs-dense on every default Appendix-B point, faulted and
-  unfaulted).
+  what makes the k >= 32 Appendix-B points fit on one host.  Like
+  dense, it is one jitted program per call: a scan over cycles around
+  a scan over the index tensor, the batch riding the kernel's grid.
+  On XLA CPU that program is as fast as stepping the same slice
+  program from the host, or faster (B = 2, one cycle, median of 6:
+  k12-n108 0.08 s -> 0.02 s, k16-n256 0.25 s -> 0.15 s, k24-n432
+  0.88-0.91 s -> 0.73-0.88 s), so one path serves every backend.
+  ``engine="auto"`` picks sparse at N >= `SPARSE_AUTO_RACKS`, dense
+  below.  Both engines agree with the oracle at f32 ulp tolerance
+  (tests/test_rotor_slice.py pins sparse-vs-dense on every default
+  Appendix-B point, faulted and unfaulted).
 """
 from __future__ import annotations
 
@@ -119,16 +120,15 @@ def _run_batch(adj, own0, vlb: bool, num_cycles: int):
 
 # engine="auto" switches to the sparse gather engine at this rack count:
 # the dense relay matmul's O(N^2 u) overtakes the sparse step's
-# O(N (N + u)) well below this on paper radixes, but per-step dispatch
-# overhead eats the win for small fabrics (PERF.md records what the
-# chip measures on each side of this threshold).
+# O(N (N + u)) well below this on paper radixes (PERF.md records what
+# the chip measures on each side of this threshold).
 SPARSE_AUTO_RACKS = 192
 
 
 @functools.partial(jax.jit, static_argnames=("vlb",))
 def _sparse_slice_step(own, relay, done, wire, dst, vlb: bool):
-    """One sparse slice step + trajectory accumulation — the per-step
-    device program of the sparse driver.  The slice math lives in
+    """One sparse slice step + trajectory accumulation — the scan body
+    of `_run_batch_sparse`.  The slice math lives in
     `kernels.rotor_slice` (Pallas; `ref.rotor_slice_ref` is its oracle
     and mirrors `fluid.rotor_slice_step` / `_slice_step`; change them
     together)."""
@@ -140,34 +140,36 @@ def _sparse_slice_step(own, relay, done, wire, dst, vlb: bool):
     return own, relay, done, wire
 
 
+def _trajectories(ys):
+    """(num_cycles, num_slices, B) scan outputs -> (B, num_cycles*num_slices)."""
+    return tuple(y.reshape(-1, y.shape[-1]).T for y in ys)
+
+
+@functools.partial(jax.jit, static_argnames=("vlb", "num_cycles"))
 def _run_batch_sparse(dst, own0, vlb: bool, num_cycles: int):
     """Sparse analogue of `_run_batch`: same (done_t, wire_t, residual)
-    contract, but driven slice-by-slice from the host — one jitted call
-    per step.  Deliberately NOT a `lax.scan`: XLA CPU runs the sparse
-    step 4-5x slower per step inside a multi-step program (scan or
-    unrolled chunks alike) than as a standalone program, while a
-    single-step jit call leaves the compare-select chains fused and
-    fast.  Per-step dispatch costs microseconds against a
-    millisecond-scale step at the rack counts that route here."""
-    with obs.span("fluid.sparse.loop"):
-        bsz = own0.shape[0]
-        own = own0
-        relay = jnp.zeros_like(own0)
-        done = jnp.zeros((bsz,), own0.dtype)
-        wire = jnp.zeros((bsz,), own0.dtype)
-        with obs.span("fluid.sparse.split"):
-            dst_slices = [dst[t] for t in range(dst.shape[0])]
-        done_t, wire_t = [], []
-        with obs.span("fluid.sparse.dispatch"):
-            for _ in range(num_cycles):
-                for d in dst_slices:
-                    own, relay, done, wire = _sparse_slice_step(
-                        own, relay, done, wire, d, vlb)
-                    done_t.append(done)
-                    wire_t.append(wire)
-        with obs.span("fluid.sparse.stack"):
-            residual = own.sum((1, 2)) + relay.sum((1, 2))
-            return jnp.stack(done_t, 1), jnp.stack(wire_t, 1), residual
+    contract, one program per call.  The batch rides the kernel's grid
+    instead of a vmap: scan over cycles, inner scan over the
+    ``(S, N, u)`` index tensor, `_sparse_slice_step` as the body."""
+    bsz = own0.shape[0]
+
+    def step(carry, d):
+        carry = _sparse_slice_step(*carry, d, vlb)
+        return carry, carry[2:]
+
+    def one_cycle(carry, _):
+        return jax.lax.scan(step, carry, dst)
+
+    carry0 = (
+        own0,
+        jnp.zeros_like(own0),
+        jnp.zeros((bsz,), own0.dtype),
+        jnp.zeros((bsz,), own0.dtype),
+    )
+    (own, relay, _, _), ys = jax.lax.scan(
+        one_cycle, carry0, None, length=num_cycles)
+    done_t, wire_t = _trajectories(ys)
+    return done_t, wire_t, own.sum((1, 2)) + relay.sum((1, 2))
 
 
 @functools.partial(jax.jit, static_argnames=("vlb",))
@@ -176,13 +178,13 @@ def _sparse_slice_step_faulted(
     up_onset, up_detect, up_recover, tor_onset, tor_detect, tor_recover,
     vlb: bool,
 ):
-    """Faulted sparse step: rebuild the per-step masks from the compiled
-    component timelines (same int32 comparisons as
-    `_slice_step_faulted`, so masks stay *data* and one lowering serves
-    every failure draw), then run the edge-layout faulted math.  Slot s
-    of ``dst`` is switch s, so the per-uplink timelines apply directly
-    by slot; only the pair-dead relay mask still needs the dense
-    ``pair_sw`` serving-switch gather."""
+    """Faulted sparse step — the scan body of `_run_batch_sparse_faulted`:
+    rebuild the per-step masks from the compiled component timelines
+    (same int32 comparisons as `_slice_step_faulted`, so masks stay
+    *data* and one lowering serves every failure draw), then run the
+    edge-layout faulted math.  Slot s of ``dst`` is switch s, so the
+    per-uplink timelines apply directly by slot; only the pair-dead
+    relay mask still needs the dense ``pair_sw`` serving-switch gather."""
     from repro.kernels.rotor_slice.ref import rotor_slice_faulted_ref
 
     bsz, n = own.shape[0], own.shape[1]
@@ -206,43 +208,44 @@ def _sparse_slice_step_faulted(
     return own, relay, done, wire, blk, g + 1
 
 
+@functools.partial(
+    jax.jit, static_argnames=("vlb", "num_cycles", "paced_cycles")
+)
 def _run_batch_sparse_faulted(
     dst, pair_sw, own0,
     up_onset, up_detect, up_recover, tor_onset, tor_detect, tor_recover,
     vlb: bool, num_cycles: int, paced_cycles: int,
 ):
-    """Sparse analogue of `_run_batch_faulted` (same host-side per-step
-    driving as `_run_batch_sparse`); returns (done_t, wire_t, residual,
-    blackholed)."""
-    with obs.span("fluid.sparse.loop"):
-        bsz = own0.shape[0]
+    """Sparse analogue of `_run_batch_faulted`, one program per call as
+    `_run_batch_sparse`, with the global step counter and blackholed
+    total in the carry; returns (done_t, wire_t, residual, blackholed)."""
+    bsz = own0.shape[0]
+    timelines = (up_onset, up_detect, up_recover,
+                 tor_onset, tor_detect, tor_recover)
+    if paced_cycles:
+        inject = own0 * (1.0 / paced_cycles)
+        own_start = jnp.zeros_like(own0)
+    else:
+        own_start = own0
+
+    def step(carry, d):
+        carry = _sparse_slice_step_faulted(
+            *carry, d, pair_sw, *timelines, vlb)
+        return carry, carry[2:4]
+
+    def one_cycle(carry, c):
         if paced_cycles:
-            inject = own0 * (1.0 / paced_cycles)
-            own = jnp.zeros_like(own0)
-        else:
-            own = own0
-        relay = jnp.zeros_like(own0)
-        done = jnp.zeros((bsz,), own0.dtype)
-        wire = jnp.zeros((bsz,), own0.dtype)
-        blk = jnp.zeros((bsz,), own0.dtype)
-        g = jnp.zeros((), jnp.int32)
-        with obs.span("fluid.sparse.split"):
-            dst_slices = [dst[t] for t in range(dst.shape[0])]
-        done_t, wire_t = [], []
-        with obs.span("fluid.sparse.dispatch"):
-            for c in range(num_cycles):
-                if paced_cycles and c < paced_cycles:
-                    own = own + inject
-                for d in dst_slices:
-                    own, relay, done, wire, blk, g = _sparse_slice_step_faulted(
-                        own, relay, done, wire, blk, g, d, pair_sw,
-                        up_onset, up_detect, up_recover,
-                        tor_onset, tor_detect, tor_recover, vlb)
-                    done_t.append(done)
-                    wire_t.append(wire)
-        with obs.span("fluid.sparse.stack"):
-            residual = own.sum((1, 2)) + relay.sum((1, 2))
-            return jnp.stack(done_t, 1), jnp.stack(wire_t, 1), residual, blk
+            own = carry[0] + jnp.where(c < paced_cycles, inject, 0.0)
+            carry = (own, *carry[1:])
+        return jax.lax.scan(step, carry, dst)
+
+    zero = jnp.zeros((bsz,), own0.dtype)
+    carry0 = (own_start, jnp.zeros_like(own0), zero, zero, zero,
+              jnp.zeros((), jnp.int32))
+    (own, relay, _, _, blk, _), ys = jax.lax.scan(
+        one_cycle, carry0, jnp.arange(num_cycles, dtype=jnp.int32))
+    done_t, wire_t = _trajectories(ys)
+    return done_t, wire_t, own.sum((1, 2)) + relay.sum((1, 2)), blk
 
 
 def _slice_step_faulted(state, xs, ops, vlb: bool):
@@ -518,15 +521,20 @@ def simulate_rotor_bulk_batch(
                   demands.shape[0] * int(max_cycles) * sched.shape[0])
 
     with obs.span("fluid.run"):
-        if clean:
-            run = _run_batch_sparse if engine == "sparse" else _run_batch
-            done_t, wire_t, residual = run(
+        if engine == "sparse":
+            with obs.span("fluid.sparse.loop"):
+                if clean:
+                    done_t, wire_t, residual = _run_batch_sparse(
+                        sched, own0, bool(vlb), int(max_cycles))
+                else:
+                    (done_t, wire_t, residual,
+                     blackholed) = _run_batch_sparse_faulted(
+                        sched, pair_sw, own0, *timelines,
+                        bool(vlb), int(max_cycles), int(paced_cycles),
+                    )
+        elif clean:
+            done_t, wire_t, residual = _run_batch(
                 sched, own0, bool(vlb), int(max_cycles))
-        elif engine == "sparse":
-            done_t, wire_t, residual, blackholed = _run_batch_sparse_faulted(
-                sched, pair_sw, own0, *timelines,
-                bool(vlb), int(max_cycles), int(paced_cycles),
-            )
         else:
             done_t, wire_t, residual, blackholed = _run_batch_faulted(
                 sched, sw, pair_sw, own0, *timelines,

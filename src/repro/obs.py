@@ -17,13 +17,10 @@ import jax
 
 SPANS = {
     "fluid.prepare": "demand normalisation, schedule export, masks, uploads",
-    "fluid.run": "the dense or faulted program call, or the sparse slice loop",
+    "fluid.run": "the dense, sparse or faulted program call",
     "fluid.readback": "np.asarray of the trajectories, waiting for the device",
     "fluid.stats": "the per-row float64 completion statistics",
-    "fluid.sparse.loop": "the sparse engine's whole host slice loop",
-    "fluid.sparse.split": "dst[t]: the index tensor cut into per-slice arrays",
-    "fluid.sparse.dispatch": "one jitted slice step per slice and cycle",
-    "fluid.sparse.stack": "jnp.stack of per-slice trajectories, residual sum",
+    "fluid.sparse.loop": "the sparse engine's slice-loop program call",
     "flows.prepare": "dense packing and upload, or tiled states and constants",
     "flows.run": "the dense program call, or the tiled chunk loop",
     "flows.readback": "np.asarray of the results, waiting for the device",

@@ -23,9 +23,10 @@ SC-JAX-RECOMPILE  the sweep grid compiles more than once per design
 
 Traced entry points: ``fluid_jax._run_batch`` / ``_run_batch_faulted``
 (the dense device programs under ``simulate_rotor_bulk_batch``),
-``fluid_jax._sparse_slice_step`` / ``_sparse_slice_step_faulted`` (the
-sparse engine's per-step programs — ``count_sparse_lowerings`` holds
-them to one lowering per design point across slices and cycles),
+``fluid_jax._run_batch_sparse`` / ``_run_batch_sparse_faulted`` (the
+sparse engine's slice-loop programs, paced demand included —
+``count_sparse_lowerings`` holds the clean one to one lowering per
+(design point, ``vlb``, ``max_cycles``), whatever the demand draw),
 ``flows_jax._run_batch`` / ``_run_batch_faulted`` (under
 ``simulate_grid`` / ``simulate_flows_batch``),
 ``flows_jax._run_tiled_chunk`` / ``_run_tiled_chunk_faulted`` (the
@@ -97,18 +98,16 @@ def _entry_specs() -> List[Tuple[str, Callable, Callable]]:
             lambda: (sd((6, 8, 8)), sd((2, 8, 8))),
         ),
         (
-            "netsim.fluid_jax._sparse_slice_step",
-            lambda *a: fluid_jax._sparse_slice_step(*a, True),
-            lambda: (sd((2, 8, 8)), sd((2, 8, 8)), sd((2,)), sd((2,)),
-                     sd((8, 2), jnp.int32)),
+            "netsim.fluid_jax._run_batch_sparse",
+            lambda d, o: fluid_jax._run_batch_sparse(d, o, True, 3),
+            lambda: (sd((6, 8, 2), jnp.int32), sd((2, 8, 8))),
         ),
         (
-            "netsim.fluid_jax._sparse_slice_step_faulted",
-            lambda *a: fluid_jax._sparse_slice_step_faulted(*a, True),
+            "netsim.fluid_jax._run_batch_sparse_faulted",
+            lambda *a: fluid_jax._run_batch_sparse_faulted(*a, True, 3, 2),
             lambda: (
-                sd((2, 8, 8)), sd((2, 8, 8)), sd((2,)), sd((2,)), sd((2,)),
-                sd((), jnp.int32), sd((8, 2), jnp.int32),
-                sd((8, 8), jnp.int32),
+                sd((6, 8, 2), jnp.int32), sd((8, 8), jnp.int32),
+                sd((2, 8, 8)),
                 sd((2, 8, 3), jnp.int32), sd((2, 8, 3), jnp.int32),
                 sd((2, 8, 3), jnp.int32),
                 sd((2, 8), jnp.int32), sd((2, 8), jnp.int32),
@@ -385,12 +384,11 @@ def count_fault_lowerings(
 def count_sparse_lowerings(
     num_cycles: int = 3, num_demands: int = 2,
 ) -> Tuple[int, List[Finding]]:
-    """SC-JAX-RECOMPILE for the sparse engine: its host-side driver
-    re-invokes `fluid_jax._sparse_slice_step` once per slice per cycle,
-    so a whole run — and every run at the same design point, whatever
-    the demand draw — must reuse ONE lowering (slice index tensors are
-    same-shape data operands; the global step counter never becomes a
-    trace constant).
+    """SC-JAX-RECOMPILE for the sparse engine: `fluid_jax._run_batch_sparse`
+    runs every slice of every cycle in one program, so a whole run —
+    and every run at the same (design point, vlb, max_cycles), whatever
+    the demand draw — must reuse ONE lowering (the index tensor and the
+    demand are data operands, never trace constants).
 
     Returns (new_lowerings, findings)."""
     import numpy as np
@@ -401,7 +399,7 @@ def count_sparse_lowerings(
 
     topo = build_opera_topology(8, 2, seed=0)
     cfg = DesignPoint(k=4, num_racks=8).to_config()
-    before = fluid_jax._sparse_slice_step._cache_size()
+    before = fluid_jax._run_batch_sparse._cache_size()
     rng = np.random.default_rng(0)
     for _ in range(num_demands):
         demand = rng.uniform(0, 1e6, (8, 8))
@@ -409,17 +407,17 @@ def count_sparse_lowerings(
         fluid_jax.simulate_rotor_bulk_batch(
             cfg, demand[None], topo=topo, max_cycles=num_cycles,
             engine="sparse")
-    new = fluid_jax._sparse_slice_step._cache_size() - before
-    path, line = _src_location(fluid_jax._sparse_slice_step)
+    new = fluid_jax._run_batch_sparse._cache_size() - before
+    path, line = _src_location(fluid_jax._run_batch_sparse)
     findings: List[Finding] = []
     if new > 1:
         findings.append(Finding(
             "SC-JAX-RECOMPILE",
             f"{num_demands} sparse-engine runs x {num_cycles} cycles x "
             f"{topo.num_slices} slices at one design point compiled {new} "
-            "`_sparse_slice_step` lowerings — slice index tensors are "
-            "data; the per-step program must lower once per design-point "
-            "shape, never per slice or per run",
+            "`_run_batch_sparse` lowerings — the index tensor and demands "
+            "are data; the slice-loop program must lower once per "
+            "(design point, vlb, max_cycles), never per slice or per run",
             path=path, line=line))
     return new, findings
 

@@ -29,9 +29,6 @@ PARENT = {
     "fluid.prepare": None, "fluid.run": None, "fluid.readback": None,
     "fluid.stats": None,
     "fluid.sparse.loop": "fluid.run",
-    "fluid.sparse.split": "fluid.sparse.loop",
-    "fluid.sparse.dispatch": "fluid.sparse.loop",
-    "fluid.sparse.stack": "fluid.sparse.loop",
     "flows.prepare": None, "flows.run": None, "flows.readback": None,
     "flows.finalize": None,
     "flows.tiled.fill": "flows.run", "flows.tiled.upload": "flows.run",
@@ -39,8 +36,7 @@ PARENT = {
     "flows.tiled.retire": "flows.run",
 }
 FLUID_TOP = {"fluid.prepare", "fluid.run", "fluid.readback", "fluid.stats"}
-SPARSE = {"fluid.sparse.loop", "fluid.sparse.split", "fluid.sparse.dispatch",
-          "fluid.sparse.stack"}
+SPARSE = {"fluid.sparse.loop"}
 FLOWS_TOP = {"flows.prepare", "flows.run", "flows.readback",
              "flows.finalize"}
 TILED = {"flows.tiled.fill", "flows.tiled.upload", "flows.tiled.chunk",
@@ -159,7 +155,7 @@ def test_spans_nest_on_one_host_line(traced, path):
         assert any(pn == parent and ps <= s and e <= pe
                    for pn, ps, pe in events), f"{n} outside {parent}"
     # one span per phase and chunk, none per slice or step
-    assert sum(n == "fluid.sparse.dispatch" for n, _, _ in events) <= 1
+    assert sum(n == "fluid.sparse.loop" for n, _, _ in events) <= 1
 
 
 @pytest.mark.parametrize("path", sorted(PATHS))

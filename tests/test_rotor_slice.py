@@ -15,7 +15,8 @@ Three contracts under test:
   3. The sparse batch drivers (`_run_batch_sparse`, and the faulted
      engine behind ``engine="sparse"``) match the dense scan engine on
      full trajectories, unfaulted and under a nonempty
-     `FailureSchedule`.
+     `FailureSchedule`, and their one-program slice loops match a plain
+     host stepping of the slice step bit for bit.
 """
 import numpy as np
 import pytest
@@ -158,6 +159,48 @@ def _drift(a, b):
     return float(np.max(np.abs(a - b) / np.maximum(np.abs(a), 1.0)))
 
 
+def _faults(cfg):
+    return FailureSchedule(
+        num_racks=cfg.num_racks, num_switches=cfg.u,
+        events=(FailureEvent("link", ((1, 0), (5, 1)), onset_step=1,
+                             detect_lag=2, recover_step=10),
+                FailureEvent("tor", (3,), onset_step=2,
+                             detect_lag=1, recover_step=12)))
+
+
+def _host_loop(dst, own0, vlb, num_cycles, faulted=None, paced_cycles=0):
+    """The sparse drivers' slice loop stepped from the host: one call per
+    slice and cycle, trajectories stacked at the end."""
+    import jax.numpy as jnp
+
+    from repro.kernels.rotor_slice.ops import rotor_slice_step
+    from repro.netsim.fluid_jax import _sparse_slice_step_faulted
+
+    bsz = own0.shape[0]
+    own = jnp.zeros_like(own0) if paced_cycles else own0
+    relay = jnp.zeros_like(own0)
+    done = wire = blk = jnp.zeros((bsz,), own0.dtype)
+    g = jnp.zeros((), jnp.int32)
+    done_t, wire_t = [], []
+    for c in range(num_cycles):
+        if c < paced_cycles:
+            own = own + own0 * (1.0 / paced_cycles)
+        for t in range(dst.shape[0]):
+            if faulted is None:
+                own, relay, delivered, moved = rotor_slice_step(
+                    own, relay, dst[t], vlb=vlb)
+                done = done + delivered
+                wire = wire + delivered + moved
+            else:
+                own, relay, done, wire, blk, g = _sparse_slice_step_faulted(
+                    own, relay, done, wire, blk, g, dst[t], *faulted, vlb)
+            done_t.append(done)
+            wire_t.append(wire)
+    residual = own.sum((1, 2)) + relay.sum((1, 2))
+    out = (jnp.stack(done_t, 1), jnp.stack(wire_t, 1), residual)
+    return out if faulted is None else out + (blk,)
+
+
 class TestEngineParity:
     def test_run_batch_trajectories_agree(self):
         """Unfaulted drivers on an overloaded skew batch: cumulative
@@ -186,12 +229,7 @@ class TestEngineParity:
         cfg = dp.to_config()
         topo = build_opera_topology(
             cfg.num_racks, cfg.u, seed=0, groups=cfg.groups)
-        faults = FailureSchedule(
-            num_racks=cfg.num_racks, num_switches=cfg.u,
-            events=(FailureEvent("link", ((1, 0), (5, 1)), onset_step=1,
-                                 detect_lag=2, recover_step=10),
-                    FailureEvent("tor", (3,), onset_step=2,
-                                 detect_lag=1, recover_step=12)))
+        faults = _faults(cfg)
         dem = np.stack([scenario_demand("permutation", cfg, 0.5, s)
                         for s in range(2)])
         res = {
@@ -212,6 +250,47 @@ class TestEngineParity:
             assert bh_d.max() > 0, "schedule must blackhole something"
         total = dem.sum(axis=(1, 2))
         assert float(np.max(np.abs(bh_d - bh_s) / total)) < 1e-6
+
+    @pytest.mark.parametrize("faulted", [False, True],
+                             ids=["clean", "faulted-paced"])
+    @pytest.mark.parametrize("dp", [DP, DP_G2], ids=["g1", "g2"])
+    @pytest.mark.parametrize("vlb", [False, True])
+    def test_device_loop_bitwise_matches_host_loop(self, dp, vlb, faulted):
+        """One program per call (scan over cycles and slices) gives the
+        host-stepped loop's trajectories, residual and blackholed total
+        bit for bit; the faulted case paces its demand over 2 of 4
+        cycles."""
+        import jax.numpy as jnp
+
+        from repro.netsim.faults import compile_fault_masks
+        from repro.netsim.fluid_jax import (
+            _run_batch_sparse,
+            _run_batch_sparse_faulted,
+        )
+
+        cfg = dp.to_config()
+        topo = build_opera_topology(
+            cfg.num_racks, cfg.u, seed=0, groups=cfg.groups)
+        cap = slice_capacity_bytes(cfg, cycle_timing(cfg))
+        dem = np.stack([scenario_demand("skew", cfg, 2.5, s)
+                        for s in range(3)])
+        own0 = jnp.asarray(dem / cap, jnp.float32)
+        dst = jnp.asarray(topo.matching_index_tensor())
+        if faulted:
+            masks = compile_fault_masks(topo, _faults(cfg)).broadcast_to(3)
+            ops = [jnp.asarray(getattr(masks, f)) for f in (
+                "pair_switch", "up_onset", "up_detect", "up_recover",
+                "tor_onset", "tor_detect", "tor_recover")]
+            got = _run_batch_sparse_faulted(dst, ops[0], own0, *ops[1:],
+                                            vlb, 4, 2)
+            want = _host_loop(dst, own0, vlb, 4, faulted=ops,
+                              paced_cycles=2)
+        else:
+            got = _run_batch_sparse(dst, own0, vlb, 4)
+            want = _host_loop(dst, own0, vlb, 4)
+        assert np.asarray(got[0]).shape == (3, 4 * topo.num_slices)
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     def test_engine_dispatch_validates(self):
         from repro.netsim.fluid_jax import (

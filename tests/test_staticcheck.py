@@ -308,8 +308,8 @@ class TestJaxprRules:
         assert "netsim.flows_jax._run_batch_faulted" in names
         assert "netsim.flows_jax._run_tiled_chunk" in names
         assert "netsim.flows_jax._run_tiled_chunk_faulted" in names
-        assert "netsim.fluid_jax._sparse_slice_step" in names
-        assert "netsim.fluid_jax._sparse_slice_step_faulted" in names
+        assert "netsim.fluid_jax._run_batch_sparse" in names
+        assert "netsim.fluid_jax._run_batch_sparse_faulted" in names
         assert "kernels.rotor_slice.ops.rotor_slice_step" in names
         assert any("flash_attention" in n for n in names)
 
@@ -386,8 +386,10 @@ class TestRecompilePinning:
 
     def test_sparse_demand_draws_share_one_lowering(self):
         """Sparse engine: distinct demand draws through one design point
-        must add at most one `_sparse_slice_step` lowering (slice index
-        tensors are data, not static), and a re-run must add none."""
+        must add at most one `_run_batch_sparse` lowering (the index
+        tensor is data, not static, and no program is lowered per
+        slice), and a re-run must add none; a new `max_cycles` is a new
+        static shape and adds exactly one."""
         from repro.staticcheck.jaxpr_rules import count_sparse_lowerings
 
         new, findings = count_sparse_lowerings(num_cycles=3, num_demands=2)
@@ -396,6 +398,9 @@ class TestRecompilePinning:
         new2, findings2 = count_sparse_lowerings(num_cycles=3, num_demands=2)
         assert findings2 == []
         assert new2 == 0
+        new3, findings3 = count_sparse_lowerings(num_cycles=5, num_demands=2)
+        assert findings3 == []
+        assert new3 == 1
 
     def test_tiled_flow_grid_shares_one_lowering(self):
         """Tiled flow engine: chunk shapes are (batch, window, tile)

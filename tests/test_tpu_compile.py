@@ -4,7 +4,8 @@ No chip is needed: the TPU compiler is installed and compiles for a
 described `v5e:2x2` topology.  These tests catch what interpret mode
 cannot — block shapes Mosaic refuses, kernels that overflow VMEM,
 programs that do not fit one chip — and pin that the Pallas rotor
-kernel, not a substitute, is what the sparse engine's step compiles to.
+kernel, not a substitute, is what the sparse engine's slice loop
+compiles to.
 
 The topology is described inside a module fixture (never at import):
 only one process at a time may load the TPU library, and every pytest
@@ -69,8 +70,9 @@ def test_rotor_kernel_compiles(sds, n, u, vlb):
 
 
 def test_sparse_engine_step_holds_kernel(sds, monkeypatch):
-    """The sparse engine's per-slice program at k32-n432 dispatches the
-    kernel itself: no reference math replaces it on the chip."""
+    """The sparse engine's slice-loop program at k32-n432 (432 slices a
+    cycle, 2 cycles) runs the kernel itself: no reference math replaces
+    it on the chip, and the whole loop fits one chip."""
     import jax
     import jax.numpy as jnp
 
@@ -78,17 +80,18 @@ def test_sparse_engine_step_holds_kernel(sds, monkeypatch):
     from repro.netsim import fluid_jax
 
     n, u = KERNEL_POINTS[1]
-    st, vec = sds((BATCH, n, n)), sds((BATCH,))
     # `ops` picks kernel or ref path by the default backend, the CPU
     # here; steer it to the branch the chip takes
     monkeypatch.setattr(ops, "resolve_interpret", lambda interpret=None: False)
     jax.clear_caches()
     try:
-        text = fluid_jax._sparse_slice_step.lower(
-            st, st, vec, vec, sds((n, u), jnp.int32), True).compile().as_text()
+        compiled = fluid_jax._run_batch_sparse.lower(
+            sds((n, n, u), jnp.int32), sds((BATCH, n, n)), True, 2).compile()
     finally:
         jax.clear_caches()
-    assert "tpu_custom_call" in text
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 10**9
 
 
 def test_dense_run_batch_compiles_k12_n108(sds):
