@@ -33,6 +33,13 @@ PROGRAM_LEAVES = {
 }
 
 
+def program_norm_eps(mcfg) -> float:
+    """The epsilon the program's RMSNorms run: its configuration's
+    `norm_eps` where it carries one, else the 1e-6 that
+    `models/layers.apply_norm` fixes, which no caller overrides."""
+    return getattr(mcfg, "norm_eps", 1e-6)
+
+
 def _path(path) -> tuple:
     return tuple(str(getattr(k, "key", getattr(k, "name", k))) for k in path)
 
@@ -125,6 +132,12 @@ class Cell:
             if cfg[mine] != getattr(mcfg, theirs):
                 raise ValueError(f"{mine} {cfg[mine]} != program's "
                                  f"{theirs} {getattr(mcfg, theirs)}")
+        # checked here, before minutes of compiling: at its limits
+        # `correct` cannot see even a tenfold epsilon
+        eps = program_norm_eps(mcfg)
+        if cfg["rms_norm_eps"] != eps:
+            raise ValueError(f"rms_norm_eps {cfg['rms_norm_eps']} != the "
+                             f"program's RMSNorm epsilon {eps}")
         self.mesh = make_host_mesh(model=1)
         if len(self.mesh.devices.flat) != len(devices):
             raise ValueError(f"mesh of {self.mesh.devices.size} devices, "

@@ -37,3 +37,18 @@ def test_control_is_not_correct(tmp_path):
     limits = out["limits"]
     assert all(v <= limits[k] for k, v in out["program"].items()), out
     assert any(v > limits[k] for k, v in out["control"].items()), out
+
+
+def test_epsilon_the_program_does_not_run_is_refused():
+    """A configuration whose RMSNorm epsilon differs from the program's
+    is refused at set-up, before anything compiles."""
+    from bench import run as R
+    from repro.configs import get_config
+
+    driver = R.import_file(R.BENCH / "drivers" / "train.py", "train_eps")
+    cfg = R.load_json(R.BENCH / "configs" / "smollm-360m.json")
+    cfg["rms_norm_eps"] = 10 * driver.program_norm_eps(
+        get_config(cfg["program_config"]))
+    traffic = R.load_json(R.BENCH / "traffic" / "train-dp-8x1024.json")
+    with pytest.raises(ValueError, match="rms_norm_eps"):
+        driver.Cell(cfg, traffic, 2**31 + 7, devices=[])

@@ -26,11 +26,9 @@ CONFIG, TRAFFIC = "smollm-360m", "train-dp-8x1024"
 RATE = dict(name="train_tokens_per_s", unit="tokens/s", better="higher",
             bound=0.01, source="host_clock", workloads=["tiny-train"])
 TINY_ARCH = "smollm-360m-tiny"
-# at the program's RMSNorm epsilon, so that the rehearsal tests the
-# harness and the faults, not the departure that keeps the cell out
 TINY = dict(program_config=TINY_ARCH, hidden_size=64, num_hidden_layers=2,
             num_attention_heads=4, num_key_value_heads=2, head_dim=16,
-            intermediate_size=128, vocab_size=256, rms_norm_eps=1e-6)
+            intermediate_size=128, vocab_size=256)
 # the tiny model's own limits, set as the cell's were, from readings at
 # this size: sound runs read up to 1.2e-3 (loss) and 1.6e-3 (the rest);
 # the control reads 2.9e-3 (loss), 1.4e-2 (gnorm, grad) and 7e-3 (update)
@@ -40,12 +38,18 @@ LIMITS = dict(loss_gap=4e-3, gnorm_gap=4e-3, grad_gap=8e-3, update_gap=4e-3,
 
 def tiny_root(root: Path) -> None:
     """The real yardstick with a tiny model and batch beside the cell."""
+    from bench.drivers.train import program_norm_eps
+    from repro.configs import get_config
+
     shutil.copytree(ROOT / "bench", root / "bench",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     cfg = json.loads((ROOT / f"bench/configs/{CONFIG}.json").read_text())
-    (root / "bench/configs/tiny-lm.json").write_text(json.dumps({**cfg,
-                                                                 **TINY}))
+    # at the epsilon the program runs, which the driver insists on, so
+    # that the rehearsal tests the harness and the faults
+    eps = program_norm_eps(get_config(cfg["program_config"]))
+    (root / "bench/configs/tiny-lm.json").write_text(json.dumps(
+        {**cfg, **TINY, "rms_norm_eps": eps}))
     tr = json.loads((ROOT / f"bench/traffic/{TRAFFIC}.json").read_text())
     tr.update(per_chip_batch=2, seq_len=16, block_rows=1, batches=4)
     (root / "bench/traffic/tiny-train.json").write_text(json.dumps(tr))
