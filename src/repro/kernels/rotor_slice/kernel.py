@@ -9,12 +9,18 @@ math of `ref.rotor_slice_ref`, in the forms Mosaic lowers:
 * no gathers: slot s's live edges are the (N, N) one-hot mask
   ``hit_s[i, j] = (dst[i, s] == j)``, symmetric because every matching
   is an involution.  The edge value ``own[i, dst[i, s]]`` is a masked
-  row sum, and the relay row gather ``take[dst[j, s], :]`` is the
-  product ``hit_s @ take`` at HIGHEST precision.  Both sum one nonzero
-  term, so both are exact.
-* slots are a `fori_loop`, and per-edge quantities are (N, 1) columns
-  rebuilt per slot, so VMEM holds a fixed number of (N, N) tiles
-  whatever u is.
+  row sum, exact since it sums one nonzero term.
+* the relay row gather ``take[dst[j, s], :]`` is ``hit_s @ take`` with
+  no HIGHEST product: `take` is split once into three bf16 parts that
+  sum back to it exactly (`_split3`), each gathered by one single-pass
+  bf16 product with the bf16 one-hot.  Every output of such a product
+  sums one bf16 value times 1.0 and zeros, so it is exact, and so is
+  the re-sum.  The share column goes the same way, its three parts side
+  by side in one narrow product.
+* slots are a `fori_loop`.  The direct loop's per-edge quantities are
+  (N, 1) columns rebuilt per slot, so VMEM holds a fixed number of
+  (N, N) tiles whatever u is; each slot's leftover `room` column is
+  kept in one (N, u) array, so the spread loop rebuilds only `hit_s`.
 * per-rack totals leave through a (B, N, 3) block (full trailing dims,
   a legal Mosaic block); `ref.rack_totals` sums them to (B,).
 """
@@ -42,13 +48,29 @@ def _vmem_limit_bytes(n: int) -> int:
     return min(max(16 * 2**20, _VMEM_LIVE_TILES * n * n * 4), _VMEM_CAP_BYTES)
 
 
-def _gather_rows(onehot, x):
-    """``x[dst[j]]`` for each row j of a one-hot matrix: exact, since
-    every output sums one product with 1.0 and zeros."""
-    return jax.lax.dot_general(
-        onehot, x, (((1,), (0,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32)
+def _split3(x):
+    """Three f32 parts of f32 `x`, each exact in bf16, whose sum is `x`
+    bit for bit in any order.  Truncation split: `hi` keeps x's top 16
+    bits (sign, exponent, 7 mantissa bits), `mid` the same of the
+    remainder, and `lo` what is left, at most 8 significant bits.  The
+    parts are disjoint bit fields of x with its sign, so every partial
+    sum is exact.  Holds where the parts are normal, |x| >= 2**-103, and
+    for +0 (-0 sums back to +0)."""
+    def trunc(v):
+        bits = jax.lax.bitcast_convert_type(v, jnp.int32)
+        return jax.lax.bitcast_convert_type(bits & jnp.int32(-65536),
+                                            jnp.float32)
+
+    hi = trunc(x)
+    rest = x - hi
+    mid = trunc(rest)
+    return hi, mid, rest - mid
+
+
+def _dot(onehot, x):
+    """One single-pass bf16 product: rows of `x` picked by `onehot`."""
+    return jax.lax.dot_general(onehot, x, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 def _kernel(dst_t_ref, own_ref, relay_ref, own_o, relay_o, stats_o, *,
@@ -58,32 +80,36 @@ def _kernel(dst_t_ref, own_ref, relay_ref, own_o, relay_o, stats_o, *,
     u, n = dst_t_ref.shape
     rows = jax.lax.broadcasted_iota(dst_t_ref.dtype, (n, n), 0)
 
-    def slot(s):
-        """Slot s's edge mask and (N, 1) edge columns of the direct phase.
-        ``rows == dst[:, s]`` as a row is hit_s transposed, which is
-        hit_s itself: the slot's matching is an involution."""
-        hit = rows == dst_t_ref[pl.ds(s, 1), :]
+    def hit_of(s):
+        """Slot s's edge mask.  ``rows == dst[:, s]`` as a row is hit_s
+        transposed, which is hit_s itself: the matching is an
+        involution."""
+        return rows == dst_t_ref[pl.ds(s, 1), :]
+
+    slot_col = jax.lax.broadcasted_iota(jnp.int32, (n, u), 1)
+
+    def direct(s, carry):
+        # nested selects: slots are disjoint, at most one fires per element;
+        # `rooms` (VLB only) collects each slot's room column for `spread`
+        d_own, d_relay, d_elig, r, so, sr, *rooms = carry
+        hit = hit_of(s)
         vf = hit.astype(own0.dtype).sum(1, keepdims=True)
         own_e = jnp.where(hit, own0, 0.0).sum(1, keepdims=True)
         send_own = jnp.minimum(own_e, vf)
         relay_e = jnp.where(hit, relay0, 0.0).sum(1, keepdims=True)
         send_relay = jnp.minimum(relay_e, vf - send_own)
         room = vf - send_own - send_relay
-        return hit, own_e, send_own, send_relay, room
-
-    def direct(s, carry):
-        # nested selects: slots are disjoint, at most one fires per element
-        d_own, d_relay, d_elig, r, so, sr = carry
-        hit, own_e, send_own, send_relay, room = slot(s)
         return (jnp.where(hit, -send_own, d_own),
                 jnp.where(hit, -send_relay, d_relay),
                 jnp.where(hit, -(own_e - send_own), d_elig),
-                r + room, so + send_own, sr + send_relay)
+                r + room, so + send_own, sr + send_relay,
+                *(jnp.where(slot_col == s, room, x) for x in rooms))
 
     zn = jnp.zeros_like(own0)
     z1 = jnp.zeros((n, 1), own0.dtype)
-    d_own, d_relay, d_elig, r, so, sr = jax.lax.fori_loop(
-        0, u, direct, (zn, zn, zn, z1, z1, z1))
+    rooms = (jnp.zeros((n, u), own0.dtype),) if vlb else ()
+    d_own, d_relay, d_elig, r, so, sr, *rooms = jax.lax.fori_loop(
+        0, u, direct, (zn, zn, zn, z1, z1, z1, *rooms))
     own = own0 + d_own
     relay = relay0 + d_relay
     moved = z1
@@ -95,14 +121,25 @@ def _kernel(dst_t_ref, own_ref, relay_ref, own_o, relay_o, stats_o, *,
         take = elig * frac
         inv_r = jnp.where(r > 0, 1.0 / jnp.maximum(r, 1e-30), 0.0)
         own = own - take
+        shares = rooms[0] * inv_r
+        take_hi, take_mid, take_lo = (p.astype(jnp.bfloat16)
+                                      for p in _split3(take))
+        part_col = jax.lax.broadcasted_iota(jnp.int32, (n, 3), 1)
 
         def spread(s, add):
             # relay[j] += share_s[dst[j, s]] * take[dst[j, s]]: both row
-            # gathers are products with the one-hot (symmetric) hit_s
-            hit, _, _, _, room = slot(s)
-            onehot = hit.astype(take.dtype)
-            w = _gather_rows(onehot, room * inv_r)
-            return add + w * _gather_rows(onehot, take)
+            # gathers are products with the one-hot (symmetric) hit_s.
+            # The share's parts sit in one (N, 3) operand, re-summed
+            # across lanes: exact in any order (`_split3`).
+            onehot = hit_of(s).astype(jnp.bfloat16)
+            share = jnp.where(slot_col == s, shares, 0.0).sum(1, keepdims=True)
+            s_hi, s_mid, s_lo = _split3(share)
+            parts = jnp.where(part_col == 0, s_hi,
+                              jnp.where(part_col == 1, s_mid, s_lo))
+            w = _dot(onehot, parts.astype(jnp.bfloat16)).sum(1, keepdims=True)
+            row = ((_dot(onehot, take_hi) + _dot(onehot, take_mid))
+                   + _dot(onehot, take_lo))
+            return add + w * row
 
         relay = relay + jax.lax.fori_loop(0, u, spread, zn)
         moved = t

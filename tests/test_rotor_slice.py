@@ -9,15 +9,20 @@ Three contracts under test:
      grouped reconfiguration darkens (at least) `groups` whole columns
      per slice.
   2. The `kernels/rotor_slice` trio agrees with itself (Pallas
-     interpret path vs jnp ref path, bitwise — the kernel's one-hot
-     gathers are exact and it sums in the ref's order) and with the
-     numpy oracle `fluid.rotor_slice_step`.
+     interpret path vs jnp ref path, bitwise when compiled without FMA
+     — the kernel's one-hot gathers are exact and it sums in the ref's
+     order) and with the numpy oracle `fluid.rotor_slice_step`.
   3. The sparse batch drivers (`_run_batch_sparse`, and the faulted
      engine behind ``engine="sparse"``) match the dense scan engine on
      full trajectories, unfaulted and under a nonempty
      `FailureSchedule`, and their one-program slice loops match a plain
      host stepping of the slice step bit for bit.
 """
+import json
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -96,34 +101,128 @@ class TestIndexTensor:
 # ---------------------------------------------------------------------------
 
 
+def test_split3_parts_are_bf16_and_sum_back_exactly():
+    """The kernel's truncation split: each part is exact in bf16, and
+    the parts re-sum to the input bit for bit, in the kernel's order and
+    in any other, across 2**-100..2**10 and at the edge values."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.rotor_slice.kernel import _split3
+
+    rng = np.random.default_rng(0)
+    mant = rng.uniform(1.0, 2.0, 200_000)
+    x = (mant * 2.0 ** rng.integers(-100, 11, mant.size)).astype(np.float32)
+    edge = np.array([0.0, 1 / 3, np.uint32(0x3FFFFFFF).view(np.float32),
+                     np.uint32(0x7F7FFFFF).view(np.float32) / 2**118],
+                    np.float32)
+    x = np.concatenate([x, edge, -x, -edge[1:]])
+    hi, mid, lo = (np.asarray(p) for p in jax.jit(_split3)(jnp.asarray(x)))
+    for p in (hi, mid, lo):
+        assert p.dtype == np.float32
+        back = np.asarray(jnp.asarray(p).astype(jnp.bfloat16)
+                          .astype(jnp.float32))
+        np.testing.assert_array_equal(back.view(np.uint32),
+                                      p.view(np.uint32))
+    assert not (hi.view(np.uint32) & 0xFFFF).any()
+    for total in ((hi + mid) + lo, hi + (mid + lo), (hi + lo) + mid):
+        np.testing.assert_array_equal(total.view(np.uint32),
+                                      x.view(np.uint32))
+
+
+def _kernel_state(kind, seed=0):
+    """(own, relay) for 3 scenarios at 16 racks, zero diagonal: "uniform"
+    over [0, 2) and [0, 1), or "wide", whose values carry full 24-bit
+    mantissas across 2**-40..2**1."""
+    rng = np.random.default_rng(seed)
+    if kind == "uniform":
+        own = rng.uniform(0.0, 2.0, (3, 16, 16))
+        relay = rng.uniform(0.0, 1.0, (3, 16, 16))
+    else:
+        own, relay = (rng.uniform(0.0, 1.0, (3, 16, 16))
+                      * 2.0 ** rng.integers(-40, 2, (3, 16, 16))
+                      for _ in range(2))
+    own, relay = own.astype(np.float32), relay.astype(np.float32)
+    for a in (own, relay):
+        a[:, np.arange(16), np.arange(16)] = 0.0
+    return own, relay
+
+
+# Pallas-vs-ref cases: a bare slice index is the uniform state on the
+# one-group fabric; tuples are (state, groups, slice)
+PALLAS_CASES = [0, 3, 7, ("wide", 1, 3), ("wide", 1, 7), ("uniform", 2, 3),
+                ("wide", 2, 6)]
+
+
+def _case(t):
+    return t if isinstance(t, tuple) else ("uniform", 1, t)
+
+
+def _pallas_vs_ref_mismatches():
+    """Per case and vlb, the elements of each output in which the Pallas
+    kernel (interpret mode) and the ref path differ, bit for bit."""
+    import jax.numpy as jnp
+
+    from repro.kernels.rotor_slice import rotor_slice_step
+
+    out = {}
+    for t in PALLAS_CASES:
+        kind, groups, s = _case(t)
+        dst = jnp.asarray(_topo(8, 16, groups).matching_index_tensor()[s])
+        own, relay = (jnp.asarray(a) for a in _kernel_state(kind))
+        for vlb in (False, True):
+            ref = rotor_slice_step(own, relay, dst, vlb=vlb)
+            pal = rotor_slice_step(own, relay, dst, vlb=vlb,
+                                   force_pallas=True)
+            out[f"{kind}-{groups}-{s}-{vlb}"] = [
+                int((np.asarray(a).view(np.uint32)
+                     != np.asarray(b).view(np.uint32)).sum())
+                for a, b in zip(ref, pal)]
+    return out
+
+
+@pytest.fixture(scope="module")
+def pallas_vs_ref():
+    """`_pallas_vs_ref_mismatches` in a child process compiled for a CPU
+    without FMA.  XLA's CPU compiler always lets LLVM fuse a multiply
+    into an add, and the two paths' VLB spread sums fuse different
+    multiplies (the ref's first two slots become fma(w0, row0, w1*row1),
+    the kernel's fma(w1, row1, w0*row0)), so with FMA the paths may
+    differ by an ulp in `relay`.  Without it each op rounds alone."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    src = os.path.join(os.path.dirname(here), "src")
+    env = dict(
+        os.environ, JAX_PLATFORMS="cpu",
+        XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
+                   + " --xla_cpu_max_isa=AVX").strip(),
+        PYTHONPATH=os.pathsep.join(
+            [here, src, os.environ.get("PYTHONPATH", "")]))
+    code = ("import json, test_rotor_slice as t; "
+            "print(json.dumps(t._pallas_vs_ref_mismatches()))")
+    run = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert run.returncode == 0, run.stderr[-4000:]
+    return json.loads(run.stdout.strip().splitlines()[-1])
+
+
 class TestKernelParity:
     @pytest.fixture(scope="class")
     def state(self):
         topo = _topo(8, 16, 1)
         dst = topo.matching_index_tensor()
         dense = topo.matching_tensor()
-        rng = np.random.default_rng(0)
-        own = rng.uniform(0.0, 2.0, (3, 16, 16)).astype(np.float32)
-        relay = rng.uniform(0.0, 1.0, (3, 16, 16)).astype(np.float32)
-        for a in (own, relay):
-            a[:, np.arange(16), np.arange(16)] = 0.0
-        return dst, dense, own, relay
+        return (dst, dense) + _kernel_state("uniform")
 
     @pytest.mark.parametrize("vlb", [False, True])
-    @pytest.mark.parametrize("t", [0, 3, 7])
-    def test_pallas_kernel_bitwise_matches_ref_path(self, state, vlb, t):
-        import jax.numpy as jnp
-
-        from repro.kernels.rotor_slice import rotor_slice_step
-
-        dst, _, own, relay = state
-        own_j, relay_j = jnp.asarray(own), jnp.asarray(relay)
-        dst_j = jnp.asarray(dst[t])
-        ref = rotor_slice_step(own_j, relay_j, dst_j, vlb=vlb)
-        pal = rotor_slice_step(own_j, relay_j, dst_j, vlb=vlb,
-                               force_pallas=True)
-        for a, b in zip(ref, pal):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    @pytest.mark.parametrize("t", [
+        c if isinstance(c, int) else pytest.param(c, id="-".join(map(str, c)))
+        for c in PALLAS_CASES])
+    def test_pallas_kernel_bitwise_matches_ref_path(self, pallas_vs_ref,
+                                                    vlb, t):
+        """Own, relay, delivered and moved, bit for bit: the kernel's
+        one-hot gathers are exact and it sums in the ref's order."""
+        kind, groups, s = _case(t)
+        assert pallas_vs_ref[f"{kind}-{groups}-{s}-{vlb}"] == [0, 0, 0, 0]
 
     @pytest.mark.parametrize("vlb", [False, True])
     @pytest.mark.parametrize("t", [0, 3, 7])
