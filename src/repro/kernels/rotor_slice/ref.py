@@ -21,8 +21,8 @@ Two structural tricks keep it scatter-free (XLA CPU scatters serialize):
 
 `kernels/rotor_slice/kernel.py` is the Pallas form of this exact math
 and `ops.py` parity-gates the two; `fluid_jax._sparse_slice_step`,
-the scan body of the sparse engine's slice loop, drives it and `fluid.rotor_slice_step` (numpy, f64) stays the
-engine-level oracle.
+the scan body of the sparse engine's slice loop, drives it and
+`fluid.rotor_slice_step` (numpy, f64) stays the engine-level oracle.
 """
 from __future__ import annotations
 
@@ -148,7 +148,10 @@ def rotor_slice_faulted_ref(
            jnp.ndarray]:
     """Faulted slice step in edge layout — mirrors
     `fluid.rotor_slice_step_faulted` (and the dense
-    `fluid_jax._slice_step_faulted`); change the three together.
+    `fluid_jax._slice_step` given faults); change the three together.
+    It is also the faulted sparse engine's step body
+    (`fluid_jax._sparse_slice_step_faulted`): no kernel runs under
+    faults.
 
     Slot s of ``dst`` *is* switch s, so the per-uplink masks apply
     directly by slot — no switch-id gather.  An edge is down (really /

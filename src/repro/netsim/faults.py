@@ -23,11 +23,12 @@ scan:
   point);
 * `step_masks` is the shared numpy reference for that per-step mask
   math — the fluid oracle (`fluid.rotor_slice_step_faulted`) consumes
-  it directly and `fluid_jax._slice_step_faulted` mirrors it in jnp;
+  it directly and `fluid_jax._slice_masks` mirrors it in jnp;
 * `apply_flow_faults` projects a schedule onto a `FlowScenario` as
   per-flow blackhole/frozen windows plus per-step pool-capacity scales,
   the shape the flow-level pair (`flows._oracle_steps` /
-  `flows_jax._flow_step`) consumes.
+  `flows_jax._flow_step` and `_tiled_step`, given a `FlowFaults`)
+  consumes.
 
 Mask semantics (both engine pairs; the lockstep contract):
 
@@ -315,7 +316,7 @@ def step_masks(
     capacity (all slices — they share one serving switch) is known
     dead, the condition under which RotorLB forwards non-local traffic
     onward instead of waiting for a circuit that will not come.
-    `fluid_jax._slice_step_faulted` implements identical math in jnp —
+    `fluid_jax._slice_masks` implements identical math in jnp —
     change the two together."""
     sw = masks.switch_id[sl % masks.switch_id.shape[0]]
     up_f = (g >= masks.up_onset[b]) & (g < masks.up_recover[b])

@@ -313,7 +313,8 @@ def _oracle_steps(
     (-1 if unfinished) and deficit_mid/deficit_end are the NIC-bound
     service deficits (see `FlowScenario.deficit_allowance`) at the
     half-horizon / horizon snapshots.  `flows_jax._flow_step` implements
-    identical per-step math in jnp — change the two together."""
+    identical per-step math in jnp, the faulted branch as its optional
+    `FlowFaults` operand — change the two together."""
     n = scn.num_flows
     nic = scn.nic_Bps
     faulted = scn.has_faults

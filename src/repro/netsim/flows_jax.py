@@ -44,13 +44,20 @@ programs are shaped by (batch, window, tile, chunk_steps) only — never
 by the scenario's flow count — so one lowering serves every load and
 seed of a design point (pinned by staticcheck's
 `count_tiled_lowerings`).
+
+Faults: each engine is one jitted program whose last operand is an
+optional `FlowFaults` pytree (`faults.apply_flow_faults` windows and
+pool scales).  ``None`` is resolved at trace time, so the failure-free
+lowering carries no fault work at all; jit keys its cache on the
+operand's pytree structure, so every failure draw shares the one
+faulted lowering.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
 import itertools
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -94,20 +101,39 @@ def resolve_flow_engine(engine: str, n_max: int, trace: bool = False) -> str:
     return engine
 
 
-def dense_state_bytes(num_flows: int, batch: int = 1) -> int:
-    """Peak device-resident per-flow state of the dense engine (fault-
-    free): f32 remaining/allow_mid/allow_end/arr_ms plus the two carried
-    deficit-snapshot vectors, int32 start/class/done_step, bool is_bulk
-    — 37 B per flow slot, held for the scenario's whole lifetime."""
-    return batch * num_flows * 37
+class FlowOps(NamedTuple):
+    """The flow programs' per-scenario operands, each with a leading
+    scenario axis: per-flow arrays (``rem0`` remaining bytes at start,
+    activation step, bulk flag, FCT class, arrival ms; the dense engine's
+    NIC-bound deficit allowances) and per-scenario scalars (pool
+    capacities, dt, the two deficit-snapshot steps)."""
+
+    rem0: jax.Array
+    start: jax.Array
+    is_bulk: jax.Array
+    class_id: jax.Array
+    arr_ms: jax.Array
+    lat_u: jax.Array
+    bulk_u: jax.Array
+    dt_ms: jax.Array
+    mid_step: jax.Array
+    end_step: jax.Array
+    allow_mid: Optional[jax.Array] = None   # dense engine only
+    allow_end: Optional[jax.Array] = None   # dense engine only
 
 
-def tiled_state_bytes(window_tiles: int, tile_size: int,
-                      batch: int = 1) -> int:
-    """Peak device-resident per-flow state of the tiled engine (fault-
-    free): f32 rem/rem0/arr_ms, int32 start/class, bool is_bulk — 21 B
-    per *window slot*, independent of total flow count."""
-    return batch * window_tiles * tile_size * 21
+class FlowFaults(NamedTuple):
+    """The faulted programs' extra operand (`faults.apply_flow_faults`):
+    per-flow blackhole/frozen windows, shaped like the flow arrays, and
+    per-step pool-capacity scales (B, steps), scanned with the steps —
+    inside a step body each scale is that step's scalar."""
+
+    blk_start: jax.Array
+    blk_end: jax.Array
+    frz_start: jax.Array
+    frz_end: jax.Array
+    lat_scale: jax.Array
+    bulk_scale: jax.Array
 
 
 def _hist_accumulate(hist, fct_sum, newly, class_id, step, arr_ms, dt_ms):
@@ -126,58 +152,103 @@ def _hist_accumulate(hist, fct_sum, newly, class_id, step, arr_ms, dt_ms):
     return hist, fct_sum
 
 
-def _flow_step(carry, step, scn_ops, trace: bool):
+def _fault_gates(step, active, faults):
+    """(sharing, blackhole) masks of one step.  Under `faults`, frozen
+    flows (detected-dead ToR) leave the share computation and
+    blackholed flows (dead circuit, pre-detection) consume their share
+    with zero progress; without, every active flow shares and none is
+    lost (``blackhole`` None)."""
+    if faults is None:
+        return active, None
+    frozen = (step >= faults.frz_start) & (step < faults.frz_end)
+    blackhole = (step >= faults.blk_start) & (step < faults.blk_end)
+    return active & ~frozen, blackhole
+
+
+def _serve_pools(rem, sharing, blackhole, ops, faults, count):
+    """Serve the latency pool, then the bulk pool, for one step: each
+    pool (scaled by the step's surviving-capacity fraction under
+    `faults`) is split evenly over its sharing flows, `count` of them,
+    at most one NIC-step (1.0) a flow.  Yields each pool's member mask
+    and the remaining bytes after it is served."""
+    scales = ((None, None) if faults is None
+              else (faults.lat_scale, faults.bulk_scale))
+    for pool_u, scale, mask in (
+        (ops.lat_u, scales[0], sharing & ~ops.is_bulk),
+        (ops.bulk_u, scales[1], sharing & ops.is_bulk),
+    ):
+        if scale is not None:
+            pool_u = pool_u * scale
+        m = mask.astype(rem.dtype)
+        k = count(m)
+        share = jnp.minimum(pool_u / jnp.maximum(k, 1.0), 1.0)
+        share = jnp.where(pool_u > 0, share, 0.0)
+        prog = (m if blackhole is None
+                else (mask & ~blackhole).astype(rem.dtype))
+        rem = rem - jnp.minimum(rem, share) * prog
+        yield mask, rem
+
+
+def _scan_steps(step_fn, carry, steps, faults):
+    """`lax.scan` of ``step_fn(carry, step)`` over `steps`; with `faults`
+    the pool scales are scanned alongside and each call gets
+    ``step_fn(carry, step, faults)`` with that step's scales."""
+    if faults is None:
+        return jax.lax.scan(step_fn, carry, steps)
+
+    def body(c, xs):
+        step, lat, bulk = xs
+        return step_fn(c, step, faults._replace(lat_scale=lat, bulk_scale=bulk))
+
+    return jax.lax.scan(body, carry,
+                        (steps, faults.lat_scale, faults.bulk_scale))
+
+
+def _flow_step(carry, step, ops, trace: bool, faults=None):
     """One fixed-dt step, pure jnp — the dense scan body.
 
     Mirrors `flows._oracle_steps` exactly (normalized units: every
-    flow's per-step NIC budget is 1.0); change the two together.
+    flow's per-step NIC budget is 1.0), and its faulted branch when
+    `faults` (this step's slice of a `FlowFaults`) is given: windows
+    are data (int32 comparisons), so one lowering serves every failure
+    draw.  Change the two together.
     """
     remaining, done_step, rem_mid, rem_end, hist, fct_sum = carry
-    (start, is_bulk, lat_u, bulk_u, allow_mid, allow_end, mid_step,
-     end_step, class_id, arr_ms, dt_ms) = scn_ops
-    active = (step >= start) & (remaining > 0)
+    active = (step >= ops.start) & (remaining > 0)
+    sharing, blackhole = _fault_gates(step, active, faults)
     # Deficit snapshots stay per-flow vectors; the host sums them at
     # float64 over real flows only, so appending never-active pad flows
     # is bitwise invisible (no device reduction to regroup).
     rem_mid = jnp.where(
-        step == mid_step, jnp.maximum(remaining - allow_mid, 0.0), rem_mid
+        step == ops.mid_step,
+        jnp.maximum(remaining - ops.allow_mid, 0.0), rem_mid,
     )
     rem_end = jnp.where(
-        step == end_step, jnp.maximum(remaining - allow_end, 0.0), rem_end
+        step == ops.end_step,
+        jnp.maximum(remaining - ops.allow_end, 0.0), rem_end,
     )
     newly_any = jnp.zeros(remaining.shape, bool)
-    for pool_u, mask in (
-        (lat_u, active & ~is_bulk),
-        (bulk_u, active & is_bulk),
-    ):
-        m = mask.astype(remaining.dtype)
-        k = m.sum()
-        share = jnp.minimum(pool_u / jnp.maximum(k, 1.0), 1.0)
-        share = jnp.where(pool_u > 0, share, 0.0)
-        remaining = remaining - jnp.minimum(remaining, share) * m
+    for mask, remaining in _serve_pools(remaining, sharing, blackhole,
+                                        ops, faults, jnp.sum):
         newly = mask & (remaining <= 0) & (done_step < 0)
         done_step = jnp.where(newly, step + 1, done_step)
         newly_any = newly_any | newly
     hist, fct_sum = _hist_accumulate(
-        hist, fct_sum, newly_any, class_id, step, arr_ms, dt_ms
+        hist, fct_sum, newly_any, ops.class_id, step, ops.arr_ms, ops.dt_ms
     )
     carry = (remaining, done_step, rem_mid, rem_end, hist, fct_sum)
     return carry, (remaining if trace else jnp.zeros((), remaining.dtype))
 
 
 @functools.partial(jax.jit, static_argnames=("num_steps", "trace"))
-def _run_batch(
-    remaining0, start_step, is_bulk, lat_u, bulk_u,
-    allow_mid, allow_end, mid_step, end_step,
-    class_id, arr_ms, dt_ms, num_steps: int, trace: bool,
-):
-    """vmap(scan): batch -> time steps.  All operands carry a leading
-    scenario axis except the shared step count."""
+def _run_batch(scn: FlowOps, num_steps: int, trace: bool,
+               faults: Optional[FlowFaults] = None):
+    """vmap(scan): batch -> time steps over the (B, n_max) flow state.
+    Every operand carries a leading scenario axis; `faults` adds the
+    fault windows (B, n_max) and pool scales (B, num_steps)."""
 
-    def one_scenario(rem0, start, bulk_mask, lat, blk, amid, aend,
-                     mstep, estep, cls, arr, dtm):
-        scn_ops = (start, bulk_mask, lat, blk, amid, aend, mstep, estep,
-                   cls, arr, dtm)
+    def one_scenario(ops, f):
+        rem0 = ops.rem0
         carry0 = (
             rem0,
             jnp.full(rem0.shape, -1, jnp.int32),
@@ -188,251 +259,76 @@ def _run_batch(
         )
         steps = jnp.arange(num_steps, dtype=jnp.int32)
         (remaining, done_step, rem_mid, rem_end, hist, fct_sum), ys = (
-            jax.lax.scan(
-                lambda c, s: _flow_step(c, s, scn_ops, trace), carry0, steps
-            )
+            _scan_steps(lambda c, s, *f_t: _flow_step(c, s, ops, trace, *f_t),
+                        carry0, steps, f)
         )
         return remaining, done_step, rem_mid, rem_end, hist, fct_sum, ys
 
-    return jax.vmap(one_scenario)(
-        remaining0, start_step, is_bulk, lat_u, bulk_u,
-        allow_mid, allow_end, mid_step, end_step, class_id, arr_ms, dt_ms,
-    )
-
-
-def _flow_step_faulted(carry, xs, scn_ops, trace: bool):
-    """`_flow_step` under per-flow fault windows and per-step pool
-    scales — the faulted dense scan body.
-
-    Mirrors `flows._oracle_steps`'s faulted branch exactly: frozen flows
-    (detected-dead ToR) leave the share computation, blackholed flows
-    (dead circuit, pre-detection) consume their share with zero
-    progress, and each pool is scaled by the step's surviving-capacity
-    fraction; change the two together.  Windows are data (int32
-    comparisons), so one lowering serves every failure draw.
-    """
-    remaining, done_step, rem_mid, rem_end, hist, fct_sum = carry
-    step, lat_scale_t, bulk_scale_t = xs
-    (start, is_bulk, lat_u, bulk_u, allow_mid, allow_end, mid_step,
-     end_step, class_id, arr_ms, dt_ms,
-     blk_start, blk_end, frz_start, frz_end) = scn_ops
-    active = (step >= start) & (remaining > 0)
-    frozen = (step >= frz_start) & (step < frz_end)
-    blackhole = (step >= blk_start) & (step < blk_end)
-    sharing = active & ~frozen
-    # per-flow snapshots, host-summed — see `_flow_step`
-    rem_mid = jnp.where(
-        step == mid_step, jnp.maximum(remaining - allow_mid, 0.0), rem_mid
-    )
-    rem_end = jnp.where(
-        step == end_step, jnp.maximum(remaining - allow_end, 0.0), rem_end
-    )
-    newly_any = jnp.zeros(remaining.shape, bool)
-    for pool_u, scale_t, mask in (
-        (lat_u, lat_scale_t, sharing & ~is_bulk),
-        (bulk_u, bulk_scale_t, sharing & is_bulk),
-    ):
-        pool_u = pool_u * scale_t
-        m = mask.astype(remaining.dtype)
-        k = m.sum()
-        share = jnp.minimum(pool_u / jnp.maximum(k, 1.0), 1.0)
-        share = jnp.where(pool_u > 0, share, 0.0)
-        prog = (mask & ~blackhole).astype(remaining.dtype)
-        remaining = remaining - jnp.minimum(remaining, share) * prog
-        newly = mask & (remaining <= 0) & (done_step < 0)
-        done_step = jnp.where(newly, step + 1, done_step)
-        newly_any = newly_any | newly
-    hist, fct_sum = _hist_accumulate(
-        hist, fct_sum, newly_any, class_id, step, arr_ms, dt_ms
-    )
-    carry = (remaining, done_step, rem_mid, rem_end, hist, fct_sum)
-    return carry, (remaining if trace else jnp.zeros((), remaining.dtype))
-
-
-@functools.partial(jax.jit, static_argnames=("num_steps", "trace"))
-def _run_batch_faulted(
-    remaining0, start_step, is_bulk, lat_u, bulk_u,
-    allow_mid, allow_end, mid_step, end_step, class_id, arr_ms, dt_ms,
-    blk_start, blk_end, frz_start, frz_end, lat_scale, bulk_scale,
-    num_steps: int, trace: bool,
-):
-    """`_run_batch` with per-flow fault windows (B, n) and per-step pool
-    scales (B, num_steps) vmapped alongside the flow state."""
-
-    def one_scenario(rem0, start, bulk_mask, lat, blk, amid, aend,
-                     mstep, estep, cls, arr, dtm, bs, be, fs, fe, lsc, bsc):
-        scn_ops = (start, bulk_mask, lat, blk, amid, aend, mstep, estep,
-                   cls, arr, dtm, bs, be, fs, fe)
-        carry0 = (
-            rem0,
-            jnp.full(rem0.shape, -1, jnp.int32),
-            jnp.zeros(rem0.shape, rem0.dtype),
-            jnp.zeros(rem0.shape, rem0.dtype),
-            jnp.zeros(NUM_FCT_CLASSES * FCT_HIST_BINS, jnp.int32),
-            jnp.zeros((), rem0.dtype),
-        )
-        steps = jnp.arange(num_steps, dtype=jnp.int32)
-        (remaining, done_step, rem_mid, rem_end, hist, fct_sum), ys = (
-            jax.lax.scan(
-                lambda c, xs: _flow_step_faulted(c, xs, scn_ops, trace),
-                carry0, (steps, lsc, bsc)
-            )
-        )
-        return remaining, done_step, rem_mid, rem_end, hist, fct_sum, ys
-
-    return jax.vmap(one_scenario)(
-        remaining0, start_step, is_bulk, lat_u, bulk_u,
-        allow_mid, allow_end, mid_step, end_step, class_id, arr_ms, dt_ms,
-        blk_start, blk_end, frz_start, frz_end, lat_scale, bulk_scale,
-    )
+    return jax.vmap(one_scenario)(scn, faults)
 
 
 # ---------------- tiled streaming engine -------------------------------
 
 
-def _tiled_step(carry, step, scn_ops):
+def _tiled_step(carry, step, ops, faults=None):
     """One fixed-dt step over the (window, tile) active slice — the
-    tiled scan body.  Identical per-flow math to `_flow_step` /
-    `flows._oracle_steps` (change them together); the two-pass
+    tiled scan body; ``ops`` is (`FlowOps` row, horizon in steps).
+    Identical per-flow math to `_flow_step` / `flows._oracle_steps`,
+    faulted branch included (change them together); the two-pass
     reduction (per-tile counts -> global share -> per-tile apply) only
     regroups exact small-integer sums, so shares and therefore
     remaining-byte trajectories and histograms match the dense engine
     bitwise.  `live` gates steps past the scenario horizon in the final
     partial chunk."""
     rem, hist, fct_sum, rem_mid, rem_end = carry
-    (rem0, start, is_bulk, class_id, arr_ms, lat_u, bulk_u, dt_ms,
-     mid_step, end_step, num_steps) = scn_ops
+    scn, num_steps = ops
     live = step < num_steps
-    active = live & (step >= start) & (rem > 0)
+    active = live & (step >= scn.start) & (rem > 0)
+    sharing, blackhole = _fault_gates(step, active, faults)
     # NIC-bound deficit allowance on device (normalized units: a
     # dedicated NIC serves exactly 1.0 per step).  Flows outside the
     # window contribute zero deficit: drained tiles have rem == 0,
     # future tiles rem == rem0 == allow.
-    allow = rem0 - jnp.minimum(
-        rem0, jnp.maximum(step - start, 0).astype(rem.dtype)
+    allow = scn.rem0 - jnp.minimum(
+        scn.rem0, jnp.maximum(step - scn.start, 0).astype(rem.dtype)
     )
     deficit = jnp.maximum(rem - allow, 0.0).sum()
-    rem_mid = jnp.where(live & (step == mid_step), deficit, rem_mid)
-    rem_end = jnp.where(live & (step == end_step), deficit, rem_end)
+    rem_mid = jnp.where(live & (step == scn.mid_step), deficit, rem_mid)
+    rem_end = jnp.where(live & (step == scn.end_step), deficit, rem_end)
     newly_any = jnp.zeros(rem.shape, bool)
-    for pool_u, mask in (
-        (lat_u, active & ~is_bulk),
-        (bulk_u, active & is_bulk),
+    for mask, rem in _serve_pools(
+        rem, sharing, blackhole, scn, faults,
+        lambda m: m.sum(axis=-1).sum(),   # per-tile counts -> global pool
     ):
-        m = mask.astype(rem.dtype)
-        k = m.sum(axis=-1).sum()          # per-tile counts -> global pool
-        share = jnp.minimum(pool_u / jnp.maximum(k, 1.0), 1.0)
-        share = jnp.where(pool_u > 0, share, 0.0)
-        rem = rem - jnp.minimum(rem, share) * m
         newly_any = newly_any | (mask & (rem <= 0))
     hist, fct_sum = _hist_accumulate(
-        hist, fct_sum, newly_any, class_id, step, arr_ms, dt_ms
-    )
-    return (rem, hist, fct_sum, rem_mid, rem_end)
-
-
-def _tiled_step_faulted(carry, xs, scn_ops):
-    """`_tiled_step` under per-flow fault windows and per-step pool
-    scales — mirrors `_flow_step_faulted` / the oracle's faulted branch
-    exactly; change them together."""
-    rem, hist, fct_sum, rem_mid, rem_end = carry
-    step, lat_scale_t, bulk_scale_t = xs
-    (rem0, start, is_bulk, class_id, arr_ms, lat_u, bulk_u, dt_ms,
-     mid_step, end_step, blk_start, blk_end, frz_start, frz_end,
-     num_steps) = scn_ops
-    live = step < num_steps
-    active = live & (step >= start) & (rem > 0)
-    frozen = (step >= frz_start) & (step < frz_end)
-    blackhole = (step >= blk_start) & (step < blk_end)
-    sharing = active & ~frozen
-    allow = rem0 - jnp.minimum(
-        rem0, jnp.maximum(step - start, 0).astype(rem.dtype)
-    )
-    deficit = jnp.maximum(rem - allow, 0.0).sum()
-    rem_mid = jnp.where(live & (step == mid_step), deficit, rem_mid)
-    rem_end = jnp.where(live & (step == end_step), deficit, rem_end)
-    newly_any = jnp.zeros(rem.shape, bool)
-    for pool_u, scale_t, mask in (
-        (lat_u, lat_scale_t, sharing & ~is_bulk),
-        (bulk_u, bulk_scale_t, sharing & is_bulk),
-    ):
-        pool_u = pool_u * scale_t
-        m = mask.astype(rem.dtype)
-        k = m.sum(axis=-1).sum()
-        share = jnp.minimum(pool_u / jnp.maximum(k, 1.0), 1.0)
-        share = jnp.where(pool_u > 0, share, 0.0)
-        prog = (mask & ~blackhole).astype(rem.dtype)
-        rem = rem - jnp.minimum(rem, share) * prog
-        newly_any = newly_any | (mask & (rem <= 0))
-    hist, fct_sum = _hist_accumulate(
-        hist, fct_sum, newly_any, class_id, step, arr_ms, dt_ms
+        hist, fct_sum, newly_any, scn.class_id, step, scn.arr_ms, scn.dt_ms
     )
     return (rem, hist, fct_sum, rem_mid, rem_end)
 
 
 @functools.partial(jax.jit, static_argnames=("num_steps", "chunk_steps"))
-def _run_tiled_chunk(
-    rem, rem0, start, is_bulk, class_id, arr_ms,
-    lat_u, bulk_u, dt_ms, mid_step, end_step,
-    hist, fct_sum, rem_mid, rem_end, step0,
-    num_steps: int, chunk_steps: int,
-):
+def _run_tiled_chunk(carry, scn: FlowOps, step0, num_steps: int,
+                     chunk_steps: int, faults: Optional[FlowFaults] = None):
     """`chunk_steps` scan steps over the (B, W, T) active windows, one
-    device dispatch.  Histograms and deficit snapshots stay device-
-    resident across chunks; only the window's remaining bytes round-
-    trip to the host (for tile retirement).  Shapes depend on the
-    window geometry only — never on the scenario's total flow count —
-    so one lowering serves every load and seed of a design point."""
+    device dispatch.  ``carry`` is (rem, hist, fct_sum, rem_mid,
+    rem_end): histograms and deficit snapshots stay device-resident
+    across chunks; only the window's remaining bytes round-trip to the
+    host (for tile retirement).  `faults` adds the windows' fault
+    windows (B, W, T) and this chunk's pool scales (B, chunk_steps).
+    Shapes depend on the window geometry only — never on the scenario's
+    total flow count — so one lowering serves every load and seed of a
+    design point."""
     steps = step0 + jnp.arange(chunk_steps, dtype=jnp.int32)
 
-    def one_scenario(rm, r0, st, bm, cls, arr, lat, blk, dtm, mstep, estep,
-                     h, fs, rmid, rend):
-        scn_ops = (r0, st, bm, cls, arr, lat, blk, dtm, mstep, estep,
-                   num_steps)
+    def one_scenario(c0, scn_row, f):
+        ops = (scn_row, num_steps)
+        c, _ = _scan_steps(
+            lambda c, s, *f_t: (_tiled_step(c, s, ops, *f_t), None),
+            c0, steps, f)
+        return c
 
-        def body(c, s):
-            return _tiled_step(c, s, scn_ops), None
-
-        carry, _ = jax.lax.scan(body, (rm, h, fs, rmid, rend), steps)
-        return carry
-
-    return jax.vmap(one_scenario)(
-        rem, rem0, start, is_bulk, class_id, arr_ms,
-        lat_u, bulk_u, dt_ms, mid_step, end_step,
-        hist, fct_sum, rem_mid, rem_end,
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("num_steps", "chunk_steps"))
-def _run_tiled_chunk_faulted(
-    rem, rem0, start, is_bulk, class_id, arr_ms,
-    lat_u, bulk_u, dt_ms, mid_step, end_step,
-    blk_start, blk_end, frz_start, frz_end, lat_scale, bulk_scale,
-    hist, fct_sum, rem_mid, rem_end, step0,
-    num_steps: int, chunk_steps: int,
-):
-    """`_run_tiled_chunk` with per-flow fault windows (B, W, T) and this
-    chunk's per-step pool scales (B, chunk_steps)."""
-    steps = step0 + jnp.arange(chunk_steps, dtype=jnp.int32)
-
-    def one_scenario(rm, r0, st, bm, cls, arr, lat, blk, dtm, mstep, estep,
-                     bs, be, fs_, fe, lsc, bsc, h, fsum, rmid, rend):
-        scn_ops = (r0, st, bm, cls, arr, lat, blk, dtm, mstep, estep,
-                   bs, be, fs_, fe, num_steps)
-
-        def body(c, xs):
-            return _tiled_step_faulted(c, xs, scn_ops), None
-
-        carry, _ = jax.lax.scan(body, (rm, h, fsum, rmid, rend),
-                                (steps, lsc, bsc))
-        return carry
-
-    return jax.vmap(one_scenario)(
-        rem, rem0, start, is_bulk, class_id, arr_ms,
-        lat_u, bulk_u, dt_ms, mid_step, end_step,
-        blk_start, blk_end, frz_start, frz_end, lat_scale, bulk_scale,
-        hist, fct_sum, rem_mid, rem_end,
-    )
+    return jax.vmap(one_scenario)(carry, scn, faults)
 
 
 class _TiledState:
@@ -535,21 +431,20 @@ def _simulate_flows_tiled(
     faulted = any(s.has_faults for s in scenarios)
     with obs.span("flows.prepare"):
         states = [_TiledState(s, T, num_steps, faulted) for s in scenarios]
-        lat_u = jnp.asarray([s.lat_pool_Bps / s.nic_Bps for s in scenarios], dtype)
-        bulk_u = jnp.asarray([s.bulk_pool_Bps / s.nic_Bps for s in scenarios], dtype)
-        dt_ms = jnp.asarray([s.dt_s * 1e3 for s in scenarios], dtype)
-        mid_step = jnp.asarray([s.mid_step for s in scenarios], jnp.int32)
-        end_step = jnp.asarray([s.end_step for s in scenarios], jnp.int32)
-        obs.count("flows.h2d_bytes", sum(
-            x.nbytes for x in (lat_u, bulk_u, dt_ms, mid_step, end_step)))
+        scalars = dict(
+            lat_u=jnp.asarray([s.lat_pool_Bps / s.nic_Bps for s in scenarios], dtype),
+            bulk_u=jnp.asarray([s.bulk_pool_Bps / s.nic_Bps for s in scenarios], dtype),
+            dt_ms=jnp.asarray([s.dt_s * 1e3 for s in scenarios], dtype),
+            mid_step=jnp.asarray([s.mid_step for s in scenarios], jnp.int32),
+            end_step=jnp.asarray([s.end_step for s in scenarios], jnp.int32),
+        )
+        obs.count("flows.h2d_bytes", sum(x.nbytes for x in scalars.values()))
         hist = jnp.zeros((B, NUM_FCT_CLASSES * FCT_HIST_BINS), jnp.int32)
         fct_sum = jnp.zeros((B,), dtype)
         rem_mid = jnp.zeros((B,), dtype)
         rem_end = jnp.zeros((B,), dtype)
 
-    window_names = ("rem", "rem0", "start", "is_bulk", "class_id", "arr_ms")
     if faulted:
-        window_names += ("blk_start", "blk_end", "frz_start", "frz_end")
         from repro.netsim.faults import NEVER
 
     W = int(window_tiles)
@@ -579,33 +474,29 @@ def _simulate_flows_tiled(
                 if faulted:
                     for name in ("blk_start", "blk_end", "frz_start", "frz_end"):
                         row[name] = np.full((B, W, T), NEVER, np.int32)
-                    lsc = np.ones((B, C), np.float32)
-                    bsc = np.ones((B, C), np.float32)
+                for b, (st, w) in enumerate(zip(states, ws)):
+                    st.fill(row, b, w)
+                if faulted:
+                    lsc = row["lat_scale"] = np.ones((B, C), np.float32)
+                    bsc = row["bulk_scale"] = np.ones((B, C), np.float32)
                     for b, st in enumerate(states):
                         lsc[b, :chunk_end - c0] = st.lat_scale[c0:chunk_end]
                         bsc[b, :chunk_end - c0] = st.bulk_scale[c0:chunk_end]
-                for b, (st, w) in enumerate(zip(states, ws)):
-                    st.fill(row, b, w)
             with obs.span("flows.tiled.upload"):
-                operands = [jnp.asarray(row[name], dtype) if name in
-                            ("rem", "rem0", "arr_ms") else jnp.asarray(row[name])
-                            for name in window_names]
-                if faulted:
-                    operands += [jnp.asarray(lsc, dtype), jnp.asarray(bsc, dtype)]
-                obs.count("flows.h2d_bytes", sum(x.nbytes for x in operands))
+                # float arrays go up at the engine dtype
+                up = {name: jnp.asarray(a, dtype) if a.dtype == np.float32
+                      else jnp.asarray(a) for name, a in row.items()}
+                obs.count("flows.h2d_bytes", sum(x.nbytes for x in up.values()))
+                scn = FlowOps(rem0=up["rem0"], start=up["start"],
+                              is_bulk=up["is_bulk"], class_id=up["class_id"],
+                              arr_ms=up["arr_ms"], **scalars)
+                faults = (FlowFaults(**{f: up[f] for f in FlowFaults._fields})
+                          if faulted else None)
             with obs.span("flows.tiled.chunk"):
-                if faulted:
-                    rem_out, hist, fct_sum, rem_mid, rem_end = _run_tiled_chunk_faulted(
-                        *operands[:6], lat_u, bulk_u, dt_ms, mid_step, end_step,
-                        *operands[6:], hist, fct_sum, rem_mid, rem_end, c0,
-                        num_steps=num_steps, chunk_steps=C,
-                    )
-                else:
-                    rem_out, hist, fct_sum, rem_mid, rem_end = _run_tiled_chunk(
-                        *operands, lat_u, bulk_u, dt_ms, mid_step, end_step,
-                        hist, fct_sum, rem_mid, rem_end, c0,
-                        num_steps=num_steps, chunk_steps=C,
-                    )
+                rem_out, hist, fct_sum, rem_mid, rem_end = _run_tiled_chunk(
+                    (up["rem"], hist, fct_sum, rem_mid, rem_end), scn, c0,
+                    num_steps=num_steps, chunk_steps=C, faults=faults,
+                )
             with obs.span("flows.tiled.readback"):
                 rem_np = np.asarray(rem_out)
             with obs.span("flows.tiled.retire"):
@@ -667,9 +558,9 @@ def simulate_flows_batch(
     All scenarios must share dt/horizon/tail (one static step count per
     compiled program); flow counts may differ — shorter rows are padded
     with never-active flows.  Rows carrying a fault projection
-    (`faults.apply_flow_faults`) route the whole batch through the
-    faulted lowering; fault-free batches run the original program
-    untouched (bit-identical no-op dispatch).  `engine="auto"` picks
+    (`faults.apply_flow_faults`) give the whole batch a `FlowFaults`
+    operand; fault-free batches run without one, as the failure-free
+    lowering (bit-identical no-op dispatch).  `engine="auto"` picks
     tiled once the largest scenario reaches `TILED_AUTO_FLOWS` flows
     (trace mode forces dense and is size-gated by `TRACE_MAX_ELEMS`).
     """
@@ -720,8 +611,8 @@ def simulate_flows_batch(
         faulted = any(s.has_faults for s in scenarios)
         if faulted:
             # NEVER-filled windows for fault-free rows and pad flows; unit
-            # scales for fault-free rows — the faulted step then reduces to
-            # the plain recurrence for them (to f32 fusion tolerance).
+            # scales for fault-free rows — the faulted step then computes
+            # the plain recurrence for them (x * 1.0, never-true windows).
             from repro.netsim.faults import NEVER
 
             blk_start = np.full((B, n_max), NEVER, np.int32)
@@ -754,38 +645,33 @@ def simulate_flows_batch(
                 lat_scale[b] = s.lat_scale[:num_steps]
                 bulk_scale[b] = s.bulk_scale[:num_steps]
 
-        common = (
-            jnp.asarray(remaining0, dtype),
-            jnp.asarray(start_step),
-            jnp.asarray(is_bulk),
-            jnp.asarray(lat_u, dtype),
-            jnp.asarray(bulk_u, dtype),
-            jnp.asarray(allow_mid, dtype),
-            jnp.asarray(allow_end, dtype),
-            jnp.asarray(mid_step),
-            jnp.asarray(end_step),
-            jnp.asarray(class_id),
-            jnp.asarray(arr_ms, dtype),
-            jnp.asarray(dt_ms, dtype),
+        scn = FlowOps(
+            rem0=jnp.asarray(remaining0, dtype),
+            start=jnp.asarray(start_step),
+            is_bulk=jnp.asarray(is_bulk),
+            class_id=jnp.asarray(class_id),
+            arr_ms=jnp.asarray(arr_ms, dtype),
+            lat_u=jnp.asarray(lat_u, dtype),
+            bulk_u=jnp.asarray(bulk_u, dtype),
+            dt_ms=jnp.asarray(dt_ms, dtype),
+            mid_step=jnp.asarray(mid_step),
+            end_step=jnp.asarray(end_step),
+            allow_mid=jnp.asarray(allow_mid, dtype),
+            allow_end=jnp.asarray(allow_end, dtype),
         )
-        fault_ops = ()
+        faults = None
         if faulted:
-            fault_ops = (
+            faults = FlowFaults(
                 jnp.asarray(blk_start), jnp.asarray(blk_end),
                 jnp.asarray(frz_start), jnp.asarray(frz_end),
                 jnp.asarray(lat_scale, dtype), jnp.asarray(bulk_scale, dtype),
             )
-        obs.count("flows.h2d_bytes", sum(x.nbytes for x in common + fault_ops))
+        obs.count("flows.h2d_bytes",
+                  sum(x.nbytes for x in jax.tree.leaves((scn, faults))))
 
     with obs.span("flows.run"):
-        if faulted:
-            remaining, done_step, rem_mid, rem_end, hist, _, ys = (
-                _run_batch_faulted(*common, *fault_ops, num_steps, bool(trace))
-            )
-        else:
-            remaining, done_step, rem_mid, rem_end, hist, _, ys = _run_batch(
-                *common, num_steps, bool(trace),
-            )
+        remaining, done_step, rem_mid, rem_end, hist, _, ys = _run_batch(
+            scn, num_steps, bool(trace), faults)
     with obs.span("flows.readback"):
         done_step = np.asarray(done_step)
         # Device f32 results are de-normalized on the host at float64,

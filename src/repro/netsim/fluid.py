@@ -139,8 +139,8 @@ def rotor_slice_step_faulted(
     With all-zero masks every expression reduces to the exact
     failure-free arithmetic (x*1.0 and x+0.0 are IEEE-exact), so
     `FailureSchedule.empty()` is bit-identical to `rotor_slice_step`.
-    `fluid_jax._slice_step_faulted` implements the same math in jnp —
-    change the two together.  Returns (own, relay, delivered_bytes,
+    `fluid_jax._slice_step` given faults implements the same math in
+    jnp — change the two together.  Returns (own, relay, delivered_bytes,
     vlb_first_hop_bytes, blackholed_bytes).
     """
     cap = adj_cap * (1.0 - e_known) * (1.0 - tor_real)[:, None]

@@ -38,12 +38,22 @@ Two engines share the public API (`engine=` on
   below.  Both engines agree with the oracle at f32 ulp tolerance
   (tests/test_rotor_slice.py pins sparse-vs-dense on every default
   Appendix-B point, faulted and unfaulted).
+
+Faults: each engine is one jitted program (`_run_batch`,
+`_run_batch_sparse`) whose fault operand is an optional `RotorFaults`
+pytree.  ``None`` is resolved at trace time, so the failure-free
+lowering carries no mask work at all; given one, the scan carries the
+global step counter and blackholed total, rebuilds the per-step masks
+from the timelines, and may pace the demand.  jit keys its cache on the
+operand's pytree structure, so every failure draw shares one faulted
+lowering.  The faulted sparse step has no kernel: it runs
+`ref.rotor_slice_faulted_ref`.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -56,62 +66,185 @@ from repro.core.topology import OperaTopology, build_opera_topology
 from repro.netsim.fluid import RotorFluidResult
 
 
-def _slice_step(state, adj, vlb: bool):
-    """One topology slice, pure jnp — the scan body.
+_TIMELINES = ("up_onset", "up_detect", "up_recover",
+              "tor_onset", "tor_detect", "tor_recover")
+
+
+class RotorFaults(NamedTuple):
+    """The faulted programs' extra operand: a `faults.FaultMasks` on the
+    device.  The six component timelines carry a leading batch axis, one
+    failure draw per row; ``pair_sw`` (N, N) — the one switch serving
+    each rack pair — and, for the dense engine, ``switch_id`` (S, N, N)
+    are shared design-time maps."""
+
+    pair_sw: jax.Array
+    up_onset: jax.Array      # (B, N, S+1)
+    up_detect: jax.Array
+    up_recover: jax.Array
+    tor_onset: jax.Array     # (B, N)
+    tor_detect: jax.Array
+    tor_recover: jax.Array
+    switch_id: Optional[jax.Array] = None   # dense engine only
+
+    @classmethod
+    def from_masks(cls, masks, dense: bool) -> "RotorFaults":
+        return cls(
+            jnp.asarray(masks.pair_switch),
+            *(jnp.asarray(getattr(masks, f)) for f in _TIMELINES),
+            switch_id=jnp.asarray(masks.switch_id) if dense else None,
+        )
+
+
+# vmap axes of a `RotorFaults`: timelines per row, maps shared
+_ROW_AXES = RotorFaults(None, *(0,) * len(_TIMELINES), switch_id=None)
+
+
+def _slice_masks(g, sw, faults, dtype):
+    """Per-step failure masks of one scenario at global step `g`, from
+    its component timelines (int32 comparisons, `faults.step_masks` is
+    the numpy reference): (e_real, e_known, pair_dead, tor_real,
+    tor_known)."""
+    up_f = (g >= faults.up_onset) & (g < faults.up_recover)
+    up_k = (g >= faults.up_detect) & (g < faults.up_recover)
+    tor_fb = (g >= faults.tor_onset) & (g < faults.tor_recover)
+    tor_kb = (g >= faults.tor_detect) & (g < faults.tor_recover)
+    i_f = jnp.take_along_axis(up_f, sw, axis=1)
+    i_k = jnp.take_along_axis(up_k, sw, axis=1)
+    e_real = (i_f | i_f.T | tor_fb[:, None] | tor_fb[None, :]).astype(dtype)
+    e_known = (i_k | i_k.T | tor_kb[:, None] | tor_kb[None, :]).astype(dtype)
+    p_k = jnp.take_along_axis(up_k, faults.pair_sw, axis=1)
+    pair_dead = (
+        p_k | p_k.T | tor_kb[:, None] | tor_kb[None, :]
+    ).astype(dtype)
+    return e_real, e_known, pair_dead, tor_fb.astype(dtype), tor_kb.astype(dtype)
+
+
+def _slice_step(state, xs, vlb: bool, faults=None):
+    """One topology slice, pure jnp — the dense scan body.
 
     Mirrors `fluid.rotor_slice_step` exactly (normalized units: every
-    live edge's capacity is 1.0); change the two together.
+    live edge's capacity is 1.0); change the two together.  Given
+    `faults` (one row of a `RotorFaults`) it mirrors
+    `fluid.rotor_slice_step_faulted` instead: ``state`` then also carries
+    the blackholed total and the global step counter, ``xs`` is
+    (adjacency, switch ids), and the masks are rebuilt from the counter
+    each step — masks are data, so one lowering serves every failure
+    draw.  Under an empty schedule the faulted expressions reduce to the
+    clean ones (x*1.0, x+0.0) but XLA may order reductions differently,
+    a last-ulp drift: the public API runs event-less schedules clean
+    (`_faults_all_empty`).
     """
-    own, relay, done, wire = state
-    send_own = jnp.minimum(own, adj)
-    own = own - send_own
-    room = adj - send_own
+    if faults is None:
+        own, relay, done, wire = state
+        cap = xs
+    else:
+        own, relay, done, wire, blk, g = state
+        adj, sw = xs
+        e_real, e_known, pair_dead, tor_real, tor_known = _slice_masks(
+            g, sw, faults, own.dtype)
+        cap = adj * (1.0 - e_known) * (1.0 - tor_real)[:, None]
+        arrive = 1.0 - e_real
+
+    def landed(x):   # what a send delivers: all of it unless faulted
+        return x if faults is None else x * arrive
+
+    send_own = jnp.minimum(own, cap)
+    own = own - landed(send_own)
+    room = cap - send_own
     send_relay = jnp.minimum(relay, room)
-    relay = relay - send_relay
+    relay = relay - landed(send_relay)
     room = room - send_relay
-    delivered = send_own.sum() + send_relay.sum()
+    delivered = landed(send_own).sum() + landed(send_relay).sum()
     done = done + delivered
     wire = wire + delivered
+    if faults is not None:
+        # lost sends summed directly (see fluid.rotor_slice_step_faulted)
+        blk = blk + (send_own * e_real).sum() + (send_relay * e_real).sum()
     if vlb:
-        elig = jnp.where(adj > 0, 0.0, own)
-        q = elig.sum(1)
+        if faults is None:
+            elig = jnp.where(cap > 0, 0.0, own)
+            q = elig.sum(1)
+        else:
+            dst_ok = 1.0 - tor_known
+            elig = jnp.where(cap > 0, 0.0, own * dst_ok[None, :])
+            relig = relay * pair_dead * dst_ok[None, :]  # stuck relay re-spreads
+            q = elig.sum(1) + relig.sum(1)
         r = room.sum(1)
         t = jnp.minimum(q, r)
-        take = elig * jnp.where(q > 0, t / jnp.maximum(q, 1e-30), 0.0)[:, None]
+        frac = jnp.where(q > 0, t / jnp.maximum(q, 1e-30), 0.0)[:, None]
+        take = elig * frac
+        if faults is not None:
+            rtake = relig * frac
         share = room * jnp.where(r > 0, 1.0 / jnp.maximum(r, 1e-30), 0.0)[:, None]
-        own = own - take
         # HIGHEST: the TPU's default f32 matmul rounds through bf16
-        relay = relay + jnp.matmul(share.T, take,
-                                   precision=jax.lax.Precision.HIGHEST)
-        wire = wire + t.sum()
-    return (own, relay, done, wire), (done, wire)
+        hi = jax.lax.Precision.HIGHEST
+        if faults is None:
+            own = own - take
+            relay = relay + jnp.matmul(share.T, take, precision=hi)
+            wire = wire + t.sum()
+        else:
+            lost = (share * e_real).sum(1)
+            own = own - take + take * lost[:, None]
+            relay = relay - rtake + rtake * lost[:, None]
+            relay = relay + jnp.matmul((share * arrive).T, take + rtake,
+                                       precision=hi)
+            lost_sum = ((take + rtake).sum(1) * lost).sum()
+            wire = wire + (t.sum() - lost_sum)
+            blk = blk + lost_sum
+    if faults is None:
+        return (own, relay, done, wire), (done, wire)
+    return (own, relay, done, wire, blk, g + 1), (done, wire)
 
 
-@functools.partial(jax.jit, static_argnames=("vlb", "num_cycles"))
-def _run_batch(adj, own0, vlb: bool, num_cycles: int):
+@functools.partial(
+    jax.jit, static_argnames=("vlb", "num_cycles", "paced_cycles")
+)
+def _run_batch(adj, own0, vlb: bool, num_cycles: int,
+               faults: Optional[RotorFaults] = None, paced_cycles: int = 0):
     """vmap(scan(scan)): batch -> cycles -> slices.  Returns cumulative
     delivered/wire trajectories (B, num_cycles*num_slices) and the final
-    undelivered residual (B,), all in normalized units."""
+    undelivered residual (B,), all in normalized units.
 
-    def one_scenario(own_init):
+    `faults` runs every row under its own failure draw and appends the
+    per-row blackholed total to the outputs.  `paced_cycles`, which
+    needs `faults`, injects each row's demand in equal parts at the
+    first that many cycle starts instead of all at t=0.  The topology
+    tensor and the fault maps are shared design-time state."""
+
+    def one_scenario(own_init, f):
         step = functools.partial(_slice_step, vlb=vlb)
+        xs = adj
+        own_start = own_init
+        if f is not None:
+            step = functools.partial(step, faults=f)
+            xs = (adj, f.switch_id)
+            if paced_cycles:
+                inject = own_init * (1.0 / paced_cycles)
+                own_start = jnp.zeros_like(own_init)
 
-        def one_cycle(carry, _):
-            carry, ys = jax.lax.scan(step, carry, adj)
+        def one_cycle(carry, c):
+            if paced_cycles:
+                own = carry[0] + inject * (c < paced_cycles).astype(own_start.dtype)
+                carry = (own, *carry[1:])
+            carry, ys = jax.lax.scan(step, carry, xs)
             return carry, ys
 
         carry0 = (
-            own_init,
-            jnp.zeros_like(own_init),
-            jnp.zeros((), own_init.dtype),
-            jnp.zeros((), own_init.dtype),
+            own_start,
+            jnp.zeros_like(own_start),
+            jnp.zeros((), own_start.dtype),
+            jnp.zeros((), own_start.dtype),
         )
-        (own, relay, _, _), (done_t, wire_t) = jax.lax.scan(
-            one_cycle, carry0, None, length=num_cycles
-        )
-        return done_t.reshape(-1), wire_t.reshape(-1), own.sum() + relay.sum()
+        if f is not None:
+            carry0 += (jnp.zeros((), own_start.dtype), jnp.zeros((), jnp.int32))
+        cycles = None if f is None else jnp.arange(num_cycles, dtype=jnp.int32)
+        (own, relay, *rest), (done_t, wire_t) = jax.lax.scan(
+            one_cycle, carry0, cycles, length=num_cycles)
+        out = done_t.reshape(-1), wire_t.reshape(-1), own.sum() + relay.sum()
+        return out if f is None else out + (rest[2],)
 
-    return jax.vmap(one_scenario)(own0)
+    row_axes = None if faults is None else _ROW_AXES
+    return jax.vmap(one_scenario, in_axes=(0, row_axes))(own0, faults)
 
 
 # --------------------------------------------------------------------------
@@ -140,60 +273,26 @@ def _sparse_slice_step(own, relay, done, wire, dst, vlb: bool):
     return own, relay, done, wire
 
 
-def _trajectories(ys):
-    """(num_cycles, num_slices, B) scan outputs -> (B, num_cycles*num_slices)."""
-    return tuple(y.reshape(-1, y.shape[-1]).T for y in ys)
-
-
-@functools.partial(jax.jit, static_argnames=("vlb", "num_cycles"))
-def _run_batch_sparse(dst, own0, vlb: bool, num_cycles: int):
-    """Sparse analogue of `_run_batch`: same (done_t, wire_t, residual)
-    contract, one program per call.  The batch rides the kernel's grid
-    instead of a vmap: scan over cycles, inner scan over the
-    ``(S, N, u)`` index tensor, `_sparse_slice_step` as the body."""
-    bsz = own0.shape[0]
-
-    def step(carry, d):
-        carry = _sparse_slice_step(*carry, d, vlb)
-        return carry, carry[2:]
-
-    def one_cycle(carry, _):
-        return jax.lax.scan(step, carry, dst)
-
-    carry0 = (
-        own0,
-        jnp.zeros_like(own0),
-        jnp.zeros((bsz,), own0.dtype),
-        jnp.zeros((bsz,), own0.dtype),
-    )
-    (own, relay, _, _), ys = jax.lax.scan(
-        one_cycle, carry0, None, length=num_cycles)
-    done_t, wire_t = _trajectories(ys)
-    return done_t, wire_t, own.sum((1, 2)) + relay.sum((1, 2))
-
-
 @functools.partial(jax.jit, static_argnames=("vlb",))
-def _sparse_slice_step_faulted(
-    own, relay, done, wire, blk, g, dst, pair_sw,
-    up_onset, up_detect, up_recover, tor_onset, tor_detect, tor_recover,
-    vlb: bool,
-):
-    """Faulted sparse step — the scan body of `_run_batch_sparse_faulted`:
-    rebuild the per-step masks from the compiled component timelines
-    (same int32 comparisons as `_slice_step_faulted`, so masks stay
+def _sparse_slice_step_faulted(own, relay, done, wire, blk, g, dst,
+                               faults: RotorFaults, vlb: bool):
+    """Faulted sparse step — the scan body of `_run_batch_sparse` under
+    faults: rebuild the per-step masks from the compiled component
+    timelines (same int32 comparisons as `_slice_masks`, so masks stay
     *data* and one lowering serves every failure draw), then run the
-    edge-layout faulted math.  Slot s of ``dst`` is switch s, so the
-    per-uplink timelines apply directly by slot; only the pair-dead
-    relay mask still needs the dense ``pair_sw`` serving-switch gather."""
+    edge-layout faulted math (`ref.rotor_slice_faulted_ref`; there is no
+    faulted kernel).  Slot s of ``dst`` is switch s, so the per-uplink
+    timelines apply directly by slot; only the pair-dead relay mask
+    still needs the dense ``pair_sw`` serving-switch gather."""
     from repro.kernels.rotor_slice.ref import rotor_slice_faulted_ref
 
     bsz, n = own.shape[0], own.shape[1]
     u = dst.shape[1]
-    up_f = (g >= up_onset) & (g < up_recover)
-    up_k = (g >= up_detect) & (g < up_recover)
-    tor_fb = (g >= tor_onset) & (g < tor_recover)
-    tor_kb = (g >= tor_detect) & (g < tor_recover)
-    psw = jnp.broadcast_to(pair_sw[None], (bsz, n, n))
+    up_f = (g >= faults.up_onset) & (g < faults.up_recover)
+    up_k = (g >= faults.up_detect) & (g < faults.up_recover)
+    tor_fb = (g >= faults.tor_onset) & (g < faults.tor_recover)
+    tor_kb = (g >= faults.tor_detect) & (g < faults.tor_recover)
+    psw = jnp.broadcast_to(faults.pair_sw[None], (bsz, n, n))
     p_k = jnp.take_along_axis(up_k, psw, axis=2)
     pair_dead = (
         p_k | jnp.swapaxes(p_k, 1, 2)
@@ -208,30 +307,44 @@ def _sparse_slice_step_faulted(
     return own, relay, done, wire, blk, g + 1
 
 
+def _trajectories(ys):
+    """(num_cycles, num_slices, B) scan outputs -> (B, num_cycles*num_slices)."""
+    return tuple(y.reshape(-1, y.shape[-1]).T for y in ys)
+
+
 @functools.partial(
     jax.jit, static_argnames=("vlb", "num_cycles", "paced_cycles")
 )
-def _run_batch_sparse_faulted(
-    dst, pair_sw, own0,
-    up_onset, up_detect, up_recover, tor_onset, tor_detect, tor_recover,
-    vlb: bool, num_cycles: int, paced_cycles: int,
-):
-    """Sparse analogue of `_run_batch_faulted`, one program per call as
-    `_run_batch_sparse`, with the global step counter and blackholed
-    total in the carry; returns (done_t, wire_t, residual, blackholed)."""
+def _run_batch_sparse(dst, own0, vlb: bool, num_cycles: int,
+                      faults: Optional[RotorFaults] = None,
+                      paced_cycles: int = 0):
+    """Sparse analogue of `_run_batch`: same outputs, faults and pacing,
+    one program per call.  The batch rides the kernel's grid instead of
+    a vmap: scan over cycles, inner scan over the ``(S, N, u)`` index
+    tensor, `_sparse_slice_step` as the body (`_sparse_slice_step_faulted`
+    under faults, with the global step counter and blackholed total in
+    the carry)."""
     bsz = own0.shape[0]
-    timelines = (up_onset, up_detect, up_recover,
-                 tor_onset, tor_detect, tor_recover)
-    if paced_cycles:
-        inject = own0 * (1.0 / paced_cycles)
-        own_start = jnp.zeros_like(own0)
+    if faults is None:
+        def step(carry, d):
+            carry = _sparse_slice_step(*carry, d, vlb)
+            return carry, carry[2:]
+
+        carry0 = (own0, jnp.zeros_like(own0),
+                  jnp.zeros((bsz,), own0.dtype), jnp.zeros((bsz,), own0.dtype))
     else:
         own_start = own0
+        if paced_cycles:
+            inject = own0 * (1.0 / paced_cycles)
+            own_start = jnp.zeros_like(own0)
 
-    def step(carry, d):
-        carry = _sparse_slice_step_faulted(
-            *carry, d, pair_sw, *timelines, vlb)
-        return carry, carry[2:4]
+        def step(carry, d):
+            carry = _sparse_slice_step_faulted(*carry, d, faults, vlb)
+            return carry, carry[2:4]
+
+        zero = jnp.zeros((bsz,), own0.dtype)
+        carry0 = (own_start, jnp.zeros_like(own0), zero, zero, zero,
+                  jnp.zeros((), jnp.int32))
 
     def one_cycle(carry, c):
         if paced_cycles:
@@ -239,132 +352,12 @@ def _run_batch_sparse_faulted(
             carry = (own, *carry[1:])
         return jax.lax.scan(step, carry, dst)
 
-    zero = jnp.zeros((bsz,), own0.dtype)
-    carry0 = (own_start, jnp.zeros_like(own0), zero, zero, zero,
-              jnp.zeros((), jnp.int32))
-    (own, relay, _, _, blk, _), ys = jax.lax.scan(
-        one_cycle, carry0, jnp.arange(num_cycles, dtype=jnp.int32))
+    cycles = None if faults is None else jnp.arange(num_cycles, dtype=jnp.int32)
+    (own, relay, *rest), ys = jax.lax.scan(
+        one_cycle, carry0, cycles, length=num_cycles)
     done_t, wire_t = _trajectories(ys)
-    return done_t, wire_t, own.sum((1, 2)) + relay.sum((1, 2)), blk
-
-
-def _slice_step_faulted(state, xs, ops, vlb: bool):
-    """One topology slice under failure masks — the faulted scan body.
-
-    Mirrors `fluid.rotor_slice_step_faulted` exactly: per-step masks are
-    rebuilt from the compiled component timelines (`faults.step_masks`
-    is the numpy reference) with pure int32 comparisons on the global
-    step counter carried through the scan — masks are data, so one
-    lowering serves every failure draw; change the two together.  With
-    an empty schedule every expression reduces algebraically to
-    `_slice_step` (x*1.0 / x+0.0), but XLA's fusion-dependent reduction
-    order still drifts the last f32 ulp between the two programs — the
-    public API dispatches event-less schedules to `_run_batch` so the
-    no-op case stays bit-identical (see `_faults_all_empty`).
-    """
-    own, relay, done, wire, blk, g = state
-    adj, sw = xs
-    (pair_sw, up_onset, up_detect, up_recover,
-     tor_onset, tor_detect, tor_recover) = ops
-    up_f = (g >= up_onset) & (g < up_recover)
-    up_k = (g >= up_detect) & (g < up_recover)
-    tor_fb = (g >= tor_onset) & (g < tor_recover)
-    tor_kb = (g >= tor_detect) & (g < tor_recover)
-    i_f = jnp.take_along_axis(up_f, sw, axis=1)
-    i_k = jnp.take_along_axis(up_k, sw, axis=1)
-    e_real = (i_f | i_f.T | tor_fb[:, None] | tor_fb[None, :]).astype(own.dtype)
-    e_known = (i_k | i_k.T | tor_kb[:, None] | tor_kb[None, :]).astype(own.dtype)
-    p_k = jnp.take_along_axis(up_k, pair_sw, axis=1)
-    pair_dead = (
-        p_k | p_k.T | tor_kb[:, None] | tor_kb[None, :]
-    ).astype(own.dtype)
-    tor_real = tor_fb.astype(own.dtype)
-    tor_known = tor_kb.astype(own.dtype)
-
-    cap = adj * (1.0 - e_known) * (1.0 - tor_real)[:, None]
-    arrive = 1.0 - e_real
-    send_own = jnp.minimum(own, cap)
-    own = own - send_own * arrive
-    room = cap - send_own
-    send_relay = jnp.minimum(relay, room)
-    relay = relay - send_relay * arrive
-    room = room - send_relay
-    delivered = (send_own * arrive).sum() + (send_relay * arrive).sum()
-    done = done + delivered
-    wire = wire + delivered
-    # lost sends summed directly (see fluid.rotor_slice_step_faulted)
-    blk = blk + (send_own * e_real).sum() + (send_relay * e_real).sum()
-    if vlb:
-        dst_ok = 1.0 - tor_known
-        elig = jnp.where(cap > 0, 0.0, own * dst_ok[None, :])
-        relig = relay * pair_dead * dst_ok[None, :]  # stuck relay re-spreads
-        q = elig.sum(1) + relig.sum(1)
-        r = room.sum(1)
-        t = jnp.minimum(q, r)
-        frac = jnp.where(q > 0, t / jnp.maximum(q, 1e-30), 0.0)[:, None]
-        take = elig * frac
-        rtake = relig * frac
-        share = room * jnp.where(r > 0, 1.0 / jnp.maximum(r, 1e-30), 0.0)[:, None]
-        lost = (share * e_real).sum(1)
-        own = own - take + take * lost[:, None]
-        relay = relay - rtake + rtake * lost[:, None]
-        relay = relay + jnp.matmul((share * arrive).T, take + rtake,
-                                   precision=jax.lax.Precision.HIGHEST)
-        lost_sum = ((take + rtake).sum(1) * lost).sum()
-        wire = wire + (t.sum() - lost_sum)
-        blk = blk + lost_sum
-    return (own, relay, done, wire, blk, g + 1), (done, wire)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("vlb", "num_cycles", "paced_cycles")
-)
-def _run_batch_faulted(
-    adj, sw, pair_sw, own0,
-    up_onset, up_detect, up_recover, tor_onset, tor_detect, tor_recover,
-    vlb: bool, num_cycles: int, paced_cycles: int,
-):
-    """`_run_batch` with per-row failure timelines (and optional paced
-    demand injection).  The mask arrays are vmapped scenario operands —
-    every batch row carries an independent failure draw — while the
-    topology tensor, its switch-id map, and the per-pair serving-switch
-    map are shared design-time state.  Also returns the per-row
-    blackholed-byte total."""
-    def one_scenario(own_init, uo, ud, ur, to, td, tr):
-        step = functools.partial(
-            _slice_step_faulted, ops=(pair_sw, uo, ud, ur, to, td, tr), vlb=vlb
-        )
-        if paced_cycles:
-            inject = own_init * (1.0 / paced_cycles)
-            own_start = jnp.zeros_like(own_init)
-        else:
-            own_start = own_init
-
-        def one_cycle(carry, c):
-            if paced_cycles:
-                own, relay, done, wire, blk, g = carry
-                own = own + inject * (c < paced_cycles).astype(own.dtype)
-                carry = (own, relay, done, wire, blk, g)
-            carry, ys = jax.lax.scan(step, carry, (adj, sw))
-            return carry, ys
-
-        carry0 = (
-            own_start,
-            jnp.zeros_like(own_start),
-            jnp.zeros((), own_start.dtype),
-            jnp.zeros((), own_start.dtype),
-            jnp.zeros((), own_start.dtype),
-            jnp.zeros((), jnp.int32),
-        )
-        (own, relay, _, _, blk, _), (done_t, wire_t) = jax.lax.scan(
-            one_cycle, carry0, jnp.arange(num_cycles, dtype=jnp.int32)
-        )
-        return done_t.reshape(-1), wire_t.reshape(-1), own.sum() + relay.sum(), blk
-
-    return jax.vmap(one_scenario)(
-        own0, up_onset, up_detect, up_recover,
-        tor_onset, tor_detect, tor_recover,
-    )
+    out = done_t, wire_t, own.sum((1, 2)) + relay.sum((1, 2))
+    return out if faults is None else out + (rest[2],)
 
 
 @dataclasses.dataclass
@@ -466,15 +459,16 @@ def simulate_rotor_bulk_batch(
     sequence of them (one independent draw per row); `paced_cycles`
     spreads each row's demand over that many cycle starts instead of
     offering it all at t=0 — the sustained-load mode the dynamic
-    Fig. 11 throughput-retention columns measure.  Both route through
-    one faulted lowering per design point; when neither is set the
-    original failure-free program runs untouched.
+    Fig. 11 throughput-retention columns measure.  Either passes the
+    engine's program a `RotorFaults` operand (one faulted lowering per
+    design point); when neither is set the program runs without it, as
+    its failure-free lowering.
 
     `engine` selects the dense scan or the permutation-sparse gather
     engine (see module docstring); "auto" picks by rack count.  Within
-    either engine an event-less `faults` with no pacing dispatches to
-    that engine's unfaulted program, so `FailureSchedule.empty()` stays
-    bit-identical to the failure-free run.
+    either engine an event-less `faults` with no pacing runs
+    failure-free, so `FailureSchedule.empty()` stays bit-identical to
+    the failure-free run.
     """
     with obs.span("fluid.prepare"):
         demands = np.asarray(demands, np.float64)  # staticcheck: ok SC-AST-F64 (host staging)
@@ -493,10 +487,8 @@ def simulate_rotor_bulk_batch(
             sched = jnp.asarray(topo.matching_index_tensor())
         else:
             sched = jnp.asarray(topo.matching_tensor(), dtype)
-        clean = _faults_all_empty(faults) and not paced_cycles
-        uploads = [own0, sched]
-        blackholed = None
-        if not clean:
+        fault_ops = None
+        if not (_faults_all_empty(faults) and not paced_cycles):
             from repro.netsim.faults import (
                 FailureSchedule,
                 FaultMasks,
@@ -507,48 +499,28 @@ def simulate_rotor_bulk_batch(
                 faults = FailureSchedule.empty(topo)
             masks = (faults if isinstance(faults, FaultMasks)
                      else compile_fault_masks(topo, faults))
-            masks = masks.broadcast_to(demands.shape[0])
-            pair_sw = jnp.asarray(masks.pair_switch)
-            timelines = [jnp.asarray(getattr(masks, f)) for f in (
-                "up_onset", "up_detect", "up_recover",
-                "tor_onset", "tor_detect", "tor_recover")]
-            uploads += [pair_sw, *timelines]
-            if engine == "dense":
-                sw = jnp.asarray(masks.switch_id)
-                uploads.append(sw)
-        obs.count("fluid.h2d_bytes", sum(x.nbytes for x in uploads))
+            fault_ops = RotorFaults.from_masks(
+                masks.broadcast_to(demands.shape[0]), dense=engine == "dense")
+        obs.count("fluid.h2d_bytes", sum(
+            x.nbytes for x in jax.tree.leaves((own0, sched, fault_ops))))
         obs.count("fluid.scenario_slices",
                   demands.shape[0] * int(max_cycles) * sched.shape[0])
 
     with obs.span("fluid.run"):
+        args = (sched, own0, bool(vlb), int(max_cycles), fault_ops,
+                int(paced_cycles))
         if engine == "sparse":
             with obs.span("fluid.sparse.loop"):
-                if clean:
-                    done_t, wire_t, residual = _run_batch_sparse(
-                        sched, own0, bool(vlb), int(max_cycles))
-                else:
-                    (done_t, wire_t, residual,
-                     blackholed) = _run_batch_sparse_faulted(
-                        sched, pair_sw, own0, *timelines,
-                        bool(vlb), int(max_cycles), int(paced_cycles),
-                    )
-        elif clean:
-            done_t, wire_t, residual = _run_batch(
-                sched, own0, bool(vlb), int(max_cycles))
+                out = _run_batch_sparse(*args)
         else:
-            done_t, wire_t, residual, blackholed = _run_batch_faulted(
-                sched, sw, pair_sw, own0, *timelines,
-                bool(vlb), int(max_cycles), int(paced_cycles),
-            )
+            out = _run_batch(*args)
 
     with obs.span("fluid.readback"):
         # Device f32 trajectories are de-normalized on the host at float64
         # before stats, mirroring the numpy oracle's precision.
-        done = np.asarray(done_t, np.float64) * cap  # staticcheck: ok SC-AST-F64 (host staging)
-        wire = np.asarray(wire_t, np.float64) * cap  # staticcheck: ok SC-AST-F64 (host staging)
-        residual = np.asarray(residual, np.float64) * cap  # staticcheck: ok SC-AST-F64 (host staging)
-        if not clean:
-            blackholed = np.asarray(blackholed, np.float64) * cap  # staticcheck: ok SC-AST-F64 (host staging)
+        out = [np.asarray(x, np.float64) * cap for x in out]  # staticcheck: ok SC-AST-F64 (host staging)
+        done, wire, residual = out[:3]
+        blackholed = out[3] if fault_ops is not None else None
 
     with obs.span("fluid.stats"):
         totals = demands.sum((1, 2))

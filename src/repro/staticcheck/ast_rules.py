@@ -32,8 +32,8 @@ LOCKSTEP_PAIRS: Tuple[Tuple[str, str], ...] = (
 )
 # failure-semantics module: its per-step mask/window math is mirrored
 # inside every member of LOCKSTEP_PAIRS (faults.step_masks <->
-# fluid_jax._slice_step_faulted, apply_flow_faults windows <-> both
-# flow engines), so a diff touching it couples to every pair
+# fluid_jax._slice_masks, apply_flow_faults windows <-> both flow
+# engines' step bodies), so a diff touching it couples to every pair
 FAULTS_MODULE = "src/repro/netsim/faults.py"
 
 

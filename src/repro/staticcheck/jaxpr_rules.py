@@ -17,26 +17,25 @@ SC-JAX-RECOMPILE  the sweep grid compiles more than once per design
                   `fluid_jax._run_batch` per (k, num_racks, groups)
                   shape, never one per load/seed scenario.  The fault
                   path has the same contract (`count_fault_lowerings`):
-                  failure timelines are int32 *data* operands of
-                  `_run_batch_faulted`, so distinct failure draws must
-                  never trigger fresh lowerings.
+                  failure timelines are int32 *data* inside the
+                  `RotorFaults` operand of `_run_batch`, so distinct
+                  failure draws must never trigger fresh lowerings.
 
-Traced entry points: ``fluid_jax._run_batch`` / ``_run_batch_faulted``
-(the dense device programs under ``simulate_rotor_bulk_batch``),
-``fluid_jax._run_batch_sparse`` / ``_run_batch_sparse_faulted`` (the
-sparse engine's slice-loop programs, paced demand included —
-``count_sparse_lowerings`` holds the clean one to one lowering per
-(design point, ``vlb``, ``max_cycles``), whatever the demand draw),
-``flows_jax._run_batch`` / ``_run_batch_faulted`` (under
-``simulate_grid`` / ``simulate_flows_batch``),
-``flows_jax._run_tiled_chunk`` / ``_run_tiled_chunk_faulted`` (the
-streaming tiled flow engine's chunk programs — shapes depend on the
-(batch, window_tiles, tile) geometry only, never on the scenario's
-flow count, and ``count_tiled_lowerings`` holds them to one lowering
-per design point across loads and seeds), and the five Pallas kernel
-``ops`` wrappers (``rotor_slice_step`` traced with
-``force_pallas=True`` so the kernel body, not the CPU ref fast path,
-is what the rules walk).
+Traced entry points, each twice — without its fault operand and, under
+the name suffixed ``[faulted]``, with one: ``fluid_jax._run_batch`` (the
+dense device program under ``simulate_rotor_bulk_batch``),
+``fluid_jax._run_batch_sparse`` (the sparse engine's slice-loop program,
+paced demand included when faulted — ``count_sparse_lowerings`` holds
+the clean one to one lowering per (design point, ``vlb``,
+``max_cycles``), whatever the demand draw), ``flows_jax._run_batch``
+(under ``simulate_grid`` / ``simulate_flows_batch``),
+``flows_jax._run_tiled_chunk`` (the streaming tiled flow engine's chunk
+program — shapes depend on the (batch, window_tiles, tile) geometry
+only, never on the scenario's flow count, and ``count_tiled_lowerings``
+holds it to one lowering per design point across loads and seeds); and
+the five Pallas kernel ``ops`` wrappers (``rotor_slice_step`` traced
+with ``force_pallas=True`` so the kernel body, not the CPU ref fast
+path, is what the rules walk).
 """
 from __future__ import annotations
 
@@ -91,96 +90,54 @@ def _entry_specs() -> List[Tuple[str, Callable, Callable]]:
     from repro.kernels.rglru_scan.ops import rglru_scan
     from repro.kernels.rotor_slice.ops import rotor_slice_step
 
+    def clean_and_faulted(name, run, args, faults):
+        """`run(*args, faults)` traced with no fault operand and with one."""
+        return [(name, lambda *a: run(*a, None), lambda: args),
+                (name + "[faulted]", run, lambda: args + (faults,))]
+
+    def rotor_faults(dense):
+        return fluid_jax.RotorFaults(
+            sd((8, 8), jnp.int32),
+            *[sd((2, 8, 3), jnp.int32)] * 3, *[sd((2, 8), jnp.int32)] * 3,
+            switch_id=sd((6, 8, 8), jnp.int32) if dense else None)
+
+    def flow_ops(lead, dense):
+        f32, i32 = sd(lead), sd(lead, jnp.int32)
+        allow = dict(allow_mid=f32, allow_end=f32) if dense else {}
+        return flows_jax.FlowOps(
+            rem0=f32, start=i32, is_bulk=sd(lead, jnp.bool_), class_id=i32,
+            arr_ms=f32, lat_u=sd((2,)), bulk_u=sd((2,)), dt_ms=sd((2,)),
+            mid_step=sd((2,), jnp.int32), end_step=sd((2,), jnp.int32),
+            **allow)
+
+    def flow_faults(lead, steps):
+        return flows_jax.FlowFaults(*[sd(lead, jnp.int32)] * 4,
+                                    sd((2, steps)), sd((2, steps)))
+
+    tiled_carry = (sd((2, 3, 4)), sd((2, 288), jnp.int32),
+                   sd((2,)), sd((2,)), sd((2,)))
     return [
-        (
+        *clean_and_faulted(
             "netsim.fluid_jax._run_batch",
-            lambda a, o: fluid_jax._run_batch(a, o, True, 3),
-            lambda: (sd((6, 8, 8)), sd((2, 8, 8))),
-        ),
-        (
+            lambda a, o, f: fluid_jax._run_batch(a, o, True, 3, f),
+            (sd((6, 8, 8)), sd((2, 8, 8))), rotor_faults(dense=True)),
+        *clean_and_faulted(
             "netsim.fluid_jax._run_batch_sparse",
-            lambda d, o: fluid_jax._run_batch_sparse(d, o, True, 3),
-            lambda: (sd((6, 8, 2), jnp.int32), sd((2, 8, 8))),
-        ),
-        (
-            "netsim.fluid_jax._run_batch_sparse_faulted",
-            lambda *a: fluid_jax._run_batch_sparse_faulted(*a, True, 3, 2),
-            lambda: (
-                sd((6, 8, 2), jnp.int32), sd((8, 8), jnp.int32),
-                sd((2, 8, 8)),
-                sd((2, 8, 3), jnp.int32), sd((2, 8, 3), jnp.int32),
-                sd((2, 8, 3), jnp.int32),
-                sd((2, 8), jnp.int32), sd((2, 8), jnp.int32),
-                sd((2, 8), jnp.int32),
-            ),
-        ),
-        (
-            "netsim.fluid_jax._run_batch_faulted",
-            lambda *a: fluid_jax._run_batch_faulted(*a, True, 3, 0),
-            lambda: (
-                sd((6, 8, 8)), sd((6, 8, 8), jnp.int32),
-                sd((8, 8), jnp.int32), sd((2, 8, 8)),
-                sd((2, 8, 3), jnp.int32), sd((2, 8, 3), jnp.int32),
-                sd((2, 8, 3), jnp.int32),
-                sd((2, 8), jnp.int32), sd((2, 8), jnp.int32),
-                sd((2, 8), jnp.int32),
-            ),
-        ),
-        (
+            lambda d, o, f: fluid_jax._run_batch_sparse(
+                d, o, True, 3, f, paced_cycles=0 if f is None else 2),
+            (sd((6, 8, 2), jnp.int32), sd((2, 8, 8))),
+            rotor_faults(dense=False)),
+        *clean_and_faulted(
             "netsim.flows_jax._run_batch",
-            lambda *a: flows_jax._run_batch(*a, num_steps=7, trace=False),
-            lambda: (
-                sd((2, 5)), sd((2, 5), jnp.int32), sd((2, 5), jnp.bool_),
-                sd((2,)), sd((2,)), sd((2, 5)), sd((2, 5)),
-                sd((2,), jnp.int32), sd((2,), jnp.int32),
-                sd((2, 5), jnp.int32), sd((2, 5)), sd((2,)),
-            ),
-        ),
-        (
-            "netsim.flows_jax._run_batch_faulted",
-            lambda *a: flows_jax._run_batch_faulted(*a, num_steps=7,
-                                                    trace=False),
-            lambda: (
-                sd((2, 5)), sd((2, 5), jnp.int32), sd((2, 5), jnp.bool_),
-                sd((2,)), sd((2,)), sd((2, 5)), sd((2, 5)),
-                sd((2,), jnp.int32), sd((2,), jnp.int32),
-                sd((2, 5), jnp.int32), sd((2, 5)), sd((2,)),
-                sd((2, 5), jnp.int32), sd((2, 5), jnp.int32),
-                sd((2, 5), jnp.int32), sd((2, 5), jnp.int32),
-                sd((2, 7)), sd((2, 7)),
-            ),
-        ),
-        (
+            lambda scn, f: flows_jax._run_batch(scn, 7, False, f),
+            (flow_ops((2, 5), dense=True),), flow_faults((2, 5), 7)),
+        *clean_and_faulted(
             "netsim.flows_jax._run_tiled_chunk",
-            lambda *a: flows_jax._run_tiled_chunk(*a, num_steps=7,
-                                                  chunk_steps=4),
-            lambda: (
-                sd((2, 3, 4)), sd((2, 3, 4)), sd((2, 3, 4), jnp.int32),
-                sd((2, 3, 4), jnp.bool_), sd((2, 3, 4), jnp.int32),
-                sd((2, 3, 4)),
-                sd((2,)), sd((2,)), sd((2,)),
-                sd((2,), jnp.int32), sd((2,), jnp.int32),
-                sd((2, 288), jnp.int32), sd((2,)), sd((2,)), sd((2,)),
-                sd((), jnp.int32),
-            ),
-        ),
-        (
-            "netsim.flows_jax._run_tiled_chunk_faulted",
-            lambda *a: flows_jax._run_tiled_chunk_faulted(*a, num_steps=7,
-                                                          chunk_steps=4),
-            lambda: (
-                sd((2, 3, 4)), sd((2, 3, 4)), sd((2, 3, 4), jnp.int32),
-                sd((2, 3, 4), jnp.bool_), sd((2, 3, 4), jnp.int32),
-                sd((2, 3, 4)),
-                sd((2,)), sd((2,)), sd((2,)),
-                sd((2,), jnp.int32), sd((2,), jnp.int32),
-                sd((2, 3, 4), jnp.int32), sd((2, 3, 4), jnp.int32),
-                sd((2, 3, 4), jnp.int32), sd((2, 3, 4), jnp.int32),
-                sd((2, 4)), sd((2, 4)),
-                sd((2, 288), jnp.int32), sd((2,)), sd((2,)), sd((2,)),
-                sd((), jnp.int32),
-            ),
-        ),
+            lambda c, scn, s0, f: flows_jax._run_tiled_chunk(
+                c, scn, s0, num_steps=7, chunk_steps=4, faults=f),
+            (tiled_carry, flow_ops((2, 3, 4), dense=False),
+             sd((), jnp.int32)),
+            flow_faults((2, 3, 4), 4)),
         (
             "kernels.flash_attention.ops.flash_attention",
             lambda q, k, v: flash_attention(q, k, v, interpret=True),
@@ -344,10 +301,10 @@ def count_fault_lowerings(
     num_draws: int = 2, max_cycles: int = 6,
 ) -> Tuple[int, List[Finding]]:
     """SC-JAX-RECOMPILE for the fault path: failure timelines are int32
-    *data* operands of `fluid_jax._run_batch_faulted` (the per-step 0/1
-    masks are rebuilt inside the scan from the global step counter), so
-    running several distinct failure draws through one design point must
-    add at most one fresh lowering — zero once warm.
+    *data* in the `RotorFaults` operand of `fluid_jax._run_batch` (the
+    per-step 0/1 masks are rebuilt inside the scan from the global step
+    counter), so running several distinct failure draws through one
+    design point must add at most one fresh lowering — zero once warm.
 
     Returns (new_lowerings, findings)."""
     import numpy as np
@@ -361,21 +318,21 @@ def count_fault_lowerings(
     cfg = DesignPoint(k=4, num_racks=8).to_config()
     demand = np.full((8, 8), 1e6)
     np.fill_diagonal(demand, 0.0)
-    before = fluid_jax._run_batch_faulted._cache_size()
+    before = fluid_jax._run_batch._cache_size()
     for seed in range(num_draws):
         sched = FailureSchedule.draw(
             topo, seed=seed, link_frac=0.1, switch_count=1, onset_step=2)
         fluid_jax.simulate_rotor_bulk_batch(
             cfg, demand[None], topo=topo, max_cycles=max_cycles,
             faults=[sched])
-    new = fluid_jax._run_batch_faulted._cache_size() - before
-    path, line = _src_location(fluid_jax._run_batch_faulted)
+    new = fluid_jax._run_batch._cache_size() - before
+    path, line = _src_location(fluid_jax._run_batch)
     findings: List[Finding] = []
     if new > 1:
         findings.append(Finding(
             "SC-JAX-RECOMPILE",
             f"{num_draws} failure draws through one design point compiled "
-            f"{new} `_run_batch_faulted` lowerings — fault masks are data; "
+            f"{new} faulted `_run_batch` lowerings — fault masks are data; "
             "the engine must lower once per design point, never per draw",
             path=path, line=line))
     return new, findings

@@ -12,8 +12,8 @@ Three contracts under test:
      interpret path vs jnp ref path, bitwise when compiled without FMA
      — the kernel's one-hot gathers are exact and it sums in the ref's
      order) and with the numpy oracle `fluid.rotor_slice_step`.
-  3. The sparse batch drivers (`_run_batch_sparse`, and the faulted
-     engine behind ``engine="sparse"``) match the dense scan engine on
+  3. The sparse batch driver (`_run_batch_sparse`, clean and with its
+     `RotorFaults` operand) matches the dense scan engine on
      full trajectories, unfaulted and under a nonempty
      `FailureSchedule`, and their one-program slice loops match a plain
      host stepping of the slice step bit for bit.
@@ -292,7 +292,7 @@ def _host_loop(dst, own0, vlb, num_cycles, faulted=None, paced_cycles=0):
                 wire = wire + delivered + moved
             else:
                 own, relay, done, wire, blk, g = _sparse_slice_step_faulted(
-                    own, relay, done, wire, blk, g, dst[t], *faulted, vlb)
+                    own, relay, done, wire, blk, g, dst[t], faulted, vlb)
             done_t.append(done)
             wire_t.append(wire)
     residual = own.sum((1, 2)) + relay.sum((1, 2))
@@ -362,10 +362,7 @@ class TestEngineParity:
         import jax.numpy as jnp
 
         from repro.netsim.faults import compile_fault_masks
-        from repro.netsim.fluid_jax import (
-            _run_batch_sparse,
-            _run_batch_sparse_faulted,
-        )
+        from repro.netsim.fluid_jax import RotorFaults, _run_batch_sparse
 
         cfg = dp.to_config()
         topo = build_opera_topology(
@@ -377,11 +374,8 @@ class TestEngineParity:
         dst = jnp.asarray(topo.matching_index_tensor())
         if faulted:
             masks = compile_fault_masks(topo, _faults(cfg)).broadcast_to(3)
-            ops = [jnp.asarray(getattr(masks, f)) for f in (
-                "pair_switch", "up_onset", "up_detect", "up_recover",
-                "tor_onset", "tor_detect", "tor_recover")]
-            got = _run_batch_sparse_faulted(dst, ops[0], own0, *ops[1:],
-                                            vlb, 4, 2)
+            ops = RotorFaults.from_masks(masks, dense=False)
+            got = _run_batch_sparse(dst, own0, vlb, 4, ops, paced_cycles=2)
             want = _host_loop(dst, own0, vlb, 4, faulted=ops,
                               paced_cycles=2)
         else:

@@ -304,12 +304,10 @@ class TestJaxprRules:
         names = {e.name for e in entries}
         assert len(names) == 13
         assert any("fluid_jax" in n for n in names)
-        assert "netsim.fluid_jax._run_batch_faulted" in names
-        assert "netsim.flows_jax._run_batch_faulted" in names
-        assert "netsim.flows_jax._run_tiled_chunk" in names
-        assert "netsim.flows_jax._run_tiled_chunk_faulted" in names
-        assert "netsim.fluid_jax._run_batch_sparse" in names
-        assert "netsim.fluid_jax._run_batch_sparse_faulted" in names
+        for program in ("fluid_jax._run_batch", "fluid_jax._run_batch_sparse",
+                        "flows_jax._run_batch", "flows_jax._run_tiled_chunk"):
+            assert f"netsim.{program}" in names
+            assert f"netsim.{program}[faulted]" in names
         assert "kernels.rotor_slice.ops.rotor_slice_step" in names
         assert any("flash_attention" in n for n in names)
 
@@ -373,7 +371,7 @@ class TestRecompilePinning:
 
     def test_fault_draws_share_one_lowering(self):
         """Failure timelines are data: distinct draws through one design
-        point must add at most one `_run_batch_faulted` lowering, and a
+        point must add at most one faulted `_run_batch` lowering, and a
         re-run with fresh draws must add none."""
         from repro.staticcheck.jaxpr_rules import count_fault_lowerings
 
@@ -417,6 +415,70 @@ class TestRecompilePinning:
                                                 seeds=(2, 3))
         assert findings2 == []
         assert new2 == 0
+
+
+def _fluid_runner(engine):
+    from repro.netsim import fluid_jax
+    from repro.netsim.faults import FailureSchedule
+    from repro.netsim.sweep import DesignPoint
+
+    fab = build_opera_topology(8, 2, seed=0)
+    cfg = DesignPoint(k=4, num_racks=8).to_config()
+    demand = np.full((1, 8, 8), 1e6)
+    demand[0][np.diag_indices(8)] = 0.0
+    program = (fluid_jax._run_batch_sparse if engine == "sparse"
+               else fluid_jax._run_batch)
+
+    def run(draw):
+        faults = None if draw is None else FailureSchedule.draw(
+            fab, seed=draw, link_frac=0.1, switch_count=1, onset_step=2)
+        fluid_jax.simulate_rotor_bulk_batch(
+            cfg, demand, topo=fab, max_cycles=3, faults=faults,
+            engine=engine)
+    return program, run
+
+
+def _flows_runner(engine):
+    from repro.netsim import flows_jax
+    from repro.netsim.faults import FailureSchedule, apply_flow_faults
+    from repro.netsim.flows import build_scenario
+
+    fab = build_opera_topology(8, 2, seed=0)
+    scn = build_scenario("opera", "websearch", 0.12, seed=0, num_hosts=16,
+                         horizon_s=0.06, dt_s=5e-4, tail_s=0.04)
+    program = (flows_jax._run_tiled_chunk if engine == "tiled"
+               else flows_jax._run_batch)
+
+    def run(draw):
+        batch = scn if draw is None else apply_flow_faults(
+            scn, FailureSchedule.draw(fab, seed=draw, tor_frac=0.25,
+                                      onset_step=20, detect_lag=5,
+                                      recover_step=80))
+        flows_jax.simulate_flows_batch(
+            [batch], engine=engine, tile_size=64, window_tiles=16,
+            chunk_steps=32)
+    return program, run
+
+
+@pytest.mark.parametrize("runner,engine", [
+    (_fluid_runner, "dense"), (_fluid_runner, "sparse"),
+    (_flows_runner, "dense"), (_flows_runner, "tiled"),
+], ids=["fluid-dense", "fluid-sparse", "flows-dense", "flows-tiled"])
+def test_fault_operand_adds_one_lowering(runner, engine):
+    """Each engine path is one jitted program whose fault operand is an
+    optional pytree: a clean call lowers it once, two distinct failure
+    draws add exactly one faulted lowering between them, and repeating
+    all three calls adds none."""
+    program, run = runner(engine)
+    program._clear_cache()
+    run(None)
+    assert program._cache_size() == 1
+    run(0)
+    run(1)
+    assert program._cache_size() == 2
+    for draw in (None, 0, 1):
+        run(draw)
+    assert program._cache_size() == 2
 
 
 # ---------------------------------------------------------------------------
